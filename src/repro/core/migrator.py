@@ -63,11 +63,7 @@ from jax import lax
 from repro.core.state import REGION, SLOT, LeapState, flat_pool_view
 from repro.kernels import ops
 
-try:  # JAX >= 0.7 public API
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -129,7 +125,7 @@ def copy_chunk_ppermute(
     mesh: jax.sharding.Mesh,
 ) -> LeapState:
     """Point-to-point copy backend: one ``ppermute`` of exactly the area bytes."""
-    fn = _shard_map(
+    fn = shard_map(
         partial(_ppermute_local, src_region, dst_region, axis_name),
         mesh=mesh,
         in_specs=(
@@ -226,9 +222,8 @@ def fused_copy(
     ``src_flat``/``dst_flat`` are flat slot ids (``region * S + slot``,
     host-computed from the exact table mirror), so one compiled variant moves
     blocks between arbitrary region pairs.  The move itself is the
-    ``leap_copy`` intra-pool kernel: on TPU a scalar-prefetched Pallas kernel
-    that streams one block per grid step, double-buffered so the HBM read of
-    block i+1 overlaps the write of block i; elsewhere the jnp oracle.
+    ``leap_copy`` intra-pool kernel: on TPU one HBM-to-HBM DMA per block,
+    addressed by scalar-prefetched slot ids; elsewhere the jnp oracle.
     """
     flat = flat_pool_view(state.pool)
     flat = ops.copy_blocks_impl(flat, src_flat, dst_flat, impl=impl)
@@ -248,8 +243,8 @@ def fused_copy_runs(
     ``src_starts``/``dst_starts`` are flat slot ids of each run's first slot
     (``region * S + start``; G-aligned and intra-region because the buddy
     allocator hands out aligned runs and G divides S).  A huge block moves as
-    ONE area through ONE kernel step — ``run * rows`` sublanes per grid step
-    via ``copy_runs`` — instead of ``run`` per-slot gathers.
+    ONE area through ONE kernel step — a single DMA of ``run`` contiguous
+    slots via ``copy_runs`` — instead of ``run`` per-slot gathers.
     """
     flat = flat_pool_view(state.pool)
     flat = ops.copy_runs_impl(flat, src_starts, dst_starts, run=run, impl=impl)
@@ -330,9 +325,9 @@ def force_areas(
 
 
 def _fused_ppermute_local(src_region, dst_region, axis_name, impl, pool, src_slots, dst_slots):
-    # pool arrives as the local shard [1, S, *blk]; flatten the payload to the
-    # [S, rows, cols] kernel layout so the local HBM pack/unpack runs through
-    # the leap_copy Pallas kernels on TPU (jnp oracle elsewhere).
+    # pool arrives as the local shard [1, S, *blk]; its flat view [S, *blk]
+    # is the kernel layout, so the local HBM pack/unpack runs through the
+    # leap_copy Pallas kernels on TPU (jnp oracle elsewhere).
     flat = flat_pool_view(pool)
     buf = ops.gather_blocks_impl(flat, src_slots, impl=impl)  # garbage off-src
     recv = lax.ppermute(buf, axis_name, perm=[(src_region, dst_region)])
@@ -360,7 +355,7 @@ def fused_copy_ppermute(
 ) -> LeapState:
     """Batched point-to-point copy: all of one tick's (src, dst) traffic in a
     single ppermute of exactly the scheduled bytes (slot ids host-computed)."""
-    fn = _shard_map(
+    fn = shard_map(
         partial(_fused_ppermute_local, src_region, dst_region, axis_name, impl),
         mesh=mesh,
         in_specs=(P(axis_name), P(), P()),

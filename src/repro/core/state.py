@@ -41,7 +41,9 @@ class PoolConfig:
       n_regions: number of memory regions (NUMA analogue; mesh-axis size).
       slots_per_region: physical capacity of each region, in blocks.
       block_shape: shape of one block's payload (e.g. ``(rows, cols)`` for a
-        morsel pool or ``(blk_tokens, 2, kv_heads, head_dim)`` for KV).
+        morsel pool or ``(layers, 2, blk_tokens, kv_heads * head_dim)`` for
+        KV).  On TPU keep the minor dim a multiple of 128 lanes: a narrower
+        one is padded to 128 in HBM.
       dtype: payload dtype.
       region_axis: mesh axis name the region dim is sharded over, or None for
         single-device operation (tests / benches).
@@ -118,11 +120,15 @@ def init_state(
     cfg: PoolConfig,
     n_blocks: int,
     initial_regions: Sequence[int] | np.ndarray,
+    mesh: jax.sharding.Mesh | None = None,
 ) -> LeapState:
     """Create a pool with ``n_blocks`` logical blocks placed per ``initial_regions``.
 
     Blocks are assigned slots densely within each region, in block-id order
-    (the host driver mirrors this allocation).
+    (the host driver mirrors this allocation).  With a ``mesh`` the state is
+    created already laid out per :func:`state_sharding`, so each device
+    allocates only its own regions; without one it lands on the default
+    device.
     """
     initial_regions = np.asarray(initial_regions, dtype=np.int32)
     if initial_regions.shape != (n_blocks,):
@@ -151,15 +157,14 @@ def init_state(
     slots[order] = np.arange(n_blocks, dtype=np.int32) - np.repeat(
         starts, counts
     ).astype(np.int32)
-    table = jnp.stack(
-        [jnp.asarray(initial_regions), jnp.asarray(slots)], axis=1
-    ).astype(jnp.int32)
-    pool = jnp.zeros((cfg.n_regions, cfg.slots_per_region) + tuple(cfg.block_shape), cfg.dtype)
+    table = np.stack([initial_regions, slots], axis=1).astype(np.int32)
+    shape = (cfg.n_regions, cfg.slots_per_region) + tuple(cfg.block_shape)
+    sh = state_sharding(cfg, mesh) if mesh is not None else None
     return LeapState(
-        pool=pool,
-        table=table,
-        dirty=jnp.zeros((n_blocks,), jnp.bool_),
-        in_flight=jnp.zeros((n_blocks,), jnp.bool_),
+        pool=jnp.zeros(shape, cfg.dtype, device=sh and sh.pool),
+        table=jnp.asarray(table, device=sh and sh.table),
+        dirty=jnp.zeros((n_blocks,), jnp.bool_, device=sh and sh.dirty),
+        in_flight=jnp.zeros((n_blocks,), jnp.bool_, device=sh and sh.in_flight),
     )
 
 
@@ -284,18 +289,18 @@ def group_in_flight(
 
 
 def flat_pool_view(pool: jax.Array) -> jax.Array:
-    """Reshape ``pool [R, S, *blk]`` to the kernel layout ``[R*S, rows, cols]``.
+    """Reshape ``pool [R, S, *blk]`` to the kernel layout ``[R*S, *blk]``.
 
-    A (region, slot) pair becomes the flat slot ``region * S + slot``; the
-    payload collapses to 2-D (``rows = prod(blk[:-1])``, ``cols = blk[-1]``),
-    which is the shape the ``leap_copy`` Pallas kernels stream block-per-grid-
-    step.  Inside jit the reshape is free (the pool is contiguous).
+    A (region, slot) pair becomes the flat slot ``region * S + slot``.  Only
+    the two leading dims merge and the payload keeps its shape: TPU tiles an
+    HBM array over its two minor dims, so merging leading dims is a bitcast
+    and a donated pool stays aliased through the view.  Collapsing the
+    payload as well would not be free: folding ``[.., KVH, hd]`` into rows
+    changes the minor-dim tiling, and XLA then copies the whole pool in and
+    out of every program that takes the view.
     """
     r, s = pool.shape[:2]
-    payload = pool.shape[2:]
-    rows = int(np.prod(payload[:-1])) if len(payload) > 1 else 1
-    cols = int(payload[-1]) if payload else 1
-    return pool.reshape(r * s, rows, cols)
+    return pool.reshape((r * s,) + tuple(pool.shape[2:]))
 
 
 def placement_histogram(state: LeapState, n_regions: int) -> np.ndarray:

@@ -1,17 +1,20 @@
 """Pallas TPU kernel: block gather/scatter by dynamic slot index — the
 physical-copy hot path of leap migration (the paper's ``memcpy`` analogue).
 
-On TPU the migration copy is: HBM(pool, scattered slots) -> VMEM -> HBM
-(contiguous staging buffer for the ICI ppermute), and the reverse on the
-destination.  Doing this with XLA gather/scatter materializes index vectors
-and gets poor HBM scheduling for large blocks; a Pallas kernel with
-*scalar-prefetched* slot indices streams one block per grid step with the
-block index feeding the BlockSpec index_map directly (double-buffered by the
-Pallas pipeline, so the HBM reads of block i+1 overlap the write of block i).
+On TPU the migration copy is HBM -> HBM: each grid step issues one DMA from
+the source slot (or contiguous run of slots) straight to its destination,
+with the slot ids *scalar-prefetched* into SMEM so they address the DMA
+descriptors directly.  Nothing is staged in VMEM, so the block size is not
+bounded by the scoped VMEM limit, and a huge-block run moves as one DMA.
 
-Alignment guidance: the trailing payload dim should be a multiple of 128
-lanes and the row dim a multiple of 8 sublanes (fp32) / 16 (bf16) so DMA is
-tile-aligned; the shapes used by the serving/morsel pools respect this.
+Layout: every kernel takes the pool as ``[S, *block]`` and slices only the
+leading (slot) dim.  On TPU, HBM arrays are tiled over their two minor dims,
+so a slot slice is tile-aligned for any payload, and the flat view
+``[R*S, *block]`` of a ``[R, S, *block]`` pool is a bitcast
+(:func:`repro.core.state.flat_pool_view`).  Payloads should keep a
+lane-dense minor dim (a multiple of 128): a narrower minor dim is padded to
+128 lanes in HBM, which doubles a 64-lane pool's footprint, and Mosaic
+refuses a DMA of such a slice.
 
 Kernels are written for TPU and validated on CPU with ``interpret=True``
 (see tests/test_kernels_leap_copy.py); ``ops.py`` picks the implementation.
@@ -20,47 +23,59 @@ Kernels are written for TPU and validated on CPU with ``interpret=True``
 from __future__ import annotations
 
 import jax
-import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _copy_kernel(idx_ref, src_ref, dst_ref):
-    """One grid step moves one whole block (index_map did the addressing)."""
-    dst_ref[...] = src_ref[...]
+def _dma(src, dst, sem):
+    copy = pltpu.make_async_copy(src, dst, sem)
+    copy.start()
+    copy.wait()
 
 
-def _scatter_kernel(idx_ref, blocks_ref, pool_ref, out_ref):
-    # pool_ref is the aliased destination (read-ignored); untouched slots are
-    # preserved by the input/output aliasing.
-    del pool_ref
-    out_ref[...] = blocks_ref[...]
+def _grid_spec(n_prefetch: int, n_in: int, k: int) -> pltpu.PrefetchScalarGridSpec:
+    """``k`` sequential DMA steps; the ``n_in`` array operands and the output
+    stay in HBM (``pl.ANY``), the slot ids are scalar-prefetched."""
+    return pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=n_prefetch,
+        grid=(k,),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * n_in,
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        scratch_shapes=[pltpu.SemaphoreType.DMA(())],
+    )
+
+
+def _out_like(pool: jax.Array, shape) -> jax.ShapeDtypeStruct:
+    """Output type: the pool's dtype, varying over the same mesh axes as the
+    pool (required when a kernel runs inside ``shard_map``)."""
+    return jax.ShapeDtypeStruct(shape, pool.dtype, vma=jax.typeof(pool).vma)
+
+
+def _check(pool: jax.Array) -> None:
+    if pool.ndim < 2:
+        raise ValueError(f"pool must be [slots, *block], got {pool.shape}")
 
 
 def gather_blocks_pallas(
     pool: jax.Array, idx: jax.Array, *, interpret: bool = False
 ) -> jax.Array:
-    """Gather ``pool[idx]`` -> ``[K, *block]`` with one block per grid step.
+    """Gather ``pool[idx]`` -> ``[K, *block]``, one block DMA per grid step.
 
-    pool: ``[S, r, d]`` region-local physical slots.
-    idx:  ``[K]`` int32 slot ids (scalar-prefetched; drive the index_map).
+    pool: ``[S, *block]`` region-local physical slots.
+    idx:  ``[K]`` int32 slot ids (scalar-prefetched; address the DMAs).
     """
-    if pool.ndim != 3:
-        raise ValueError(f"pool must be [slots, rows, cols], got {pool.shape}")
-    s, r, d = pool.shape
+    _check(pool)
     k = idx.shape[0]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(k,),
-        in_specs=[
-            pl.BlockSpec((1, r, d), lambda i, idx_ref: (idx_ref[i], 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, r, d), lambda i, idx_ref: (i, 0, 0)),
-    )
+
+    def kernel(idx_ref, pool_ref, out_ref, sem):
+        i = pl.program_id(0)
+        _dma(pool_ref.at[idx_ref[i]], out_ref.at[i], sem)
+
     return pl.pallas_call(
-        _copy_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((k, r, d), pool.dtype),
+        kernel,
+        grid_spec=_grid_spec(1, 1, k),
+        out_shape=_out_like(pool, (k,) + pool.shape[1:]),
+        name="leap_gather_blocks",
         interpret=interpret,
     )(idx, pool)
 
@@ -70,36 +85,28 @@ def scatter_blocks_pallas(
 ) -> jax.Array:
     """Scatter ``blocks`` into ``pool`` at slot ids ``idx`` (in-place via aliasing).
 
-    pool:   ``[S, r, d]`` (donated/aliased to the output — no pool copy).
+    pool:   ``[S, *block]`` (donated/aliased to the output — no pool copy).
     idx:    ``[K]`` int32 destination slots; duplicate ids: last grid step wins
-            (TPU grid steps are sequential).
-    blocks: ``[K, r, d]``.
+            (grid steps are sequential and each waits for its DMA).
+    blocks: ``[K, *block]``.
     """
-    if pool.ndim != 3:
-        raise ValueError(f"pool must be [slots, rows, cols], got {pool.shape}")
-    s, r, d = pool.shape
+    _check(pool)
     k = idx.shape[0]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(k,),
-        in_specs=[
-            pl.BlockSpec((1, r, d), lambda i, idx_ref: (i, 0, 0)),  # src block i
-            pl.BlockSpec((1, r, d), lambda i, idx_ref: (idx_ref[i], 0, 0)),  # pool
-        ],
-        out_specs=pl.BlockSpec((1, r, d), lambda i, idx_ref: (idx_ref[i], 0, 0)),
-    )
+
+    def kernel(idx_ref, blocks_ref, pool_ref, out_ref, sem):
+        del pool_ref  # aliased to out_ref: untouched slots are preserved
+        i = pl.program_id(0)
+        _dma(blocks_ref.at[i], out_ref.at[idx_ref[i]], sem)
+
     return pl.pallas_call(
-        _scatter_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s, r, d), pool.dtype),
+        kernel,
+        grid_spec=_grid_spec(1, 2, k),
+        out_shape=_out_like(pool, pool.shape),
         # alias indices count every operand incl. scalar prefetch: pool is #2
         input_output_aliases={2: 0},
+        name="leap_scatter_blocks",
         interpret=interpret,
     )(idx, blocks, pool)
-
-
-def _copy_pool_kernel(src_idx_ref, dst_idx_ref, pool_ref, out_ref):
-    out_ref[...] = pool_ref[...]
 
 
 def copy_blocks_pallas(
@@ -111,27 +118,24 @@ def copy_blocks_pallas(
 ) -> jax.Array:
     """Fused intra-pool copy: ``pool[dst_idx[i]] = pool[src_idx[i]]``.
 
-    The same-region fast path of a migration (e.g. defragmentation or a
-    single-device test): one grid step reads slot ``src_idx[i]`` and writes
-    slot ``dst_idx[i]`` without a staging buffer.
+    The same-device path of a migration (regions that share one chip's HBM,
+    or defragmentation): one grid step DMAs slot ``src_idx[i]`` to slot
+    ``dst_idx[i]`` of the aliased pool, without a staging buffer.
+    Destinations must be disjoint from sources (fresh allocations are).
     """
-    if pool.ndim != 3:
-        raise ValueError(f"pool must be [slots, rows, cols], got {pool.shape}")
-    s, r, d = pool.shape
+    _check(pool)
     k = src_idx.shape[0]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(k,),
-        in_specs=[
-            pl.BlockSpec((1, r, d), lambda i, src_ref, dst_ref: (src_ref[i], 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, r, d), lambda i, src_ref, dst_ref: (dst_ref[i], 0, 0)),
-    )
+
+    def kernel(src_ref, dst_ref, pool_ref, out_ref, sem):
+        i = pl.program_id(0)
+        _dma(pool_ref.at[src_ref[i]], out_ref.at[dst_ref[i]], sem)
+
     return pl.pallas_call(
-        _copy_pool_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s, r, d), pool.dtype),
+        kernel,
+        grid_spec=_grid_spec(2, 1, k),
+        out_shape=_out_like(pool, pool.shape),
         input_output_aliases={2: 0},  # pool aliased to output
+        name="leap_copy_blocks",
         interpret=interpret,
     )(src_idx, dst_idx, pool)
 
@@ -147,32 +151,28 @@ def copy_runs_pallas(
     """Contiguous-run copy: ``pool[dst_starts[i] : +run] = pool[src_starts[i] : +run]``.
 
     The huge-block fast path of a two-tier migration: one grid step moves a
-    whole ``run``-slot huge block (``run * rows`` sublanes per DMA instead of
-    ``run`` separate per-slot gathers), double-buffered like the per-block
-    kernel.  Starts must be ``run``-aligned — guaranteed by the buddy
-    allocator, and required because the BlockSpec addresses run-sized tiles.
+    whole ``run``-slot huge block as ONE DMA of ``run`` contiguous slots
+    instead of ``run`` separate per-slot copies.  Starts must be
+    ``run``-aligned — guaranteed by the buddy allocator (G divides S, so a
+    run never straddles a region of the flat view).
     """
-    if pool.ndim != 3:
-        raise ValueError(f"pool must be [slots, rows, cols], got {pool.shape}")
-    s, r, d = pool.shape
+    _check(pool)
+    s = pool.shape[0]
     if run < 1 or s % run != 0:
         raise ValueError(f"run {run} must divide slot count {s}")
     k = src_starts.shape[0]
-    # index_map addresses (run, r, d)-shaped tiles, so pass run-unit indices.
-    src_tiles = src_starts // run
-    dst_tiles = dst_starts // run
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(k,),
-        in_specs=[
-            pl.BlockSpec((run, r, d), lambda i, src_ref, dst_ref: (src_ref[i], 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((run, r, d), lambda i, src_ref, dst_ref: (dst_ref[i], 0, 0)),
-    )
+
+    def kernel(src_ref, dst_ref, pool_ref, out_ref, sem):
+        i = pl.program_id(0)
+        src = pl.multiple_of(src_ref[i], run)
+        dst = pl.multiple_of(dst_ref[i], run)
+        _dma(pool_ref.at[pl.ds(src, run)], out_ref.at[pl.ds(dst, run)], sem)
+
     return pl.pallas_call(
-        _copy_pool_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s, r, d), pool.dtype),
+        kernel,
+        grid_spec=_grid_spec(2, 1, k),
+        out_shape=_out_like(pool, pool.shape),
         input_output_aliases={2: 0},  # pool aliased to output
+        name="leap_copy_runs",
         interpret=interpret,
-    )(src_tiles, dst_tiles, pool)
+    )(src_starts, dst_starts, pool)

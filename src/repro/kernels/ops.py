@@ -32,9 +32,8 @@ def _resolve(impl: str | None) -> tuple[str, bool]:
 #
 # The ``*_impl`` functions are the un-jitted dispatchers: the migrator's fused
 # device programs (repro.core.migrator) call them from inside their own jit so
-# TPU gets the scalar-prefetched double-buffered Pallas path without a nested
-# dispatch.  The jitted wrappers below remain the public standalone entry
-# points.
+# TPU gets the HBM-to-HBM DMA kernels without a nested dispatch.  The jitted
+# wrappers below remain the public standalone entry points.
 
 
 def gather_blocks_impl(pool, idx, *, impl: str | None = None):
@@ -80,25 +79,30 @@ copy_runs = jax.jit(copy_runs_impl, static_argnames=("run", "impl"), donate_argn
 # -- paged decode attention ----------------------------------------------------
 
 
-@functools.partial(jax.jit, static_argnames=("softcap", "kv_heads", "impl"))
+_PAGED_STATIC = ("softcap", "kv_heads", "layer", "impl")
+
+
+@functools.partial(jax.jit, static_argnames=_PAGED_STATIC)
 def paged_decode(
     q,  # [B, H, hd]
-    kv_pool,  # [S, 2, BLK, KVH, hd]
+    kv_pool,  # [S, L, 2, BLK, KVH*hd]
     tables,  # [B, MAXB]
     lens,  # [B]
     *,
     kv_heads: int,
+    layer: int = 0,
     softcap: float = 0.0,
     impl: str | None = None,
 ):
-    """One decode step of paged attention; returns ``out [B, H, hd]``."""
+    """One decode step of paged attention over ``layer``; returns ``out [B, H, hd]``."""
     out, _, _ = paged_decode_partial(
-        q, kv_pool, tables, lens, kv_heads=kv_heads, softcap=softcap, impl=impl
+        q, kv_pool, tables, lens, kv_heads=kv_heads, layer=layer, softcap=softcap,
+        impl=impl,
     )
     return out
 
 
-@functools.partial(jax.jit, static_argnames=("softcap", "kv_heads", "impl"))
+@functools.partial(jax.jit, static_argnames=_PAGED_STATIC)
 def paged_decode_partial(
     q,
     kv_pool,
@@ -106,6 +110,7 @@ def paged_decode_partial(
     lens,
     *,
     kv_heads: int,
+    layer: int = 0,
     softcap: float = 0.0,
     impl: str | None = None,
 ):
@@ -116,7 +121,7 @@ def paged_decode_partial(
     kind, interp = _resolve(impl)
     # pad-position table entries must be valid slot ids for the index map
     maxb = tables.shape[1]
-    blk = kv_pool.shape[2]
+    blk = kv_pool.shape[3]
     n_valid = (lens[:, None] + blk - 1) // blk
     safe_tables = jnp.where(
         jnp.arange(maxb)[None, :] < n_valid, tables, 0
@@ -124,10 +129,13 @@ def paged_decode_partial(
     if kind == "pallas":
         qg = q.reshape(b, kv_heads, g, hd)
         out, m, l = paged_attn.paged_decode_pallas(
-            qg, kv_pool, safe_tables, lens, softcap=softcap, interpret=interp
+            qg, kv_pool, safe_tables, lens, layer=layer, softcap=softcap,
+            interpret=interp,
         )
         return out.reshape(b, h, hd), m.reshape(b, h), l.reshape(b, h)
-    return ref.paged_decode_ref(q, kv_pool, safe_tables, lens, softcap=softcap)
+    return ref.paged_decode_ref(
+        q, kv_pool, safe_tables, lens, kv_heads=kv_heads, layer=layer, softcap=softcap
+    )
 
 
 combine_partials = ref.combine_partials

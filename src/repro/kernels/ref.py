@@ -40,13 +40,15 @@ def copy_runs_ref(
 
 def paged_decode_ref(
     q: jax.Array,  # [B, H, hd]
-    kv_pool: jax.Array,  # [S, 2, BLK, KVH, hd]
+    kv_pool: jax.Array,  # [S, L, 2, BLK, KVH*hd]
     tables: jax.Array,  # [B, MAXB] int32 slot ids (padded arbitrarily)
     lens: jax.Array,  # [B] int32 tokens per sequence
     *,
+    kv_heads: int,
+    layer: int = 0,
     softcap: float = 0.0,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Oracle: full-precision paged attention for one decode step.
+    """Oracle: full-precision paged attention over one layer of the pool.
 
     Returns ``(out [B,H,hd], m [B,H], l [B,H])`` where m/l are the softmax
     running max and normalizer (fp32) so that shard partials combine as::
@@ -55,14 +57,15 @@ def paged_decode_ref(
         out* = sum_i out_i l_i exp(m_i - m*) / l*
     """
     b, h, hd = q.shape
-    s, _, blk, kvh, _ = kv_pool.shape
+    blk = kv_pool.shape[3]
+    kvh = kv_heads
     maxb = tables.shape[1]
     g = h // kvh
     scale = 1.0 / (hd**0.5)
 
     def per_seq(qb, tab, ln):
-        k = kv_pool[tab, 0].reshape(maxb * blk, kvh, hd).astype(jnp.float32)
-        v = kv_pool[tab, 1].reshape(maxb * blk, kvh, hd).astype(jnp.float32)
+        k = kv_pool[tab, layer, 0].reshape(maxb * blk, kvh, hd).astype(jnp.float32)
+        v = kv_pool[tab, layer, 1].reshape(maxb * blk, kvh, hd).astype(jnp.float32)
         qg = (qb.astype(jnp.float32) * scale).reshape(kvh, g, hd)
         scores = jnp.einsum("kgd,tkd->kgt", qg, k)  # [KVH, G, T]
         if softcap:

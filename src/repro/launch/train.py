@@ -20,6 +20,7 @@ import jax
 from repro.configs.base import ARCH_IDS, canon, get_config
 from repro.configs.smoke import reduce
 from repro.data.synthetic import DataConfig, SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.train.optimizer import OptimizerConfig
 from repro.train.train_step import TrainConfig
 from repro.train.trainer import Trainer, TrainerConfig
@@ -38,6 +39,7 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=50)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(canon(args.arch))
     if args.smoke:
         cfg = reduce(cfg)

@@ -51,11 +51,10 @@ def init_params(key, cfg: ModelConfig) -> dict:
     kidx = 2
     stacked = []
     for pos, kind in enumerate(period):
-        layers = [
-            B.block_init(keys[kidx + rep * len(period) + pos], cfg, kind)
-            for rep in range(cfg.repeats)
-        ]
-        stacked.append(jax.tree.map(lambda *xs: jnp.stack(xs), *layers))
+        # repeat r of position pos takes key kidx + r * len(period) + pos;
+        # vmap builds the stacked leaves directly (no per-layer copies)
+        layer_keys = keys[kidx + pos : kidx + cfg.repeats * len(period) : len(period)]
+        stacked.append(jax.vmap(lambda k, kind=kind: B.block_init(k, cfg, kind))(layer_keys))
     params["period"] = stacked
     kidx += cfg.repeats * len(period)
     params["tail"] = [

@@ -4,11 +4,14 @@ between regions *while decoding continues* — the serving-side integration
 of the paper's technique (DESIGN.md §4).
 
 One page = one token-range across ALL layers: payload
-``[L, 2, BLK, kv_heads, head_dim]`` (so migrating a sequence is one area).
-The decode hot loop uses ``repro.kernels.ops.paged_decode`` (Pallas on TPU,
-oracle elsewhere).  Supported stacks: uniform global-attention patterns
-("attn"/"moe" kinds); window/recurrent stacks serve via the contiguous
-cache path in ``launch/serve.py``.
+``[L, 2, BLK, kv_heads * head_dim]`` (so migrating a sequence is one area).
+The heads share the minor dim so a page is lane-dense on TPU whatever the
+head width: a 64-wide minor dim would be padded to 128 lanes in HBM.  The
+decode hot loop uses ``repro.kernels.ops.paged_decode_partial`` (Pallas on
+TPU, oracle elsewhere) directly on the pool, one layer per call.  Supported
+stacks: uniform global-attention patterns ("attn"/"moe" kinds);
+window/recurrent stacks serve via the contiguous cache path in
+``launch/serve.py``.
 
 Regions: on a mesh, pool dim 0 shards over the data axis and each region
 serves its resident sequences; on one device (tests/benches) regions are
@@ -26,7 +29,7 @@ import numpy as np
 from repro.api import LeapHandle, Move
 from repro.configs.base import ModelConfig
 from repro.core import LeapConfig, MigrationDriver, PoolConfig, init_state
-from repro.core.state import REGION, SLOT
+from repro.core.state import REGION, SLOT, flat_pool_view
 from repro.kernels import ops
 from repro.models import lm
 from repro.obs.metrics import LATENCY_TICK_BUCKETS, Histogram
@@ -77,28 +80,6 @@ class Sequence:
     promoted: set = dataclasses.field(default_factory=set)  # huge group ids
 
 
-def _kv_write_impl(state, block_ids, offsets, k_new, v_new):
-    """Append one token's K/V (all layers) into its page; leap-dirty fused.
-
-    block_ids/offsets: [B]; k_new/v_new: [B, L, KVH, hd].
-    """
-    loc = state.table[block_ids]
-    r, s = loc[:, REGION], loc[:, SLOT]
-    pool = state.pool
-    kv = jnp.stack([k_new, v_new], axis=2)  # [B, L, 2, KVH, hd]
-    pool = pool.at[r, s, :, :, offsets].set(kv.astype(pool.dtype))
-    dirty = state.dirty.at[block_ids].set(
-        state.dirty[block_ids] | state.in_flight[block_ids]
-    )
-    return dataclasses.replace(state, pool=pool, dirty=dirty)
-
-
-# Standalone jitted form (donates state).  The decode path instead traces
-# _kv_write_impl inside the engine's whole-step jit, where donation lives on
-# the outer call — nesting a donating jit inside another jit is a no-op.
-_kv_write = jax.jit(_kv_write_impl, donate_argnames=("state",))
-
-
 class PagedEngine:
     """Batched decode over a migration-managed paged KV cache."""
 
@@ -114,13 +95,7 @@ class PagedEngine:
         self.cfg = cfg
         self.params = params
         self.pcfg = pcfg
-        payload = (
-            cfg.n_layers,
-            2,
-            pcfg.block_tokens,
-            cfg.n_kv_heads,
-            cfg.head_dim,
-        )
+        payload = (cfg.n_layers, 2, pcfg.block_tokens, cfg.n_kv_heads * cfg.head_dim)
         G = pcfg.huge_factor
         self.pool_cfg = PoolConfig(
             pcfg.n_regions,
@@ -259,8 +234,6 @@ class PagedEngine:
             self._prefill_fns[n] = fn
         logits, cache = fn(self.params, toks)
         first_tok = int(jnp.argmax(logits, -1)[0])
-        # contiguous cache -> pages
-        k, v = _flatten_cache(cache, cfg)  # [L, S, KVH, hd]
         s = len(prompt)
         sid = self._next_sid
         self._next_sid += 1
@@ -268,14 +241,11 @@ class PagedEngine:
             sid, region, s, [], list(map(int, prompt)) + [first_tok], tenant=tenant
         )
         n_blocks = (s + blk - 1) // blk
-        for j in range(n_blocks):
-            b = self._alloc_block(region, sid)
-            seq.block_ids.append(b)
-            lo, hi = j * blk, min((j + 1) * blk, s)
-            page = jnp.zeros(self.pool_cfg.block_shape, cfg.dtype())
-            page = page.at[:, 0, : hi - lo].set(k[:, lo:hi])
-            page = page.at[:, 1, : hi - lo].set(v[:, lo:hi])
-            self.driver.write(jnp.asarray([b]), page[None])
+        seq.block_ids = [self._alloc_block(region, sid) for _ in range(n_blocks)]
+        # contiguous cache -> pages, installed with one write
+        self.driver.write(
+            jnp.asarray(seq.block_ids, jnp.int32), _cache_pages(cache, cfg, blk)
+        )
         self.seqs[sid] = seq
         return sid
 
@@ -320,10 +290,9 @@ class PagedEngine:
                     [np.asarray(self.seqs[s].block_ids, np.int32) for s in sids]
                 )
             )
-        toks = jnp.asarray([[self.seqs[s].tokens[-1]] for s in sids], jnp.int32)
         self._decode_shapes.add(len(sids))
         logits, self.driver.state = self._decode_step(
-            self.params, self.driver.state, tables, lens, toks
+            self.params, self.driver.state, tables, lens, self._last_tokens(sids)
         )
         out = np.asarray(jnp.argmax(logits, -1))
         for i, sid in enumerate(sids):
@@ -331,6 +300,18 @@ class PagedEngine:
             seq.tokens.append(int(out[i]))
             seq.length += 1
         return [int(t) for t in out]
+
+    def _last_tokens(self, sids):
+        return jnp.asarray([[self.seqs[s].tokens[-1]] for s in sids], jnp.int32)
+
+    def lower_decode(self, sids: list[int]):
+        """The decode step for ``sids`` lowered on the arguments
+        :meth:`decode` passes (``.compile().as_text()`` shows which kernels
+        the compiled step runs)."""
+        tables, lens = self._tables(sids)
+        return self._decode_step.lower(
+            self.params, self.driver.state, tables, lens, self._last_tokens(sids)
+        )
 
     # -- tier promotion -----------------------------------------------------------
 
@@ -513,45 +494,48 @@ class PagedEngine:
         return self.session.drain()
 
 
-def _flatten_cache(cache, cfg: ModelConfig):
-    """lm prefill cache -> (k, v) each [L, S, KVH, hd] (batch 1)."""
-    ks, vs = [], []
+def _cache_pages(cache, cfg: ModelConfig, blk: int):
+    """lm prefill cache (batch 1, S tokens) -> pages ``[ceil(S/blk), L, 2,
+    blk, KVH*hd]`` in layer order, zero-padded past the last token."""
     per = len(cfg.layer_pattern)
-    for pos in range(per):
-        c = cache["period"][pos]
-        # [repeats, 1, S, KVH, hd] -> interleave into layer order later
-        ks.append(np.asarray(c["k"][:, 0]))
-        vs.append(np.asarray(c["v"][:, 0]))
-    L = cfg.n_layers
-    s = ks[0].shape[1]
-    k = np.zeros((L, s) + ks[0].shape[2:], ks[0].dtype)
-    v = np.zeros_like(k)
-    for rep in range(cfg.repeats):
-        for pos in range(per):
-            k[rep * per + pos] = ks[pos][rep]
-            v[rep * per + pos] = vs[pos][rep]
-    for i, c in enumerate(cache["tail"]):
-        k[cfg.repeats * per + i] = np.asarray(c["k"][0])
-        v[cfg.repeats * per + i] = np.asarray(c["v"][0])
-    return jnp.asarray(k), jnp.asarray(v)
+
+    def layers(name):
+        # period caches are [repeats, 1, S, KVH, hd] per pattern position;
+        # layer rep*per + pos comes from position pos of repeat rep
+        x = jnp.stack([cache["period"][p][name][:, 0] for p in range(per)], axis=1)
+        return x.reshape((cfg.repeats * per,) + x.shape[2:])  # [L, S, KVH, hd]
+
+    kv = jnp.stack([layers("k"), layers("v")], axis=1)  # [L, 2, S, KVH, hd]
+    n_layers, _, s = kv.shape[:3]
+    n_pages = -(-s // blk)
+    kv = kv.reshape(n_layers, 2, s, -1)
+    kv = jnp.pad(kv, ((0, 0), (0, 0), (0, n_pages * blk - s), (0, 0)))
+    kv = kv.reshape(n_layers, 2, n_pages, blk, -1)
+    return jnp.moveaxis(kv, 2, 0)
 
 
 def _paged_step(params, state, tables, lens, toks, cfg: ModelConfig, blk: int):
-    """One decode token through paged attention for every layer."""
+    """One decode token through paged attention for every layer.
+
+    Each layer appends its new K/V into the pool in place, then attends over
+    the pool itself (the kernel indexes the layer; no per-layer copy of the
+    pool is made).  The appended blocks are marked dirty when in flight.
+    """
     b = toks.shape[0]
     x = lm.embed_tokens(params, toks, cfg)
     pos = lens  # per-sequence position (tokens cached so far)
-    flat_tables = state.table[tables.reshape(-1)]  # [(B*MAXB), 2]
     s_per = state.pool.shape[1]
+    flat_tables = state.table[tables.reshape(-1)]  # [(B*MAXB), 2]
     flat = (flat_tables[:, 0] * s_per + flat_tables[:, 1]).reshape(tables.shape)
-    pool_flat = state.pool.reshape((-1,) + state.pool.shape[2:])
+    pool = flat_pool_view(state.pool)  # [R*S, L, 2, BLK, KVH*hd]
     append_block = tables[jnp.arange(b), lens // blk]
+    loc = state.table[append_block]
+    append_slot = loc[:, REGION] * s_per + loc[:, SLOT]
     offset = lens % blk
+    at_offset = (jnp.arange(blk)[None, :] == offset[:, None])[:, None, :, None]
 
     period = cfg.layer_pattern
     # layers unrolled (engine/demo path; the dry-run path scans)
-    new_k = []
-    new_v = []
     li = 0
     stacked = params["period"]
     for rep in range(cfg.repeats):
@@ -559,23 +543,20 @@ def _paged_step(params, state, tables, lens, toks, cfg: ModelConfig, blk: int):
             lp = jax.tree.map(lambda t: t[rep], stacked[p_i])
             h = rms_norm(x, lp["norm1"], cfg.norm_eps)
             q, k, v = _project_qkv(h, lp["attn"], cfg, pos[:, None])
-            new_k.append(k[:, 0])
-            new_v.append(v[:, 0])
-            # write this layer's new token kv, then attend over len+1 tokens
-            kv_pool_l = jax.lax.dynamic_index_in_dim(
-                pool_flat, li, axis=1, keepdims=False
-            )  # [S_flat, 2, BLK, KVH, hd]
-            kv_pool_l = kv_pool_l.at[
-                state.table[append_block, 0] * s_per + state.table[append_block, 1],
-                :,
-                offset,
-            ].set(jnp.stack([k[:, 0], v[:, 0]], axis=1).astype(kv_pool_l.dtype))
+            # Rewrite the whole [2, BLK, W] tile holding the new token: a
+            # scatter of single rows would make XLA re-lay the pool out (and
+            # copy it) around every layer's kernel call.
+            kv = jnp.stack([k[:, 0], v[:, 0]], axis=1).reshape(b, 2, 1, -1)
+            tile = pool[append_slot, li]  # [B, 2, BLK, W]
+            tile = jnp.where(at_offset, kv.astype(pool.dtype), tile)
+            pool = pool.at[append_slot, li].set(tile)
             out, _, _ = ops.paged_decode_partial(
                 q[:, 0],
-                kv_pool_l,
+                pool,
                 flat,
                 lens + 1,
                 kv_heads=cfg.n_kv_heads,
+                layer=li,
                 softcap=cfg.attn_softcap,
             )
             y = out.reshape(b, 1, -1) @ lp["attn"]["wo"]
@@ -588,8 +569,10 @@ def _paged_step(params, state, tables, lens, toks, cfg: ModelConfig, blk: int):
             x = x + y2
             li += 1
     logits = lm.lm_logits(params, x, cfg)[:, 0]
-    # persist the appended kv of every layer through the leap-aware write
-    k_all = jnp.stack(new_k, axis=1)  # [B, L, KVH, hd]
-    v_all = jnp.stack(new_v, axis=1)
-    state = _kv_write_impl(state, append_block, offset, k_all, v_all)
+    dirty = state.dirty.at[append_block].set(
+        state.dirty[append_block] | state.in_flight[append_block]
+    )
+    state = dataclasses.replace(
+        state, pool=pool.reshape(state.pool.shape), dirty=dirty
+    )
     return logits, state

@@ -26,7 +26,8 @@ def run_sub(body: str) -> str:
     out = subprocess.run(
         [sys.executable, "-c", code],
         capture_output=True, text=True, timeout=600,
-        env={**os.environ, "PYTHONPATH": os.path.join(REPO, "src")},
+        # the child never reaches for an accelerator the parent may hold
+        env={**os.environ, "PYTHONPATH": os.path.join(REPO, "src"), "JAX_PLATFORMS": "cpu"},
     )
     assert out.returncode == 0, f"stdout:\n{out.stdout}\nstderr:\n{out.stderr[-3000:]}"
     return out.stdout

@@ -9,6 +9,8 @@ import pytest
 from repro.kernels import ops, ref
 from repro.kernels.paged_attn import paged_decode_pallas
 
+N_LAYERS, LAYER = 2, 1
+
 # (B, H, KVH, hd, BLK, MAXB)
 CASES = [
     (2, 4, 2, 64, 8, 4),
@@ -22,7 +24,8 @@ def _setup(b, h, kvh, hd, blk, maxb, dtype, seed=0):
     rng = np.random.default_rng(seed)
     s = b * maxb + 4
     q = jnp.asarray(rng.normal(size=(b, h, hd)), dtype)
-    kv_pool = jnp.asarray(rng.normal(size=(s, 2, blk, kvh, hd)), dtype)
+    # page payload [L, 2, BLK, KVH*hd]; the tests attend over layer LAYER
+    kv_pool = jnp.asarray(rng.normal(size=(s, N_LAYERS, 2, blk, kvh * hd)), dtype)
     # unique slots per sequence (a real block table never double-maps)
     slots = rng.choice(s, size=(b, maxb), replace=False)
     tables = jnp.asarray(slots, jnp.int32)
@@ -41,9 +44,11 @@ def test_paged_decode_matches_oracle(case, dtype):
     q, kv_pool, tables, lens = _setup(*case, dtype)
     g = h // kvh
     out, m, l = paged_decode_pallas(
-        q.reshape(b, kvh, g, hd), kv_pool, tables, lens, interpret=True
+        q.reshape(b, kvh, g, hd), kv_pool, tables, lens, layer=LAYER, interpret=True
     )
-    want_out, want_m, want_l = ref.paged_decode_ref(q, kv_pool, tables, lens)
+    want_out, want_m, want_l = ref.paged_decode_ref(
+        q, kv_pool, tables, lens, kv_heads=kvh, layer=LAYER
+    )
     np.testing.assert_allclose(
         np.asarray(out.reshape(b, h, hd), np.float32),
         np.asarray(want_out, np.float32),
@@ -62,14 +67,17 @@ def test_paged_decode_softcap():
     q, kv_pool, tables, lens = _setup(*case, jnp.float32, seed=7)
     b, h, kvh, hd, blk, maxb = case
     out, m, l = paged_decode_pallas(
-        q.reshape(b, kvh, h // kvh, hd), kv_pool, tables, lens, softcap=20.0, interpret=True
+        q.reshape(b, kvh, h // kvh, hd), kv_pool, tables, lens,
+        softcap=20.0, layer=LAYER, interpret=True,
     )
-    want, _, _ = ref.paged_decode_ref(q, kv_pool, tables, lens, softcap=20.0)
+    want, _, _ = ref.paged_decode_ref(
+        q, kv_pool, tables, lens, softcap=20.0, kv_heads=kvh, layer=LAYER
+    )
     np.testing.assert_allclose(
         np.asarray(out.reshape(b, h, hd)), np.asarray(want), rtol=2e-5, atol=2e-5
     )
     # softcap must actually change the result
-    plain, _, _ = ref.paged_decode_ref(q, kv_pool, tables, lens)
+    plain, _, _ = ref.paged_decode_ref(q, kv_pool, tables, lens, kv_heads=kvh, layer=LAYER)
     assert not np.allclose(np.asarray(want), np.asarray(plain))
 
 
@@ -78,9 +86,9 @@ def test_paged_decode_single_token_sequences():
     q, kv_pool, tables, _ = _setup(b, h, kvh, hd, blk, maxb, jnp.float32, seed=3)
     lens = jnp.ones((b,), jnp.int32)  # attention over exactly one token
     out, m, l = paged_decode_pallas(
-        q.reshape(b, kvh, h // kvh, hd), kv_pool, tables, lens, interpret=True
+        q.reshape(b, kvh, h // kvh, hd), kv_pool, tables, lens, layer=LAYER, interpret=True
     )
-    want, _, _ = ref.paged_decode_ref(q, kv_pool, tables, lens)
+    want, _, _ = ref.paged_decode_ref(q, kv_pool, tables, lens, kv_heads=kvh, layer=LAYER)
     np.testing.assert_allclose(
         np.asarray(out.reshape(b, h, hd)), np.asarray(want), rtol=2e-5, atol=2e-5
     )
@@ -94,13 +102,13 @@ def test_shard_combine_identity():
     b, h, kvh, hd, blk, maxb = 2, 8, 2, 64, 8, 6
     q, kv_pool, tables, _ = _setup(b, h, kvh, hd, blk, maxb, jnp.float32, seed=9)
     lens = jnp.full((b,), maxb * blk, jnp.int32)
-    full, _, _ = ref.paged_decode_ref(q, kv_pool, tables, lens)
+    full, _, _ = ref.paged_decode_ref(q, kv_pool, tables, lens, kv_heads=kvh, layer=LAYER)
     # shard the table into 2 halves of 3 blocks
     outs, ms, ls = [], [], []
     for p in range(2):
         tab = tables[:, p * 3 : (p + 1) * 3]
         ln = jnp.full((b,), 3 * blk, jnp.int32)
-        o, m, l = ref.paged_decode_ref(q, kv_pool, tab, ln)
+        o, m, l = ref.paged_decode_ref(q, kv_pool, tab, ln, kv_heads=kvh, layer=LAYER)
         outs.append(o), ms.append(m), ls.append(l)
     combined = ref.combine_partials(
         jnp.stack(outs), jnp.stack(ms), jnp.stack(ls)
@@ -117,10 +125,10 @@ def test_ops_paged_decode_wrapper():
     for i in range(b):
         tab[i, n_valid[i] :] = 10**6
     out_ref_impl = ops.paged_decode(
-        q, kv_pool, jnp.asarray(tab), lens, kv_heads=kvh, impl="ref"
+        q, kv_pool, jnp.asarray(tab), lens, kv_heads=kvh, layer=LAYER, impl="ref"
     )
     out_pallas = ops.paged_decode(
-        q, kv_pool, jnp.asarray(tab), lens, kv_heads=kvh, impl="pallas_interpret"
+        q, kv_pool, jnp.asarray(tab), lens, kv_heads=kvh, layer=LAYER, impl="pallas_interpret"
     )
     np.testing.assert_allclose(
         np.asarray(out_pallas), np.asarray(out_ref_impl), rtol=2e-5, atol=2e-5
