@@ -226,17 +226,21 @@ class MigrationDriver:
         self.ctx.note_reads(block_ids)
 
     def write(self, block_ids, values) -> None:
-        self.ctx.note_writes(block_ids)
-        self.ctx.state = leap_write(self.ctx.state, jax.numpy.asarray(block_ids), values)
+        ctx = self.ctx
+        with ctx.telemetry.stage("write"):
+            ctx.note_writes(block_ids)
+            ctx.state = leap_write(ctx.state, jax.numpy.asarray(block_ids), values)
 
     def write_rows(self, block_ids, row_offsets, rows) -> None:
-        self.ctx.note_writes(block_ids)
-        self.ctx.state = leap_write_rows(
-            self.ctx.state,
-            jax.numpy.asarray(block_ids),
-            jax.numpy.asarray(row_offsets),
-            rows,
-        )
+        ctx = self.ctx
+        with ctx.telemetry.stage("write"):
+            ctx.note_writes(block_ids)
+            ctx.state = leap_write_rows(
+                ctx.state,
+                jax.numpy.asarray(block_ids),
+                jax.numpy.asarray(row_offsets),
+                rows,
+            )
 
     # -- migration API ------------------------------------------------------
 

@@ -99,6 +99,48 @@ class DispatchStage:
 
     def _run_tick(self, tb: TickBudget) -> None:
         ctx = self.ctx
+        with ctx.telemetry.stage("dispatch.plan"):
+            opened, forced, zeros, plan, run_plan = self._plan(tb)
+        if self._mode == "megastep":
+            # The whole tick — staged commits, begins, zeros, forces, copies —
+            # crosses the host/device boundary as ONE program.  Phase order
+            # inside the program matches the batched generation's cross-
+            # program order; the quarantine note below applies identically.
+            with ctx.telemetry.stage("dispatch.device"):
+                self._dispatch_megastep(opened, zeros, forced, plan, run_plan)
+        elif self._fused:
+            # Device order matters: begin before copy (epoch flags gate dirty
+            # tracking), force before copy (a forced block's freed source slot
+            # may be reallocated as a copy destination next tick), zero-fill
+            # before force AND copy (a fresh area's zero pass must land before
+            # its own force/copy overwrites the same slots with the payload).
+            # This ordering is only sound because slots freed by this tick's
+            # forces are QUARANTINED until the flush below: no open in this
+            # tick can hand a force's still-unread source slot to another
+            # area as a zero/force/copy destination.
+            with ctx.telemetry.stage("dispatch.device"):
+                self._dispatch_begin_batch(opened)
+                self._dispatch_zero_batch(zeros)
+                self._dispatch_force_batch(forced)
+                self._dispatch_copy_batch(plan)
+                self._dispatch_copy_runs(run_plan)
+        if self._mode != "megastep":
+            # Batched/legacy: the tick's access-heat samples flush as their
+            # own program (megastep folds them into its single dispatch).
+            self._flush_heat()
+        # End of tick: every program that reads a forced area's old source
+        # slots is dispatched; release them for the next tick's allocations.
+        for old in self._freed:
+            for r in np.unique(old[:, REGION]):
+                ctx.free[r].put(old[old[:, REGION] == r, SLOT])
+        self._freed = []
+
+    def _plan(self, tb: TickBudget):
+        """The tick's scheduling loop: grant copies to open epochs and open
+        new ones off the queue within ``tb``; requeue congested and blocked
+        areas.  Returns ``(opened, forced, zeros, plan, run_plan)`` for the
+        fused dispatches (legacy mode dispatches as it goes)."""
+        ctx = self.ctx
         fused = self._fused
         skipped: set[int] = set()  # active areas deferred this tick (link dry)
         opened: list[Area] = []  # epochs opened this tick (fused: batch begin)
@@ -171,52 +213,7 @@ class DispatchStage:
             ctx.queue.appendleft(area)
         for area in reversed(blocked):
             ctx.queue.appendleft(area)
-        if self._mode == "megastep":
-            # The whole tick — staged commits, begins, zeros, forces, copies —
-            # crosses the host/device boundary as ONE program.  Phase order
-            # inside the program matches the batched generation's cross-
-            # program order; the quarantine note below applies identically.
-            with ctx.telemetry.stage(
-                "dispatch.device",
-                opened=len(opened),
-                forced=len(forced),
-                copy_chunks=len(plan),
-                huge_runs=len(run_plan),
-                committed=len(self._staged_small) + len(self._staged_huge),
-            ):
-                self._dispatch_megastep(opened, zeros, forced, plan, run_plan)
-        elif fused:
-            # Device order matters: begin before copy (epoch flags gate dirty
-            # tracking), force before copy (a forced block's freed source slot
-            # may be reallocated as a copy destination next tick), zero-fill
-            # before force AND copy (a fresh area's zero pass must land before
-            # its own force/copy overwrites the same slots with the payload).
-            # This ordering is only sound because slots freed by this tick's
-            # forces are QUARANTINED until the flush below: no open in this
-            # tick can hand a force's still-unread source slot to another
-            # area as a zero/force/copy destination.
-            with ctx.telemetry.stage(
-                "dispatch.device",
-                opened=len(opened),
-                forced=len(forced),
-                copy_chunks=len(plan),
-                huge_runs=len(run_plan),
-            ):
-                self._dispatch_begin_batch(opened)
-                self._dispatch_zero_batch(zeros)
-                self._dispatch_force_batch(forced)
-                self._dispatch_copy_batch(plan)
-                self._dispatch_copy_runs(run_plan)
-        if self._mode != "megastep":
-            # Batched/legacy: the tick's access-heat samples flush as their
-            # own program (megastep folds them into its single dispatch).
-            self._flush_heat()
-        # End of tick: every program that reads a forced area's old source
-        # slots is dispatched; release them for the next tick's allocations.
-        for old in self._freed:
-            for r in np.unique(old[:, REGION]):
-                ctx.free[r].put(old[old[:, REGION] == r, SLOT])
-        self._freed = []
+        return opened, forced, zeros, plan, run_plan
 
     def quarantined_slots(self) -> np.ndarray:
         """Copy of the current force-freed slot quarantine: ``(region, slot)``
@@ -569,150 +566,155 @@ class DispatchStage:
         ctx = self.ctx
         small, huge = self._staged_small, self._staged_huge
         self._staged_small, self._staged_huge = [], []
-        heat_ids, heat_w = self._pop_heat()
-        if not (
-            small
-            or huge
-            or opened
-            or zeros
-            or forced
-            or plan
-            or run_plan
-            or len(heat_ids)
-        ):
-            return
-        pc = ctx.pool_cfg
-        S = pc.slots_per_region
-        n_blocks = len(ctx.table)
-        G = pc.huge_factor
+        with ctx.telemetry.stage("dispatch.operands"):
+            heat_ids, heat_w = self._pop_heat()
+            if not (
+                small
+                or huge
+                or opened
+                or zeros
+                or forced
+                or plan
+                or run_plan
+                or len(heat_ids)
+            ):
+                return
+            pc = ctx.pool_cfg
+            S = pc.slots_per_region
+            n_blocks = len(ctx.table)
+            G = pc.huge_factor
 
-        def cat(parts: list[np.ndarray]) -> np.ndarray:
-            if not parts:
-                return np.zeros(0, np.int32)
-            return np.concatenate(parts).astype(np.int32, copy=False)
+            def cat(parts: list[np.ndarray]) -> np.ndarray:
+                if not parts:
+                    return np.zeros(0, np.int32)
+                return np.concatenate(parts).astype(np.int32, copy=False)
 
-        commit_ids = cat([a.block_ids for a in small])
-        commit_regions = cat([np.full(len(a), a.dst_region, np.int32) for a in small])
-        commit_slots = cat([a.dst_slots for a in small])
-        offsets = np.cumsum([0] + [len(a) for a in small])
-        begin_ids = cat([a.block_ids for a in opened])
-        zero_flat = cat([a.dst_region * S + a.dst_slots for a in zeros])
-        force_ids = cat([a.block_ids for a in forced])
-        force_regions = cat([np.full(len(a), a.dst_region, np.int32) for a in forced])
-        force_slots = cat([a.dst_slots for a in forced])
-        # Copy plan: flat slot ids from the exact host mirror — table entries
-        # of in-flight blocks cannot change until their commit, which this
-        # driver issues (and this tick's commits target disjoint blocks).
-        copy_ids = cat([ids for _, ids, _ in plan])
-        copy_regions = cat(
-            [np.full(len(c), a.dst_region, np.int32) for a, c, _ in plan]
-        )
-        copy_slots = cat([s for _, _, s in plan])
-        copy_src = (ctx.table[copy_ids, REGION] * S + ctx.table[copy_ids, SLOT]).astype(
-            np.int32
-        )
-        copy_dst = (copy_regions * S + copy_slots).astype(np.int32)
-        if len(copy_ids):
-            ctx.count("bytes_copied", len(copy_ids) * pc.block_bytes)
-
-        B = self._megastep_bucket(
-            len(commit_ids),
-            len(begin_ids),
-            len(zero_flat),
-            len(force_ids),
-            len(copy_src),
-        )
-        pad = self._pad_sentinel
-        if len(commit_ids):
-            commit_ids = pad(commit_ids, B, n_blocks)
-            commit_regions = pad(commit_regions, B, pc.n_regions)
-            commit_slots = pad(commit_slots, B, S)
-        if len(begin_ids):
-            begin_ids = pad(begin_ids, B, n_blocks)
-        if len(zero_flat):
-            zero_flat = pad(zero_flat, B, pc.n_regions * S)
-        if len(force_ids):
-            force_ids = pad(force_ids, B, n_blocks)
-            force_regions = pad(force_regions, B, pc.n_regions)
-            force_slots = pad(force_slots, B, S)
-        if len(copy_src):
-            copy_src, copy_dst = pad_to_bucket(B, copy_src, copy_dst)
-
-        # Huge-tier buckets are floored at the tick's huge capacity
-        # (budget / G groups), mirroring the per-block floor: every
-        # group-commit and run-copy tick shares one compiled variant.
-        huge_floor = max(1, ctx.cfg.budget_blocks_per_tick // G)
-        k = len(huge)
-        if k:
-            kb = bucket_size(max(k, huge_floor), ctx.cfg.bucket_growth)
-            members = np.concatenate([a.block_ids for a in huge]).reshape(k, G)
-            members = np.concatenate(
-                [members, np.repeat(members[:1], kb - k, axis=0)]
+            commit_ids = cat([a.block_ids for a in small])
+            commit_regions = cat([np.full(len(a), a.dst_region, np.int32) for a in small])
+            commit_slots = cat([a.dst_slots for a in small])
+            offsets = np.cumsum([0] + [len(a) for a in small])
+            begin_ids = cat([a.block_ids for a in opened])
+            zero_flat = cat([a.dst_region * S + a.dst_slots for a in zeros])
+            force_ids = cat([a.block_ids for a in forced])
+            force_regions = cat([np.full(len(a), a.dst_region, np.int32) for a in forced])
+            force_slots = cat([a.dst_slots for a in forced])
+            # Copy plan: flat slot ids from the exact host mirror — table entries
+            # of in-flight blocks cannot change until their commit, which this
+            # driver issues (and this tick's commits target disjoint blocks).
+            copy_ids = cat([ids for _, ids, _ in plan])
+            copy_regions = cat(
+                [np.full(len(c), a.dst_region, np.int32) for a, c, _ in plan]
             )
-            grp_members = members.reshape(-1).astype(np.int32)
-            grp_regions, grp_starts = pad_to_bucket(
-                kb,
-                np.asarray([a.dst_region for a in huge], np.int32),
-                np.asarray([a.dst_slots[0] for a in huge], np.int32),
+            copy_slots = cat([s for _, _, s in plan])
+            copy_src = (ctx.table[copy_ids, REGION] * S + ctx.table[copy_ids, SLOT]).astype(
+                np.int32
             )
-        else:
-            grp_members = grp_regions = grp_starts = np.zeros(0, np.int32)
-        if run_plan:
-            firsts = np.asarray([a.block_ids[0] for a in run_plan])
-            run_src = (
-                ctx.table[firsts, REGION] * S + ctx.table[firsts, SLOT]
-            ).astype(np.int32)
-            run_dst = np.asarray(
-                [a.dst_region * S + a.dst_slots[0] for a in run_plan], np.int32
-            )
-            rb = bucket_size(max(len(run_plan), huge_floor), ctx.cfg.bucket_growth)
-            run_src, run_dst = pad_to_bucket(rb, run_src, run_dst)
-            nbytes = len(run_plan) * G * pc.block_bytes
-            ctx.count("bytes_copied", nbytes)
-            ctx.count("bytes_copied_huge", nbytes)
-        else:
-            run_src = run_dst = np.zeros(0, np.int32)
+            copy_dst = (copy_regions * S + copy_slots).astype(np.int32)
+            if len(copy_ids):
+                ctx.count("bytes_copied", len(copy_ids) * pc.block_bytes)
 
-        j = jax.numpy.asarray
-        # Heat samples pad at their OWN bucket (sentinel = heat-plane length,
-        # which both paths drop) so a read-heavy tick never inflates the
-        # shared per-block bucket — the heat batch length tracks the access
-        # rate, not the migration budget.
-        n_heat = len(heat_ids)
-        if n_heat:
-            hb = self._megastep_bucket(n_heat)
-            heat_ids = pad(heat_ids, hb, int(ctx.heat.shape[0]))
-            hw = np.zeros(hb, np.float32)
-            hw[:n_heat] = heat_w
-            heat_in, heat_ids_in, heat_w_in = ctx.heat, j(heat_ids), j(hw)
-        else:
-            heat_in = jax.numpy.zeros((0,), jax.numpy.float32)
-            heat_ids_in = j(np.zeros(0, np.int32))
-            heat_w_in = jax.numpy.zeros((0,), jax.numpy.float32)
-        ctx.state, verdict_small, verdict_groups, heat_out = migrator.megastep(
-            ctx.state,
-            j(commit_ids),
-            j(commit_regions),
-            j(commit_slots),
-            j(grp_members),
-            j(grp_regions),
-            j(grp_starts),
-            j(begin_ids),
-            j(zero_flat),
-            j(force_ids),
-            j(force_regions),
-            j(force_slots),
-            j(copy_src),
-            j(copy_dst),
-            j(run_src),
-            j(run_dst),
-            heat_in,
-            heat_ids_in,
-            heat_w_in,
-            group=G,
-            impl=ctx.cfg.copy_impl,
-            heat_decay=ctx.cfg.tier_heat_decay,
-        )
+            B = self._megastep_bucket(
+                len(commit_ids),
+                len(begin_ids),
+                len(zero_flat),
+                len(force_ids),
+                len(copy_src),
+            )
+            pad = self._pad_sentinel
+            if len(commit_ids):
+                commit_ids = pad(commit_ids, B, n_blocks)
+                commit_regions = pad(commit_regions, B, pc.n_regions)
+                commit_slots = pad(commit_slots, B, S)
+            if len(begin_ids):
+                begin_ids = pad(begin_ids, B, n_blocks)
+            if len(zero_flat):
+                zero_flat = pad(zero_flat, B, pc.n_regions * S)
+            if len(force_ids):
+                force_ids = pad(force_ids, B, n_blocks)
+                force_regions = pad(force_regions, B, pc.n_regions)
+                force_slots = pad(force_slots, B, S)
+            if len(copy_src):
+                copy_src, copy_dst = pad_to_bucket(B, copy_src, copy_dst)
+
+            # Huge-tier buckets are floored at the tick's huge capacity
+            # (budget / G groups), mirroring the per-block floor: every
+            # group-commit and run-copy tick shares one compiled variant.
+            huge_floor = max(1, ctx.cfg.budget_blocks_per_tick // G)
+            k = len(huge)
+            if k:
+                kb = bucket_size(max(k, huge_floor), ctx.cfg.bucket_growth)
+                members = np.concatenate([a.block_ids for a in huge]).reshape(k, G)
+                members = np.concatenate(
+                    [members, np.repeat(members[:1], kb - k, axis=0)]
+                )
+                grp_members = members.reshape(-1).astype(np.int32)
+                grp_regions, grp_starts = pad_to_bucket(
+                    kb,
+                    np.asarray([a.dst_region for a in huge], np.int32),
+                    np.asarray([a.dst_slots[0] for a in huge], np.int32),
+                )
+            else:
+                grp_members = grp_regions = grp_starts = np.zeros(0, np.int32)
+            if run_plan:
+                firsts = np.asarray([a.block_ids[0] for a in run_plan])
+                run_src = (
+                    ctx.table[firsts, REGION] * S + ctx.table[firsts, SLOT]
+                ).astype(np.int32)
+                run_dst = np.asarray(
+                    [a.dst_region * S + a.dst_slots[0] for a in run_plan], np.int32
+                )
+                rb = bucket_size(max(len(run_plan), huge_floor), ctx.cfg.bucket_growth)
+                run_src, run_dst = pad_to_bucket(rb, run_src, run_dst)
+                nbytes = len(run_plan) * G * pc.block_bytes
+                ctx.count("bytes_copied", nbytes)
+                ctx.count("bytes_copied_huge", nbytes)
+            else:
+                run_src = run_dst = np.zeros(0, np.int32)
+
+            j = jax.numpy.asarray
+            # Heat samples pad at their OWN bucket (sentinel = heat-plane length,
+            # which both paths drop) so a read-heavy tick never inflates the
+            # shared per-block bucket — the heat batch length tracks the access
+            # rate, not the migration budget.
+            n_heat = len(heat_ids)
+            if n_heat:
+                hb = self._megastep_bucket(n_heat)
+                heat_ids = pad(heat_ids, hb, int(ctx.heat.shape[0]))
+                hw = np.zeros(hb, np.float32)
+                hw[:n_heat] = heat_w
+                heat_in, heat_ids_in, heat_w_in = ctx.heat, j(heat_ids), j(hw)
+            else:
+                heat_in = jax.numpy.zeros((0,), jax.numpy.float32)
+                heat_ids_in = j(np.zeros(0, np.int32))
+                heat_w_in = jax.numpy.zeros((0,), jax.numpy.float32)
+            operands = (
+                j(commit_ids),
+                j(commit_regions),
+                j(commit_slots),
+                j(grp_members),
+                j(grp_regions),
+                j(grp_starts),
+                j(begin_ids),
+                j(zero_flat),
+                j(force_ids),
+                j(force_regions),
+                j(force_slots),
+                j(copy_src),
+                j(copy_dst),
+                j(run_src),
+                j(run_dst),
+                heat_in,
+                heat_ids_in,
+                heat_w_in,
+            )
+        with ctx.telemetry.stage("dispatch.enqueue"):
+            ctx.state, verdict_small, verdict_groups, heat_out = migrator.megastep(
+                ctx.state,
+                *operands,
+                group=G,
+                impl=ctx.cfg.copy_impl,
+                heat_decay=ctx.cfg.tier_heat_decay,
+            )
         if n_heat:
             ctx.heat = heat_out
         ctx.count("dispatches", 1, program="megastep")

@@ -49,7 +49,7 @@ class VerdictStage:
         ctx = self.ctx
         if not ctx.pending:
             return
-        with ctx.telemetry.stage("verdict.harvest", blocking=block):
+        with ctx.telemetry.stage("verdict.harvest"):
             still = []
             for batch in ctx.pending:
                 ready = block
@@ -64,7 +64,7 @@ class VerdictStage:
                 # Sync point: materializing the verdict blocks until the
                 # device produced it (opportunistic harvests already saw
                 # is_ready(), so only block=True pays a real wait here).
-                with ctx.telemetry.stage("verdict.sync", blocking=block):
+                with ctx.telemetry.stage("verdict.sync"):
                     packed = np.asarray(batch.verdict)
                 for area, start, end in zip(batch.areas, batch.offsets, batch.offsets[1:]):
                     self._process(area, packed[start:end])

@@ -2,8 +2,9 @@
 
 Covers the recorder contract (bounded ring, exact counter totals, request
 lifecycle spans, latency attribution), the overhead guard (a disabled
-pipeline emits nothing and shares the allocation-free NULL_RECORDER; an
-enabled ring stays bounded across a long drain), the Chrome-trace exporter
+pipeline emits no ring event and shares the NULL_RECORDER; an enabled ring
+stays bounded across a long drain), the stages' ``leap.<stage>`` spans on
+the profiler clock, the Chrome-trace exporter
 and its Perfetto schema validator, the metrics registry renderings, the
 public accessors (session / sealed facade / handle latency), stats snapshot
 independence, and the ``benchmarks.run --trace`` acceptance path end to end.
@@ -222,6 +223,99 @@ def test_jit_misses_land_as_events():
     misses = [e for e in drv.telemetry.events() if e["kind"] == "jit"]
     assert drv.stats.jit_cache_misses > 0
     assert sum(e["args"]["n"] for e in misses) == drv.stats.jit_cache_misses
+
+
+# ---------------------------------------------------------------------------
+# Profiler spans: every stage on the profiler clock as leap.<stage>
+# ---------------------------------------------------------------------------
+
+#: Each span the pipeline puts on the profiler clock, with its parent.
+SPAN_PARENTS = {
+    "leap.tick": None,
+    "leap.verdict.harvest": "leap.tick",
+    "leap.verdict.sync": "leap.verdict.harvest",
+    "leap.dispatch.commit_ready": "leap.tick",
+    "leap.budget.open_tick": "leap.tick",
+    "leap.dispatch.run_tick": "leap.tick",
+    "leap.dispatch.plan": "leap.dispatch.run_tick",
+    "leap.dispatch.device": "leap.dispatch.run_tick",
+    "leap.dispatch.operands": "leap.dispatch.device",
+    "leap.dispatch.enqueue": "leap.dispatch.device",
+    "leap.write": None,
+}
+
+
+def _host_spans(logdir):
+    """``[(name, start_ns, end_ns)]`` of every host event named ``leap.*``."""
+    from jax.profiler import ProfileData
+
+    (path,) = logdir.rglob("*.xplane.pb")
+    data = ProfileData.from_file(str(path))
+    return [
+        (e.name, e.start_ns, e.start_ns + e.duration_ns)
+        for plane in data.planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines
+        for e in line.events
+        if e.name.startswith("leap.")
+    ]
+
+
+def _traced_ticks(tmp_path, telemetry):
+    import jax
+
+    _, drv, sess = make(telemetry=telemetry)
+    sess.leap(np.arange(16), 1)
+    with jax.profiler.trace(str(tmp_path)):
+        for _ in range(6):
+            drv.write(np.arange(2), jnp.ones((2, 4), jnp.float32))
+            drv.write_rows(np.arange(2, 4), np.zeros(2, np.int32), jnp.ones(2))
+            sess.tick()
+    assert sess.drain()  # drain's blocking harvests run outside any tick: untraced
+    return drv, _host_spans(tmp_path)
+
+
+@pytest.mark.parametrize("telemetry", [False, True], ids=["off", "on"])
+def test_every_stage_span_reaches_the_profiler_inside_its_parent(tmp_path, telemetry):
+    drv, spans = _traced_ticks(tmp_path, telemetry)
+    assert {name for name, _, _ in spans} == set(SPAN_PARENTS)
+    assert not [name for name, _, _ in spans if "#" in name]  # no TraceMe metadata
+    for name, s, e in spans:
+        parent = SPAN_PARENTS[name]
+        if parent is None:
+            continue
+        assert any(p == parent and ps <= s and e <= pe for p, ps, pe in spans), name
+        assert any(p == "leap.tick" and ps <= s and e <= pe for p, ps, pe in spans), name
+    ticks = [(s, e) for name, s, e in spans if name == "leap.tick"]
+    for name, s, e in spans:
+        if name == "leap.write":
+            assert not any(ts <= s < te for ts, te in ticks)
+    assert len(ticks) == 6
+    assert sum(name == "leap.write" for name, _, _ in spans) == 12
+    if telemetry:  # ring events keep their unprefixed names
+        stages = {e["name"] for e in drv.telemetry.events() if e["kind"] == "stage"}
+        assert {n.removeprefix("leap.") for n in SPAN_PARENTS} <= stages
+
+
+def test_disabled_pipeline_under_the_profiler_records_no_ring_events(tmp_path):
+    drv, spans = _traced_ticks(tmp_path, telemetry=False)
+    assert drv.telemetry is NULL_RECORDER
+    assert drv.telemetry.events() == [] and drv.telemetry.counter_totals() == {}
+    assert spans  # the annotations alone reached the trace
+
+
+def test_stage_arguments_stay_out_of_the_profiler_span_name(tmp_path):
+    import jax
+
+    rec = TelemetryRecorder(clock=_fake_clock())
+    with jax.profiler.trace(str(tmp_path)):
+        with rec.stage("admission.cancel", rid=3):
+            pass
+        with NULL_RECORDER.stage("admission.cancel", rid=4):
+            pass
+    assert [name for name, _, _ in _host_spans(tmp_path)] == ["leap.admission.cancel"] * 2
+    (ev,) = rec.events()
+    assert ev["name"] == "admission.cancel" and ev["args"] == {"rid": 3}
 
 
 # ---------------------------------------------------------------------------
