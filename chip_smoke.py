@@ -130,10 +130,12 @@ class MegastepRecorder:
     def __enter__(self):
         def recording(*args, **kwargs):
             spec = jax.tree.map(
-                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding),
+                lambda x: jax.ShapeDtypeStruct(
+                    x.shape, x.dtype, sharding=getattr(x, "sharding", None)
+                ),
                 args,
             )
-            phases = tuple(bool(a.shape[0]) for a in args[1:])
+            phases = tuple(bool(n) for n in kwargs["layout"])
             self.calls[phases] = (spec, kwargs)
             return self._real(*args, **kwargs)
 
@@ -143,16 +145,17 @@ class MegastepRecorder:
     def __exit__(self, *exc):
         migrator.megastep = self._real
 
-    def compiled_text(self, want_phase: int) -> str:
-        """Compiled text of a recorded variant whose operand ``want_phase``
-        (an index into the megastep's array arguments) was non-empty."""
+    def compiled_text(self, want_phase: str) -> str:
+        """Compiled text of a recorded variant whose segment ``want_phase``
+        (a name of ``migrator.MEGASTEP_SEGMENTS``) was non-empty."""
+        i = migrator.MEGASTEP_SEGMENTS.index(want_phase)
         for phases, (spec, kwargs) in self.calls.items():
-            if phases[want_phase - 1]:
+            if phases[i]:
                 return self._real.lower(*spec, **kwargs).compile().as_text()
-        raise SmokeFailure(f"no megastep ran with operand {want_phase} non-empty")
+        raise SmokeFailure(f"no megastep ran with segment {want_phase} non-empty")
 
 
-MEGA_COPY, MEGA_RUNS = 12, 14  # megastep operands: copy_src, run_src
+MEGA_COPY, MEGA_RUNS = "copy_src", "run_src"  # megastep segments
 
 
 def check_kernel(text: str, what: str, expect_tpu: bool) -> None:

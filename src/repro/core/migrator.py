@@ -43,7 +43,9 @@ Three dispatch generations (DESIGN.md §3, §12):
   * the :func:`megastep` program — the whole tick (commit verdicts of the
     previous epoch, then begin/zero/force/copy/run phases) fused into ONE
     device program over the flat pool view, with the pool buffers donated
-    and the dirty verdict produced on device.  Every phase operand shares a
+    and the dirty verdict produced on device.  The host packs every index
+    operand into one int32 vector, sliced in the program at static offsets,
+    so a tick makes one host-to-device transfer.  Every phase operand shares a
     single bucketed batch length, floored at the steady-state tick budget,
     and phases pad with *out-of-bounds sentinel* lanes (JAX drops
     out-of-bounds scatter updates) so one compiled variant serves every
@@ -58,6 +60,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from repro.core.state import REGION, SLOT, LeapState, flat_pool_view
@@ -392,41 +395,70 @@ def zero_fill(state: LeapState, slots: jax.Array, dst_region: int) -> LeapState:
 # undefined there — so the host pads the copy plan by replicating lane 0
 # (identical duplicate writes; destination slots are freshly allocated and
 # disjoint from every source) or, when the tick copies nothing, with slot-0
-# self-copies (value-identical no-ops).  The huge-group operands
-# (``grp_*``/``run_*``) are trace-time skippable: shape ``(0,)`` compiles a
-# variant without those phases, so small-only pools never pay for them.
+# self-copies (value-identical no-ops).  Every phase, the huge-group ones
+# (``grp_*``/``run_*``) included, is trace-time skippable: a zero-length
+# segment of the packed operand compiles a variant without that phase, so
+# small-only pools never pay for the huge tier.
 # --------------------------------------------------------------------------
+
+
+#: The megastep's index operands, in the order the host packs them into its
+#: one int32 vector (and the program slices them back out).
+MEGASTEP_SEGMENTS = (
+    "commit_ids",
+    "commit_regions",
+    "commit_slots",
+    "grp_members",
+    "grp_regions",
+    "grp_starts",
+    "begin_ids",
+    "zero_flat",
+    "force_ids",
+    "force_regions",
+    "force_slots",
+    "copy_src",
+    "copy_dst",
+    "run_src",
+    "run_dst",
+    "heat_ids",
+)
+
+
+def pack_operands(segments: dict[str, np.ndarray]) -> tuple[np.ndarray, tuple[int, ...]]:
+    """Host side: write the phase vectors back to back into ONE int32 array.
+
+    ``segments`` maps names of :data:`MEGASTEP_SEGMENTS` to int32 vectors; a
+    missing name is an empty phase and takes zero lanes.  Returns the packed
+    array and its static layout (one length per segment), which keys the
+    megastep's compile cache exactly as the separate operand shapes did.
+    """
+    parts = [np.asarray(segments.get(name, ()), np.int32) for name in MEGASTEP_SEGMENTS]
+    return np.concatenate(parts), tuple(len(p) for p in parts)
+
+
+def unpack_operands(packed: jax.Array, layout: tuple[int, ...]) -> tuple[jax.Array, ...]:
+    """Program side: slice the packed vector back into its segments, in
+    :data:`MEGASTEP_SEGMENTS` order, at static (trace-time) offsets."""
+    ends = np.cumsum(layout).tolist()
+    return tuple(packed[e - n : e] for n, e in zip(layout, ends))
 
 
 @partial(
     jax.jit,
     donate_argnames=("state", "heat"),
-    static_argnames=("group", "impl", "heat_decay"),
+    static_argnames=("layout", "group", "impl", "heat_decay"),
 )
 def megastep(
     state: LeapState,
-    commit_ids: jax.Array,
-    commit_regions: jax.Array,
-    commit_slots: jax.Array,
-    grp_members: jax.Array,
-    grp_regions: jax.Array,
-    grp_starts: jax.Array,
-    begin_ids: jax.Array,
-    zero_flat: jax.Array,
-    force_ids: jax.Array,
-    force_regions: jax.Array,
-    force_slots: jax.Array,
-    copy_src: jax.Array,
-    copy_dst: jax.Array,
-    run_src: jax.Array,
-    run_dst: jax.Array,
-    heat: jax.Array,
-    heat_ids: jax.Array,
-    heat_w: jax.Array,
+    packed: jax.Array,
+    heat: jax.Array | None = None,
+    heat_w: jax.Array | None = None,
+    *,
+    layout: tuple[int, ...],
     group: int = 1,
     impl: str | None = None,
     heat_decay: float = 1.0,
-) -> tuple[LeapState, jax.Array, jax.Array, jax.Array]:
+) -> tuple[LeapState, jax.Array, jax.Array, jax.Array | None]:
     """One tick = one dispatch: commit -> begin -> zero -> force -> copy -> heat.
 
     Fuses the previous epoch's commit verdicts with this tick's begin/zero/
@@ -438,12 +470,25 @@ def megastep(
     device: the host wraps them in :class:`~repro.core.queues.CommitBatch`
     futures and harvests them asynchronously, off the tick critical path.
 
+    Every index operand arrives in ``packed`` (one host-to-device transfer),
+    cut into the segments of :data:`MEGASTEP_SEGMENTS` by the static
+    ``layout``; a zero-length segment is an absent phase.
+
     The trailing heat phase (closed-loop tiering, DESIGN.md §13) folds the
     tick's access samples into the donated per-block heat plane — it touches
     no pool/table state, so its ordering is free, and its trace-time guard
-    (``heat_ids.shape[0]``) compiles the phase away entirely when tiering is
-    off: the tiering-less megastep variant is bit-identical to before.
+    compiles the phase away when the tick has no samples.  Without samples
+    the host passes no heat buffers at all (``None``) and gets ``heat``
+    back untouched.
     """
+    (
+        commit_ids, commit_regions, commit_slots,
+        grp_members, grp_regions, grp_starts,
+        begin_ids, zero_flat,
+        force_ids, force_regions, force_slots,
+        copy_src, copy_dst, run_src, run_dst,
+        heat_ids,
+    ) = unpack_operands(packed, layout)
     table, dirty, in_flight = state.table, state.dirty, state.in_flight
     s_per = state.pool.shape[1]
 

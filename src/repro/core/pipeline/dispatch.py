@@ -435,20 +435,41 @@ class DispatchStage:
         steady state, ``(commit,)`` on the tail — can compile at pool-attach
         time.  Each warm call is a semantic no-op: per-block operands are
         all OUT-OF-BOUNDS sentinels (scatters dropped, gather results
-        unread) and copy lanes are slot-0 self-copies.  Runs inside driver
+        unread) and copy lanes are slot-0 self-copies.  The operands go
+        through the same packing as a real tick's, so the warmed variants
+        are exactly those the steady state calls.  Runs inside driver
         construction, before the jit-miss baseline snapshot, so warmed
         compiles never count against ``MigrationStats.jit_cache_misses``.
         """
+        for segments, heat_w in self._warm_operands().values():
+            self._megastep(*migrator.pack_operands(segments), heat_w)
+
+    def _warm_operands(self) -> dict[tuple[str, ...], tuple[dict, np.ndarray | None]]:
+        """Warm signature (phases present) -> (no-op segments, heat weights)."""
         ctx = self.ctx
-        G = ctx.pool_cfg.huge_factor
+        pc = ctx.pool_cfg
+        G = pc.huge_factor
         B = self._megastep_bucket(0)
         n_blocks = len(ctx.table)
-        j = jax.numpy.asarray
-        sent = j(np.full(B, n_blocks, np.int32))  # OOB block ids: all no-op
-        regions = j(np.full(B, ctx.pool_cfg.n_regions, np.int32))
-        slots = j(np.full(B, ctx.pool_cfg.slots_per_region, np.int32))
-        self_copy = j(np.zeros(B, np.int32))
-        empty = j(np.zeros(0, np.int32))
+        sent = np.full(B, n_blocks, np.int32)  # OOB block ids: all no-op
+        self_copy = np.zeros(B, np.int32)
+        gb = bucket_size(max(1, ctx.cfg.budget_blocks_per_tick // G), ctx.cfg.bucket_growth)
+        r_self = np.zeros(gb, np.int32)
+        phases = {
+            "commit": {
+                "commit_ids": sent,
+                "commit_regions": np.full(B, pc.n_regions, np.int32),
+                "commit_slots": np.full(B, pc.slots_per_region, np.int32),
+            },
+            "begin": {"begin_ids": sent},
+            "copy": {"copy_src": self_copy, "copy_dst": self_copy},
+            "groups": {
+                "grp_members": np.full(gb * G, n_blocks, np.int32),  # OOB member ids
+                "grp_regions": np.full(gb, pc.n_regions, np.int32),
+                "grp_starts": np.full(gb, pc.slots_per_region, np.int32),
+            },
+            "runs": {"run_src": r_self, "run_dst": r_self},
+        }
         signatures = [
             ("commit",),
             ("begin", "copy"),
@@ -456,7 +477,10 @@ class DispatchStage:
         ]
         if ctx.heat is not None:
             # Tiering on: a read workload rides the heat phase on every
-            # nonempty tick, including read-only ticks (heat alone).
+            # nonempty tick, including read-only ticks (heat alone).  OOB
+            # heat ids: no lane matches, and heat is all zeros at
+            # construction, so the warmed decay pass is a value no-op too.
+            phases["heat"] = {"heat_ids": np.full(B, int(ctx.heat.shape[0]), np.int32)}
             signatures += [
                 ("heat",),
                 ("commit", "heat"),
@@ -472,52 +496,37 @@ class DispatchStage:
                 ("groups", "begin", "runs"),
                 ("groups", "begin", "copy"),
             ]
-        gb = bucket_size(
-            max(1, ctx.cfg.budget_blocks_per_tick // G), ctx.cfg.bucket_growth
-        )
-        g_sent = j(np.full(gb * G, n_blocks, np.int32))  # OOB member ids
-        g_regions = j(np.full(gb, ctx.pool_cfg.n_regions, np.int32))
-        g_starts = j(np.full(gb, ctx.pool_cfg.slots_per_region, np.int32))
-        r_self = j(np.zeros(gb, np.int32))
-        empty_f = j(np.zeros(0, np.float32))
-        if ctx.heat is not None:
-            # OOB heat ids: no lane matches, and heat is all zeros at
-            # construction, so the warmed decay pass is a value no-op too.
-            h_sent = j(np.full(B, int(ctx.heat.shape[0]), np.int32))
-            h_w = j(np.zeros(B, np.float32))
+        out = {}
         for sig in signatures:
-            with_heat = "heat" in sig
-            # The heat operand is donated, so a signature without the phase
-            # gets its own fresh empty buffer (reusing one would pass an
-            # already-donated buffer on the next warm call).
-            heat_in = ctx.heat if with_heat else j(np.zeros(0, np.float32))
-            out = migrator.megastep(
-                ctx.state,
-                sent if "commit" in sig else empty,
-                regions if "commit" in sig else empty,
-                slots if "commit" in sig else empty,
-                g_sent if "groups" in sig else empty,
-                g_regions if "groups" in sig else empty,
-                g_starts if "groups" in sig else empty,
-                sent if "begin" in sig else empty,
-                empty,
-                empty,
-                empty,
-                empty,
-                self_copy if "copy" in sig else empty,
-                self_copy if "copy" in sig else empty,
-                r_self if "runs" in sig else empty,
-                r_self if "runs" in sig else empty,
-                heat_in,
-                h_sent if with_heat else empty,
-                h_w if with_heat else empty_f,
-                group=G,
-                impl=ctx.cfg.copy_impl,
-                heat_decay=ctx.cfg.tier_heat_decay,
-            )
-            ctx.state, _, _, heat_out = out
-            if with_heat:
-                ctx.heat = heat_out
+            segments = {k: v for phase in sig for k, v in phases[phase].items()}
+            out[sig] = (segments, np.zeros(B, np.float32) if "heat" in sig else None)
+        return out
+
+    def _megastep(
+        self, packed: np.ndarray, layout: tuple[int, ...], heat_w: np.ndarray | None
+    ) -> tuple[jax.Array, jax.Array]:
+        """Fire the megastep on packed operands; returns the verdict futures.
+
+        The packed numpy vector goes straight into the jitted call, which
+        transfers it once.  Without a heat phase no heat buffer is passed
+        (the donated plane stays where it is); with one, the plane is donated
+        and the float32 weights are the tick's second transfer.
+        """
+        ctx = self.ctx
+        heat_in = None if heat_w is None else ctx.heat
+        ctx.state, verdict_small, verdict_groups, heat_out = migrator.megastep(
+            ctx.state,
+            packed,
+            heat_in,
+            heat_w,
+            layout=layout,
+            group=ctx.pool_cfg.huge_factor,
+            impl=ctx.cfg.copy_impl,
+            heat_decay=ctx.cfg.tier_heat_decay,
+        )
+        if heat_w is not None:
+            ctx.heat = heat_out
+        return verdict_small, verdict_groups
 
     def _megastep_bucket(self, *lengths: int) -> int:
         """Shared bucket for every per-block megastep operand.
@@ -547,10 +556,13 @@ class DispatchStage:
     ) -> None:
         """Assemble and fire the tick's single device program.
 
-        An EMPTY phase ships a shape-``(0,)`` operand and compiles away
-        entirely (trace-time ``if x.shape[0]`` guards in the program), so a
-        quiet drain never pays padded force-lane payload gathers and the
-        commit-only final tick compiles a lean tail variant.  A NONEMPTY
+        Every index vector is packed into ONE int32 array
+        (:func:`~repro.core.migrator.pack_operands`), sent in one transfer
+        and sliced inside the program at static offsets.  An EMPTY phase
+        takes zero lanes and compiles away entirely (trace-time
+        ``if x.shape[0]`` guards in the program), so a quiet drain never
+        pays padded force-lane payload gathers and the commit-only final
+        tick compiles a lean tail variant.  A NONEMPTY
         phase pads to the shared budget-floored bucket with OUT-OF-BOUNDS
         sentinels (block ids -> N, regions -> R, slots -> S, flat ids ->
         R*S): JAX drops out-of-bounds scatter rows and clamps out-of-bounds
@@ -671,53 +683,41 @@ class DispatchStage:
             else:
                 run_src = run_dst = np.zeros(0, np.int32)
 
-            j = jax.numpy.asarray
             # Heat samples pad at their OWN bucket (sentinel = heat-plane length,
             # which both paths drop) so a read-heavy tick never inflates the
             # shared per-block bucket — the heat batch length tracks the access
             # rate, not the migration budget.
             n_heat = len(heat_ids)
+            hw = None
             if n_heat:
                 hb = self._megastep_bucket(n_heat)
                 heat_ids = pad(heat_ids, hb, int(ctx.heat.shape[0]))
                 hw = np.zeros(hb, np.float32)
                 hw[:n_heat] = heat_w
-                heat_in, heat_ids_in, heat_w_in = ctx.heat, j(heat_ids), j(hw)
-            else:
-                heat_in = jax.numpy.zeros((0,), jax.numpy.float32)
-                heat_ids_in = j(np.zeros(0, np.int32))
-                heat_w_in = jax.numpy.zeros((0,), jax.numpy.float32)
-            operands = (
-                j(commit_ids),
-                j(commit_regions),
-                j(commit_slots),
-                j(grp_members),
-                j(grp_regions),
-                j(grp_starts),
-                j(begin_ids),
-                j(zero_flat),
-                j(force_ids),
-                j(force_regions),
-                j(force_slots),
-                j(copy_src),
-                j(copy_dst),
-                j(run_src),
-                j(run_dst),
-                heat_in,
-                heat_ids_in,
-                heat_w_in,
+            packed, layout = migrator.pack_operands(
+                {
+                    "commit_ids": commit_ids,
+                    "commit_regions": commit_regions,
+                    "commit_slots": commit_slots,
+                    "grp_members": grp_members,
+                    "grp_regions": grp_regions,
+                    "grp_starts": grp_starts,
+                    "begin_ids": begin_ids,
+                    "zero_flat": zero_flat,
+                    "force_ids": force_ids,
+                    "force_regions": force_regions,
+                    "force_slots": force_slots,
+                    "copy_src": copy_src,
+                    "copy_dst": copy_dst,
+                    "run_src": run_src,
+                    "run_dst": run_dst,
+                    "heat_ids": heat_ids,
+                }
             )
         with ctx.telemetry.stage("dispatch.enqueue"):
-            ctx.state, verdict_small, verdict_groups, heat_out = migrator.megastep(
-                ctx.state,
-                *operands,
-                group=G,
-                impl=ctx.cfg.copy_impl,
-                heat_decay=ctx.cfg.tier_heat_decay,
-            )
-        if n_heat:
-            ctx.heat = heat_out
+            verdict_small, verdict_groups = self._megastep(packed, layout, hw)
         ctx.count("dispatches", 1, program="megastep")
+        ctx.count("h2d_transfers", 1 if hw is None else 2)
         for a in small + huge:
             ctx.active.remove(a)
         if small:

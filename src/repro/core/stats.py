@@ -26,6 +26,10 @@ class MigrationStats:
     # (the whole point of the single-dispatch tick), not one per fused
     # phase; the batched generation counts each of its <=3 programs.
     dispatches: int = 0
+    # Host-to-device operand transfers the megastep dispatches made: one
+    # packed index vector per megastep, plus the heat weights on a tick
+    # with a heat phase (zero under the batched and legacy generations).
+    h2d_transfers: int = 0
     ticks: int = 0
     jit_cache_misses: int = 0  # migration-program compiles since driver init
     # per-tier counters (two-tier pool; all zero on a small-only pool)

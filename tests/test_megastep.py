@@ -14,6 +14,7 @@ Covers the acceptance criteria of the device-resident tick loop:
     including the ppermute fallback.
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -26,6 +27,7 @@ from repro.core import (
     leap_write,
     migrator,
 )
+from repro.kernels.heat_scan import padded_heat_len
 
 
 def make(n_regions=2, slots=64, n_blocks=32, block_shape=(4,), seed=0):
@@ -237,3 +239,95 @@ def test_megastep_warm_ticks_do_not_recompile():
     assert drv2.drain()
     assert migrator.program_cache_sizes()["megastep"] == before
     assert drv2.stats.jit_cache_misses == 0
+
+
+# ---------------------------------------------------------------------------
+# Packed operands: one host-to-device transfer per megastep
+# ---------------------------------------------------------------------------
+
+#: Every signature ``_warm_megastep`` compiles on a tiered (G = 2) pool with
+#: tiering on — the drain loop, the heat phase, the huge-group shapes.
+WARM_SIGNATURES = [
+    ("commit",),
+    ("begin", "copy"),
+    ("commit", "begin", "copy"),
+    ("heat",),
+    ("commit", "heat"),
+    ("begin", "copy", "heat"),
+    ("commit", "begin", "copy", "heat"),
+    ("groups",),
+    ("begin", "runs"),
+    ("groups", "begin", "runs"),
+    ("groups", "begin", "copy"),
+]
+
+
+@pytest.fixture(scope="module")
+def warm_operands():
+    cfg = PoolConfig(2, 64, (4,), huge_factor=2)
+    state = init_state(cfg, 32, np.zeros(32, np.int32))
+    drv = MigrationDriver(state, cfg, LeapConfig(tiering=True, budget_blocks_per_tick=16))
+    return cfg, drv._dispatch._warm_operands()
+
+
+def test_warm_signatures_are_the_packed_ones(warm_operands):
+    _, ops_by_sig = warm_operands
+    assert list(ops_by_sig) == WARM_SIGNATURES
+
+
+@pytest.mark.parametrize("sig", WARM_SIGNATURES, ids="+".join)
+def test_packed_operands_slice_back_to_each_phase(warm_operands, sig):
+    """Packing on the host and slicing inside a jitted program give every
+    segment exactly the separate vector, sentinels included; an absent
+    phase is a zero-length segment."""
+    cfg, ops_by_sig = warm_operands
+    segments, heat_w = ops_by_sig[sig]
+    packed, layout = migrator.pack_operands(segments)
+    assert packed.dtype == np.int32 and packed.ndim == 1
+    assert sum(layout) == len(packed) == sum(len(v) for v in segments.values())
+    sliced = jax.jit(migrator.unpack_operands, static_argnums=1)(packed, layout)
+    for name, got in zip(migrator.MEGASTEP_SEGMENTS, sliced):
+        want = segments.get(name, np.zeros(0, np.int32))
+        assert got.dtype == jnp.int32, name
+        np.testing.assert_array_equal(np.asarray(got), want, err_msg=name)
+    # the warm operands are the out-of-bounds sentinels the program drops
+    if "commit" in sig:
+        assert (segments["commit_ids"] == 32).all()
+        assert (segments["commit_regions"] == cfg.n_regions).all()
+        assert (segments["commit_slots"] == cfg.slots_per_region).all()
+    if "groups" in sig:
+        assert (segments["grp_members"] == 32).all()
+    if "heat" in sig:
+        assert (segments["heat_ids"] == padded_heat_len(32)).all()
+        assert heat_w is not None and len(heat_w) == len(segments["heat_ids"])
+    else:
+        assert heat_w is None
+
+
+@pytest.mark.parametrize(
+    "mode,tiering", [("megastep", False), ("megastep", True), ("batched", False)]
+)
+def test_h2d_transfers_per_dispatch(mode, tiering):
+    """A tiering-off megastep drain makes exactly one operand transfer per
+    dispatch; a tick with a heat phase adds the weights; the batched
+    generation does not count them."""
+    cfg, state, _ = make(n_blocks=32, slots=64, seed=41)
+    drv = MigrationDriver(
+        state, cfg, LeapConfig(budget_blocks_per_tick=8, fused_dispatch=mode, tiering=tiering)
+    )
+    drv.default_session().leap(np.arange(32), 1)
+    rng = np.random.default_rng(41)
+    while not drv.done:
+        drv.tick()
+        ids = rng.choice(32, size=2, replace=False)
+        drv.write(jnp.asarray(ids), jnp.asarray(rng.normal(size=(2, 4)).astype(np.float32)))
+    assert drv.default_session().drain()
+    s = drv.stats
+    assert s.dispatches > 0
+    if mode == "batched":
+        assert s.h2d_transfers == 0
+    elif tiering:
+        # writes feed the heat plane on every tick that dispatches after one
+        assert s.dispatches < s.h2d_transfers <= 2 * s.dispatches
+    else:
+        assert s.h2d_transfers == s.dispatches

@@ -26,6 +26,7 @@ MIRRORED = (
     "blocks_cancelled",
     "bytes_copied",
     "dispatches",
+    "h2d_transfers",
 )
 #: Mirrored too, but only nonzero on some scenario shapes (tiered pools,
 #: topologies with congestion/relays) — same equality, asserted when present.

@@ -105,8 +105,9 @@ def test_paged_decode_compiles_at_granite_widths(one_chip):
 
 
 def test_megastep_keeps_donated_kv_pool_aliased(one_chip):
-    """The steady-state tick (commit, begin, copy) on a 2 x 256-page Granite
-    KV pool must alias the donated pool: temp well under the pool's bytes."""
+    """The steady-state tick (commit, begin, copy), its index operands packed
+    into one vector, on a 2 x 256-page Granite KV pool must alias the
+    donated pool: temp well under the pool's bytes."""
     n_regions, slots, n_blocks, bucket = 2, 256, 256, 64
     pool = _sds((n_regions, slots) + GRANITE_PAGE, jnp.bfloat16, one_chip)
     state = LeapState(
@@ -115,19 +116,18 @@ def test_megastep_keeps_donated_kv_pool_aliased(one_chip):
         dirty=_sds((n_blocks,), jnp.bool_, one_chip),
         in_flight=_sds((n_blocks,), jnp.bool_, one_chip),
     )
-    lane = _sds((bucket,), jnp.int32, one_chip)
-    empty = _sds((0,), jnp.int32, one_chip)
-    no_heat = _sds((0,), jnp.float32, one_chip)
+    lane = np.zeros(bucket, np.int32)
+    packed, layout = migrator.pack_operands(
+        {
+            "commit_ids": lane, "commit_regions": lane, "commit_slots": lane,
+            "begin_ids": lane,
+            "copy_src": lane, "copy_dst": lane,
+        }
+    )
     lowered = migrator.megastep.lower(
         state,
-        lane, lane, lane,  # commit
-        empty, empty, empty,  # group commit
-        lane,  # begin
-        empty,  # zero
-        empty, empty, empty,  # force
-        lane, lane,  # copy
-        empty, empty,  # runs
-        no_heat, empty, no_heat,  # heat
+        _sds(packed.shape, jnp.int32, one_chip),
+        layout=layout,
         group=1,
         impl="pallas",
     )
