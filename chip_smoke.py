@@ -7,8 +7,9 @@ One chip.  The pool phase is the paper's ``page_leap``: a 2-region pool of
 512 KiB f32 blocks with 3 GiB resident in region 0 leaps to region 1 while
 random blocks are rewritten between ticks; it runs again with 8-block huge
 pages and the access-heat plane on.  The serving phase runs
-``repro.launch.serve`` on granite_3_2b at its published widths and all 40
-layers, once undisturbed and once while two sequences' KV pages migrate.
+``repro.launch.serve`` on granite_3_2b at its published widths, with its
+four multipliers and all 40 layers, once undisturbed and once while two
+sequences' KV pages migrate.
 
 Four chips.  One region per chip; region 0's blocks leap to region 2 through
 the ppermute backend, and the result is compared with the same seeded
@@ -300,7 +301,7 @@ def serving_phase(seed, *, smoke=False, prompt_lens=(1024, 128), requests=8, tok
     def attend(pool, impl=None):  # the flat view is a bitcast only inside jit
         return ops.paged_decode_partial(
             q, flat_pool_view(pool), jnp.asarray(tabs), lens,
-            kv_heads=cfg.n_kv_heads, layer=cfg.n_layers - 1, impl=impl,
+            kv_heads=cfg.n_kv_heads, layer=cfg.n_layers - 1, scale=cfg.attn_scale, impl=impl,
         )
 
     got = attend(eng.driver.state.pool)

@@ -64,6 +64,12 @@ class ModelConfig:
     attn_softcap: float = 0.0
     final_softcap: float = 0.0
     attn_scale: float | None = None  # None -> 1/sqrt(head_dim)
+    # Granite's scalings of the residual path (identity elsewhere):
+    # x = embed(ids) * embed_multiplier; every sublayer adds
+    # residual_multiplier * f(norm(x)); logits = head(norm(x)) / logits_scaling.
+    embed_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     window: int = 0  # sliding-window size for "win" blocks
     rope_theta: float = 10_000.0
     moe: MoEConfig | None = None

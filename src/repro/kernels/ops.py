@@ -79,7 +79,7 @@ copy_runs = jax.jit(copy_runs_impl, static_argnames=("run", "impl"), donate_argn
 # -- paged decode attention ----------------------------------------------------
 
 
-_PAGED_STATIC = ("softcap", "kv_heads", "layer", "impl")
+_PAGED_STATIC = ("softcap", "scale", "kv_heads", "layer", "impl")
 
 
 @functools.partial(jax.jit, static_argnames=_PAGED_STATIC)
@@ -92,12 +92,15 @@ def paged_decode(
     kv_heads: int,
     layer: int = 0,
     softcap: float = 0.0,
+    scale: float | None = None,
     impl: str | None = None,
 ):
-    """One decode step of paged attention over ``layer``; returns ``out [B, H, hd]``."""
+    """One decode step of paged attention over ``layer``; returns ``out [B, H, hd]``.
+
+    Scores are ``scale * q.k`` (``scale`` None: ``1/sqrt(hd)``)."""
     out, _, _ = paged_decode_partial(
         q, kv_pool, tables, lens, kv_heads=kv_heads, layer=layer, softcap=softcap,
-        impl=impl,
+        scale=scale, impl=impl,
     )
     return out
 
@@ -112,6 +115,7 @@ def paged_decode_partial(
     kv_heads: int,
     layer: int = 0,
     softcap: float = 0.0,
+    scale: float | None = None,
     impl: str | None = None,
 ):
     """Paged decode returning flash partials ``(out, m, l)`` for shard combine."""
@@ -129,12 +133,13 @@ def paged_decode_partial(
     if kind == "pallas":
         qg = q.reshape(b, kv_heads, g, hd)
         out, m, l = paged_attn.paged_decode_pallas(
-            qg, kv_pool, safe_tables, lens, layer=layer, softcap=softcap,
+            qg, kv_pool, safe_tables, lens, layer=layer, softcap=softcap, scale=scale,
             interpret=interp,
         )
         return out.reshape(b, h, hd), m.reshape(b, h), l.reshape(b, h)
     return ref.paged_decode_ref(
-        q, kv_pool, safe_tables, lens, kv_heads=kv_heads, layer=layer, softcap=softcap
+        q, kv_pool, safe_tables, lens, kv_heads=kv_heads, layer=layer, softcap=softcap,
+        scale=scale,
     )
 
 

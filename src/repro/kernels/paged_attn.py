@@ -115,9 +115,12 @@ def paged_decode_pallas(
     *,
     layer: int = 0,
     softcap: float = 0.0,
+    scale: float | None = None,
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Returns ``(out [B,KVH,G,hd], m [B,KVH,G], l [B,KVH,G])`` fp32 partials."""
+    """Returns ``(out [B,KVH,G,hd], m [B,KVH,G], l [B,KVH,G])`` fp32 partials.
+
+    Scores are ``scale * q.k`` (``scale`` None: ``1/sqrt(hd)``)."""
     b, kvh, g, hd = q.shape
     s, n_layers, two, blk, w = kv_pool.shape
     assert two == 2 and w == kvh * hd and 0 <= layer < n_layers, (
@@ -127,7 +130,8 @@ def paged_decode_pallas(
     )
     h = kvh * g
     maxb = tables.shape[1]
-    scale = 1.0 / (hd**0.5)
+    if scale is None:
+        scale = 1.0 / (hd**0.5)
     eye = jnp.eye(kvh, dtype=q.dtype)
     q_exp = jnp.einsum("bkgd,kl->bkgld", q, eye).reshape(b, h, w)
 

@@ -47,8 +47,11 @@ def paged_decode_ref(
     kv_heads: int,
     layer: int = 0,
     softcap: float = 0.0,
+    scale: float | None = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Oracle: full-precision paged attention over one layer of the pool.
+
+    Scores are ``scale * q.k`` (``scale`` None: ``1/sqrt(hd)``).
 
     Returns ``(out [B,H,hd], m [B,H], l [B,H])`` where m/l are the softmax
     running max and normalizer (fp32) so that shard partials combine as::
@@ -61,7 +64,8 @@ def paged_decode_ref(
     kvh = kv_heads
     maxb = tables.shape[1]
     g = h // kvh
-    scale = 1.0 / (hd**0.5)
+    if scale is None:
+        scale = 1.0 / (hd**0.5)
 
     def per_seq(qb, tab, ln):
         k = kv_pool[tab, layer, 0].reshape(maxb * blk, kvh, hd).astype(jnp.float32)

@@ -4,9 +4,11 @@ cache, with optional live rebalancing.
     PYTHONPATH=src python -m repro.launch.serve --arch granite_3_2b --smoke \
         --requests 8 --tokens 32 --rebalance
 
-Without ``--smoke`` the model runs at its published widths and depth with
-random weights, over 16-token KV pages and 1024 slots per region (for
-granite_3_2b: 5.1 GB of weights and a 2.7 GB KV pool, which fit one TPU v5e).
+Without ``--smoke`` the model runs at its published widths and depth, with
+its published multipliers and random weights, over 16-token KV pages and two
+regions of 1024 slots.  For granite_3_2b that is 5.07 GB of bf16 weights and
+a 2.68 GB pool of 1,310,720-byte pages, half of whose slots are migration
+headroom: 1,024 pages, 16,384 tokens of KV, fit one TPU v5e.
 """
 
 from __future__ import annotations

@@ -52,26 +52,37 @@ def _res(x):
     return constrain(x, "dp", "seq", None)
 
 
+def residual_add(x, y, cfg: ModelConfig):
+    """``x + residual_multiplier * y``: one sublayer's output joining the
+    residual stream (plain ``x + y`` at the identity default)."""
+    if cfg.residual_multiplier != 1.0:
+        y = y * jnp.asarray(cfg.residual_multiplier, y.dtype)
+    return x + y
+
+
 def block_train(x, params, cfg: ModelConfig, kind: str):
     """[B,S,D] -> ([B,S,D], aux_loss)."""
     aux = jnp.zeros((), jnp.float32)
     h = rms_norm(x, params["norm1"], cfg.norm_eps)
     if kind in ("attn", "win", "moe"):
-        x = _res(x + attn.attn_train(h, params["attn"], cfg, _window(cfg, kind)))
+        y = attn.attn_train(h, params["attn"], cfg, _window(cfg, kind))
+        x = _res(residual_add(x, y, cfg))
         h2 = rms_norm(x, params["norm2"], cfg.norm_eps)
         if kind == "moe":
             y, aux = moe_ffn(h2, params["moe"], cfg)
         else:
             y = mlp_forward(h2, params["mlp"], cfg.mlp_kind)
-        x = _res(x + y)
+        x = _res(residual_add(x, y, cfg))
     elif kind == "rec":
-        x = _res(x + rec.rec_block_train(h, params["rec"], cfg))
+        x = _res(residual_add(x, rec.rec_block_train(h, params["rec"], cfg), cfg))
         h2 = rms_norm(x, params["norm2"], cfg.norm_eps)
-        x = _res(x + mlp_forward(h2, params["mlp"], cfg.mlp_kind))
+        x = _res(residual_add(x, mlp_forward(h2, params["mlp"], cfg.mlp_kind), cfg))
     elif kind == "mlstm":
-        x = _res(x + xlstm.mlstm_block(h, params["cell"], cfg, mode="train"))
+        y = xlstm.mlstm_block(h, params["cell"], cfg, mode="train")
+        x = _res(residual_add(x, y, cfg))
     elif kind == "slstm":
-        x = _res(x + xlstm.slstm_block(h, params["cell"], cfg, mode="train"))
+        y = xlstm.slstm_block(h, params["cell"], cfg, mode="train")
+        x = _res(residual_add(x, y, cfg))
     else:
         raise ValueError(kind)
     return x, aux
@@ -96,24 +107,24 @@ def block_prefill(x, params, cfg: ModelConfig, kind: str):
     h = rms_norm(x, params["norm1"], cfg.norm_eps)
     if kind in ("attn", "win", "moe"):
         y, cache = attn.attn_prefill(h, params["attn"], cfg, _window(cfg, kind))
-        x = _res(x + y)
+        x = _res(residual_add(x, y, cfg))
         h2 = rms_norm(x, params["norm2"], cfg.norm_eps)
         if kind == "moe":
             y2, _ = moe_ffn(h2, params["moe"], cfg)
         else:
             y2 = mlp_forward(h2, params["mlp"], cfg.mlp_kind)
-        x = _res(x + y2)
+        x = _res(residual_add(x, y2, cfg))
     elif kind == "rec":
         y, cache = rec.rec_block_prefill(h, params["rec"], cfg)
-        x = _res(x + y)
+        x = _res(residual_add(x, y, cfg))
         h2 = rms_norm(x, params["norm2"], cfg.norm_eps)
-        x = _res(x + mlp_forward(h2, params["mlp"], cfg.mlp_kind))
+        x = _res(residual_add(x, mlp_forward(h2, params["mlp"], cfg.mlp_kind), cfg))
     elif kind == "mlstm":
         y, cache = xlstm.mlstm_block(h, params["cell"], cfg, mode="prefill")
-        x = _res(x + y)
+        x = _res(residual_add(x, y, cfg))
     elif kind == "slstm":
         y, cache = xlstm.slstm_block(h, params["cell"], cfg, mode="prefill")
-        x = _res(x + y)
+        x = _res(residual_add(x, y, cfg))
     else:
         raise ValueError(kind)
     return x, cache
@@ -126,24 +137,24 @@ def block_decode(x, params, cfg: ModelConfig, kind: str, cache, pos):
         y, cache = attn.attn_decode(
             h, params["attn"], cfg, cache, pos, _window(cfg, kind)
         )
-        x = x + y
+        x = residual_add(x, y, cfg)
         h2 = rms_norm(x, params["norm2"], cfg.norm_eps)
         if kind == "moe":
             y2, _ = moe_ffn(h2, params["moe"], cfg)
         else:
             y2 = mlp_forward(h2, params["mlp"], cfg.mlp_kind)
-        x = x + y2
+        x = residual_add(x, y2, cfg)
     elif kind == "rec":
         y, cache = rec.rec_block_decode(h, params["rec"], cfg, cache)
-        x = x + y
+        x = residual_add(x, y, cfg)
         h2 = rms_norm(x, params["norm2"], cfg.norm_eps)
-        x = x + mlp_forward(h2, params["mlp"], cfg.mlp_kind)
+        x = residual_add(x, mlp_forward(h2, params["mlp"], cfg.mlp_kind), cfg)
     elif kind == "mlstm":
         y, cache = xlstm.mlstm_block(h, params["cell"], cfg, cache, mode="decode")
-        x = x + y
+        x = residual_add(x, y, cfg)
     elif kind == "slstm":
         y, cache = xlstm.slstm_block(h, params["cell"], cfg, cache, mode="decode")
-        x = x + y
+        x = residual_add(x, y, cfg)
     else:
         raise ValueError(kind)
     return x, cache

@@ -90,6 +90,8 @@ def embed_tokens(params, inputs, cfg: ModelConfig):
         x = inputs.astype(cfg.dtype())  # frontend stub: already embeddings
     if cfg.embed_scale:
         x = x * jnp.asarray(cfg.d_model**0.5, cfg.dtype())
+    if cfg.embed_multiplier != 1.0:
+        x = x * jnp.asarray(cfg.embed_multiplier, cfg.dtype())
     return constrain(x, "dp", "seq", None)
 
 
@@ -98,8 +100,10 @@ def lm_logits(params, x, cfg: ModelConfig):
     head = params.get("lm_head")
     if head is None:
         head = params["embed"].T
-    logits = x @ head
-    logits = softcap(logits.astype(jnp.float32), cfg.final_softcap)
+    logits = (x @ head).astype(jnp.float32)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    logits = softcap(logits, cfg.final_softcap)
     return constrain(logits, "dp", None, "tp")
 
 

@@ -16,6 +16,13 @@ window/recurrent stacks serve via the contiguous cache path in
 Regions: on a mesh, pool dim 0 shards over the data axis and each region
 serves its resident sequences; on one device (tests/benches) regions are
 logical rows — identical control flow.
+
+Tracing: ``admit``, ``decode`` and ``rebalance`` run inside the pool's
+telemetry stages ``serve.admit``, ``serve.decode`` and ``serve.rebalance``
+(profiler spans ``leap.serve.*``, on whether telemetry is on or off), and
+:class:`ServeStats` counts steps, tokens and the KV pages the paged kernel
+reads.  The decode step and the prefill are the XLA programs
+``jit_paged_decode_step`` and ``jit_paged_prefill``.
 """
 
 from __future__ import annotations
@@ -32,11 +39,11 @@ from repro.core import LeapConfig, MigrationDriver, PoolConfig, init_state
 from repro.core.state import REGION, SLOT, flat_pool_view
 from repro.kernels import ops
 from repro.models import lm
-from repro.obs.metrics import LATENCY_TICK_BUCKETS, Histogram
-from repro.models.common import rms_norm
-from repro.models.moe import moe_ffn
-from repro.models.common import mlp_forward
 from repro.models.attention import _project_qkv
+from repro.models.blocks import residual_add
+from repro.models.common import mlp_forward, rms_norm
+from repro.models.moe import moe_ffn
+from repro.obs.metrics import LATENCY_TICK_BUCKETS, Histogram
 
 
 @dataclasses.dataclass(frozen=True)
@@ -67,6 +74,18 @@ class PagedConfig:
     # SchedulerPolicy instance — the repro.core.pipeline seam, selectable
     # per deployment so rebalance traffic can trade race-freedom for pacing.
     scheduler: object = "leap"
+
+
+@dataclasses.dataclass
+class ServeStats:
+    """Serving counters (each also a ``serve.<name>`` telemetry counter)."""
+
+    decode_steps: int = 0
+    tokens_decoded: int = 0
+    tokens_prefilled: int = 0
+    # pages the paged-attention kernel reads: per step and sequence
+    # ceil(tokens attended / block_tokens), each page once per layer
+    kv_pages_read: int = 0
 
 
 @dataclasses.dataclass
@@ -146,17 +165,14 @@ class PagedEngine:
         self._next_sid = 0
         # sid -> the handle of its latest rebalance (latency attribution)
         self._rebalance_handles: dict[int, LeapHandle] = {}
-        # Compiled decode step: cfg/block_tokens closed over, donating the
-        # old KV state so appends stay in place.  One compile per distinct
-        # decode batch size — callers that vary batch size should chunk to
-        # powers of two (repro.load does) to bound the compile count.
-        self._decode_step = jax.jit(
-            lambda p, s, t, le, k: _paged_step(p, s, t, le, k, cfg, pcfg.block_tokens),
-            donate_argnums=(1,),
-        )
+        # The decode step (``decode_step_program``) compiles once per decode
+        # batch size — callers that vary batch size should chunk to powers
+        # of two (repro.load does) to bound the compile count — and the
+        # prefill (``prefill_program``) once per prompt length.
         self._decode_shapes: set[int] = set()  # observed decode batch sizes
-        # jitted prefill per prompt length (admit() reuses, never retraces)
-        self._prefill_fns: dict[int, object] = {}
+        self.stats = ServeStats()
+        # logits of the latest admit ([1, V]) or decode ([B, V]), on the device
+        self.last_logits = None
         # Per-tenant serving metrics: token-latency histogram (modeled units
         # supplied by the caller via observe_tokens) and migration bytes
         # attributed on rebalance completion.  Exposed through telemetry().
@@ -226,28 +242,35 @@ class PagedEngine:
         token at position ``length``.  ``tenant`` labels the sequence's
         serving class for per-tenant metrics and SLO attribution."""
         cfg, blk = self.cfg, self.pcfg.block_tokens
-        toks = jnp.asarray(prompt)[None]
-        fn = self._prefill_fns.get(len(prompt))
-        if fn is None:
-            n = len(prompt)
-            fn = jax.jit(lambda p, t, n=n: lm.prefill(p, t, cfg, n))
-            self._prefill_fns[n] = fn
-        logits, cache = fn(self.params, toks)
-        first_tok = int(jnp.argmax(logits, -1)[0])
         s = len(prompt)
-        sid = self._next_sid
-        self._next_sid += 1
-        seq = Sequence(
-            sid, region, s, [], list(map(int, prompt)) + [first_tok], tenant=tenant
-        )
         n_blocks = (s + blk - 1) // blk
-        seq.block_ids = [self._alloc_block(region, sid) for _ in range(n_blocks)]
-        # contiguous cache -> pages, installed with one write
-        self.driver.write(
-            jnp.asarray(seq.block_ids, jnp.int32), _cache_pages(cache, cfg, blk)
-        )
-        self.seqs[sid] = seq
+        if n_blocks > self.pcfg.max_blocks_per_seq:
+            raise ValueError(
+                f"a {s}-token prompt needs {n_blocks} pages; max_blocks_per_seq is "
+                f"{self.pcfg.max_blocks_per_seq}"
+            )
+        with self.driver.telemetry.stage("serve.admit"):
+            toks = jnp.asarray(prompt)[None]
+            logits, cache = prefill_program(self.params, toks, cfg=cfg, max_len=s)
+            self.last_logits = logits
+            first_tok = int(jnp.argmax(logits, -1)[0])
+            sid = self._next_sid
+            self._next_sid += 1
+            seq = Sequence(
+                sid, region, s, [], list(map(int, prompt)) + [first_tok], tenant=tenant
+            )
+            seq.block_ids = [self._alloc_block(region, sid) for _ in range(n_blocks)]
+            # contiguous cache -> pages, installed with one write
+            self.driver.write(
+                jnp.asarray(seq.block_ids, jnp.int32), _cache_pages(cache, cfg, blk)
+            )
+            self.seqs[sid] = seq
+            self._count("tokens_prefilled", s)
         return sid
+
+    def _count(self, name: str, n: int) -> None:
+        setattr(self.stats, name, getattr(self.stats, name) + n)
+        self.driver.telemetry.count("serve." + name, n)
 
     def release(self, sid: int) -> None:
         seq = self.seqs.pop(sid)
@@ -269,36 +292,48 @@ class PagedEngine:
             seq = self.seqs[sid]
             tab[i, : len(seq.block_ids)] = seq.block_ids
             lens[i] = seq.length
-        return jnp.asarray(tab), jnp.asarray(lens)
+        return tab, lens
 
     def decode(self, sids: list[int], greedy: bool = True) -> list[int]:
         """One token for each sequence in ``sids``; appends in place."""
         blk = self.pcfg.block_tokens
-        # allocate next block where needed, BEFORE the step
-        for sid in sids:
-            seq = self.seqs[sid]
-            if seq.length % blk == 0 and seq.length // blk >= len(seq.block_ids):
-                seq.block_ids.append(self._alloc_block(seq.region, sid))
-            self._maybe_promote(seq)
-        tables, lens = self._tables(sids)
-        if self.driver.ctx.heat is not None:
-            # attention reads every page behind the frontier: feed the whole
-            # working set into the heat plane (folds into this tick's
-            # megastep — no extra dispatch, see DESIGN.md §13)
-            self.driver.note_reads(
-                np.concatenate(
-                    [np.asarray(self.seqs[s].block_ids, np.int32) for s in sids]
+        with self.driver.telemetry.stage("serve.decode"):
+            # allocate next block where needed, BEFORE the step
+            for sid in sids:
+                seq = self.seqs[sid]
+                if seq.length % blk == 0 and seq.length // blk >= len(seq.block_ids):
+                    if len(seq.block_ids) == self.pcfg.max_blocks_per_seq:
+                        raise ValueError(
+                            f"sequence {sid} outgrows max_blocks_per_seq "
+                            f"({self.pcfg.max_blocks_per_seq} pages)"
+                        )
+                    seq.block_ids.append(self._alloc_block(seq.region, sid))
+                self._maybe_promote(seq)
+            tables, lens = self._tables(sids)
+            if self.driver.ctx.heat is not None:
+                # attention reads every page behind the frontier: feed the whole
+                # working set into the heat plane (folds into this tick's
+                # megastep — no extra dispatch, see DESIGN.md §13)
+                self.driver.note_reads(
+                    np.concatenate(
+                        [np.asarray(self.seqs[s].block_ids, np.int32) for s in sids]
+                    )
                 )
+            self._decode_shapes.add(len(sids))
+            logits, self.driver.state = decode_step_program(
+                self.params, self.driver.state, jnp.asarray(tables), jnp.asarray(lens),
+                self._last_tokens(sids), cfg=self.cfg, blk=blk,
             )
-        self._decode_shapes.add(len(sids))
-        logits, self.driver.state = self._decode_step(
-            self.params, self.driver.state, tables, lens, self._last_tokens(sids)
-        )
-        out = np.asarray(jnp.argmax(logits, -1))
-        for i, sid in enumerate(sids):
-            seq = self.seqs[sid]
-            seq.tokens.append(int(out[i]))
-            seq.length += 1
+            self.last_logits = logits
+            out = np.asarray(jnp.argmax(logits, -1))
+            for i, sid in enumerate(sids):
+                seq = self.seqs[sid]
+                seq.tokens.append(int(out[i]))
+                seq.length += 1
+            self._count("decode_steps", 1)
+            self._count("tokens_decoded", len(sids))
+            # the kernel attends over the cached tokens and the new one
+            self._count("kv_pages_read", int(np.sum(lens // blk + 1)))
         return [int(t) for t in out]
 
     def _last_tokens(self, sids):
@@ -309,8 +344,9 @@ class PagedEngine:
         :meth:`decode` passes (``.compile().as_text()`` shows which kernels
         the compiled step runs)."""
         tables, lens = self._tables(sids)
-        return self._decode_step.lower(
-            self.params, self.driver.state, tables, lens, self._last_tokens(sids)
+        return decode_step_program.lower(
+            self.params, self.driver.state, jnp.asarray(tables), jnp.asarray(lens),
+            self._last_tokens(sids), cfg=self.cfg, blk=self.pcfg.block_tokens,
         )
 
     # -- tier promotion -----------------------------------------------------------
@@ -369,21 +405,22 @@ class PagedEngine:
         """
         seq = self.seqs[sid]
         seq.region = dst_region
-        # Strict-home policy: sequence affinity means the pages go to the
-        # declared home or wait for capacity there — reroute=False so the
-        # session never spills them to neighbouring regions, and the single
-        # returned handle tracks the whole sequence move.
-        handle = None
-        for h in self.session.apply(self, reroute=False):
-            if h.tag == sid:
-                handle = h
-                break
-        if handle is None:
-            # Every page already home: issue a vacuous (instantly-complete)
-            # handle so callers always get a future to wait on.
-            handle = self.session.leap(
-                np.asarray(seq.block_ids, np.int32), dst_region, tag=sid
-            )
+        with self.driver.telemetry.stage("serve.rebalance"):
+            # Strict-home policy: sequence affinity means the pages go to the
+            # declared home or wait for capacity there — reroute=False so the
+            # session never spills them to neighbouring regions, and the single
+            # returned handle tracks the whole sequence move.
+            handle = None
+            for h in self.session.apply(self, reroute=False):
+                if h.tag == sid:
+                    handle = h
+                    break
+            if handle is None:
+                # Every page already home: issue a vacuous (instantly-complete)
+                # handle so callers always get a future to wait on.
+                handle = self.session.leap(
+                    np.asarray(seq.block_ids, np.int32), dst_region, tag=sid
+                )
         self._rebalance_handles[sid] = handle
         tenant = seq.tenant
         handle.on_done(lambda h: self._account_migration(tenant, h))
@@ -514,7 +551,16 @@ def _cache_pages(cache, cfg: ModelConfig, blk: int):
     return jnp.moveaxis(kv, 2, 0)
 
 
-def _paged_step(params, state, tables, lens, toks, cfg: ModelConfig, blk: int):
+def paged_prefill(params, toks, *, cfg: ModelConfig, max_len: int):
+    """Prefill of one prompt: the contiguous path's ``lm.prefill``."""
+    return lm.prefill(params, toks, cfg, max_len)
+
+
+#: The prefill program, one compile per prompt length.
+prefill_program = jax.jit(paged_prefill, static_argnames=("cfg", "max_len"))
+
+
+def paged_decode_step(params, state, tables, lens, toks, *, cfg: ModelConfig, blk: int):
     """One decode token through paged attention for every layer.
 
     Each layer appends its new K/V into the pool in place, then attends over
@@ -558,15 +604,16 @@ def _paged_step(params, state, tables, lens, toks, cfg: ModelConfig, blk: int):
                 kv_heads=cfg.n_kv_heads,
                 layer=li,
                 softcap=cfg.attn_softcap,
+                scale=cfg.attn_scale,
             )
             y = out.reshape(b, 1, -1) @ lp["attn"]["wo"]
-            x = x + y
+            x = residual_add(x, y, cfg)
             h2 = rms_norm(x, lp["norm2"], cfg.norm_eps)
             if kind == "moe":
                 y2, _ = moe_ffn(h2, lp["moe"], cfg)
             else:
                 y2 = mlp_forward(h2, lp["mlp"], cfg.mlp_kind)
-            x = x + y2
+            x = residual_add(x, y2, cfg)
             li += 1
     logits = lm.lm_logits(params, x, cfg)[:, 0]
     dirty = state.dirty.at[append_block].set(
@@ -576,3 +623,9 @@ def _paged_step(params, state, tables, lens, toks, cfg: ModelConfig, blk: int):
         state, pool=pool.reshape(state.pool.shape), dirty=dirty
     )
     return logits, state
+
+
+#: The decode step program: donates the KV state so appends stay in place.
+decode_step_program = jax.jit(
+    paged_decode_step, static_argnames=("cfg", "blk"), donate_argnums=(1,)
+)

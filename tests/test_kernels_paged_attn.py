@@ -11,12 +11,13 @@ from repro.kernels.paged_attn import paged_decode_pallas
 
 N_LAYERS, LAYER = 2, 1
 
-# (B, H, KVH, hd, BLK, MAXB)
+# (B, H, KVH, hd, BLK, MAXB, score scale: None is 1/sqrt(hd))
 CASES = [
-    (2, 4, 2, 64, 8, 4),
-    (1, 8, 1, 128, 16, 3),  # MQA
-    (3, 6, 6, 64, 8, 2),  # MHA
-    (2, 12, 4, 128, 8, 5),  # GQA g=3
+    (2, 4, 2, 64, 8, 4, None),
+    (1, 8, 1, 128, 16, 3, None),  # MQA
+    (3, 6, 6, 64, 8, 2, None),  # MHA
+    (2, 12, 4, 128, 8, 5, None),  # GQA g=3
+    (2, 8, 2, 64, 16, 3, 1 / 64),  # Granite's attention_multiplier, not 1/sqrt(64)
 ]
 
 
@@ -40,15 +41,19 @@ def _tol(dtype):
 @pytest.mark.parametrize("case", CASES)
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_paged_decode_matches_oracle(case, dtype):
-    b, h, kvh, hd, blk, maxb = case
-    q, kv_pool, tables, lens = _setup(*case, dtype)
+    b, h, kvh, hd, blk, maxb, scale = case
+    q, kv_pool, tables, lens = _setup(*case[:6], dtype)
     g = h // kvh
     out, m, l = paged_decode_pallas(
-        q.reshape(b, kvh, g, hd), kv_pool, tables, lens, layer=LAYER, interpret=True
+        q.reshape(b, kvh, g, hd), kv_pool, tables, lens, layer=LAYER, scale=scale,
+        interpret=True,
     )
     want_out, want_m, want_l = ref.paged_decode_ref(
-        q, kv_pool, tables, lens, kv_heads=kvh, layer=LAYER
+        q, kv_pool, tables, lens, kv_heads=kvh, layer=LAYER, scale=scale
     )
+    if scale is not None:  # the scale reaches the scores
+        _, plain_m, _ = ref.paged_decode_ref(q, kv_pool, tables, lens, kv_heads=kvh, layer=LAYER)
+        assert not np.allclose(np.asarray(plain_m), np.asarray(want_m))
     np.testing.assert_allclose(
         np.asarray(out.reshape(b, h, hd), np.float32),
         np.asarray(want_out, np.float32),

@@ -91,6 +91,16 @@ def test_heat_scan_compiles(one_chip):
 
 
 def test_paged_decode_compiles_at_granite_widths(one_chip):
+    _compile_paged_decode(one_chip, scale=None)
+
+
+def test_paged_decode_compiles_at_granite_attention_multiplier(one_chip):
+    """Granite scores q.k / 64 (``attention_multiplier``), not q.k / 8; the
+    scale is static in the kernel, so this is a program of its own."""
+    _compile_paged_decode(one_chip, scale=1 / 64)
+
+
+def _compile_paged_decode(one_chip, scale):
     b, kvh, g, hd, maxb = 8, 8, 4, 64, 72
     pool = _sds((512,) + GRANITE_PAGE, jnp.bfloat16, one_chip)
     q = _sds((b, kvh * g, hd), jnp.bfloat16, one_chip)
@@ -98,7 +108,7 @@ def test_paged_decode_compiles_at_granite_widths(one_chip):
     lens = _sds((b,), jnp.int32, one_chip)
     _compile(
         lambda q, p, t, ln: ops.paged_decode_partial(
-            q, p, t, ln, kv_heads=kvh, layer=39, impl="pallas"
+            q, p, t, ln, kv_heads=kvh, layer=39, scale=scale, impl="pallas"
         ),
         q, pool, tables, lens,
     )
