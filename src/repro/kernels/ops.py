@@ -123,20 +123,20 @@ def paged_decode_partial(
     g = h // kv_heads
     assert g * kv_heads == h, (h, kv_heads)
     kind, interp = _resolve(impl)
-    # pad-position table entries must be valid slot ids for the index map
+    if kind == "pallas":  # the kernel reads only the entries of held pages
+        qg = q.reshape(b, kv_heads, g, hd)
+        out, m, l = paged_attn.paged_decode_pallas(
+            qg, kv_pool, tables, lens, layer=layer, softcap=softcap, scale=scale,
+            interpret=interp,
+        )
+        return out.reshape(b, h, hd), m.reshape(b, h), l.reshape(b, h)
+    # the oracle gathers every entry: point the pad entries at a valid slot
     maxb = tables.shape[1]
     blk = kv_pool.shape[3]
     n_valid = (lens[:, None] + blk - 1) // blk
     safe_tables = jnp.where(
         jnp.arange(maxb)[None, :] < n_valid, tables, 0
     ).astype(jnp.int32)
-    if kind == "pallas":
-        qg = q.reshape(b, kv_heads, g, hd)
-        out, m, l = paged_attn.paged_decode_pallas(
-            qg, kv_pool, safe_tables, lens, layer=layer, softcap=softcap, scale=scale,
-            interpret=interp,
-        )
-        return out.reshape(b, h, hd), m.reshape(b, h), l.reshape(b, h)
     return ref.paged_decode_ref(
         q, kv_pool, safe_tables, lens, kv_heads=kv_heads, layer=layer, softcap=softcap,
         scale=scale,

@@ -20,9 +20,9 @@ logical rows — identical control flow.
 Tracing: ``admit``, ``decode`` and ``rebalance`` run inside the pool's
 telemetry stages ``serve.admit``, ``serve.decode`` and ``serve.rebalance``
 (profiler spans ``leap.serve.*``, on whether telemetry is on or off), and
-:class:`ServeStats` counts steps, tokens and the KV pages the paged kernel
-reads.  The decode step and the prefill are the XLA programs
-``jit_paged_decode_step`` and ``jit_paged_prefill``.
+:class:`ServeStats` counts steps, tokens, the KV pages the paged kernel
+reads and the chunks it copies them in.  The decode step and the prefill
+are the XLA programs ``jit_paged_decode_step`` and ``jit_paged_prefill``.
 """
 
 from __future__ import annotations
@@ -37,7 +37,7 @@ from repro.api import LeapHandle, Move
 from repro.configs.base import ModelConfig
 from repro.core import LeapConfig, MigrationDriver, PoolConfig, init_state
 from repro.core.state import REGION, SLOT, flat_pool_view
-from repro.kernels import ops
+from repro.kernels import ops, paged_attn
 from repro.models import lm
 from repro.models.attention import _project_qkv
 from repro.models.blocks import residual_add
@@ -86,6 +86,9 @@ class ServeStats:
     # pages the paged-attention kernel reads: per step and sequence
     # ceil(tokens attended / block_tokens), each page once per layer
     kv_pages_read: int = 0
+    # chunks of those pages the kernel copies, each layer's counted: per
+    # step, sequence and layer ceil(pages / P), P = paged_attn.chunk_pages
+    kv_chunks_read: int = 0
 
 
 @dataclasses.dataclass
@@ -170,6 +173,10 @@ class PagedEngine:
         # of two (repro.load does) to bound the compile count — and the
         # prefill (``prefill_program``) once per prompt length.
         self._decode_shapes: set[int] = set()  # observed decode batch sizes
+        # pages per chunk of the paged kernel's walk (kv_chunks_read)
+        self._chunk_pages = paged_attn.chunk_pages(
+            pcfg.block_tokens, payload[-1], cfg.dtype(), pcfg.max_blocks_per_seq
+        )
         self.stats = ServeStats()
         # logits of the latest admit ([1, V]) or decode ([B, V]), on the device
         self.last_logits = None
@@ -333,7 +340,10 @@ class PagedEngine:
             self._count("decode_steps", 1)
             self._count("tokens_decoded", len(sids))
             # the kernel attends over the cached tokens and the new one
-            self._count("kv_pages_read", int(np.sum(lens // blk + 1)))
+            held = lens // blk + 1
+            self._count("kv_pages_read", int(np.sum(held)))
+            chunks = -(-held // self._chunk_pages)
+            self._count("kv_chunks_read", int(np.sum(chunks)) * self.cfg.n_layers)
         return [int(t) for t in out]
 
     def _last_tokens(self, sids):

@@ -5,9 +5,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import ops, ref
-from repro.kernels.paged_attn import paged_decode_pallas
+from repro.kernels.paged_attn import chunk_pages, paged_decode_pallas
 
 N_LAYERS, LAYER = 2, 1
 
@@ -65,6 +66,53 @@ def test_paged_decode_matches_oracle(case, dtype):
     np.testing.assert_allclose(
         np.asarray(l.reshape(b, h)), np.asarray(want_l), **_tol(dtype)
     )
+
+
+# The chunk walk: (B, H, KVH, hd, BLK, MAXB, dtype, pages per chunk P).  The
+# page slabs are large enough that P is small, so lengths fall at 1, P*BLK,
+# P*BLK + 1, across three or more chunks, and near MAXB*BLK, where a table
+# of 7 or 26 entries ends inside a chunk.
+WALKS = [
+    (5, 16, 8, 128, 32, 7, jnp.float32, 2),  # 128 KiB slabs
+    (5, 16, 8, 128, 16, 26, jnp.bfloat16, 8),  # 32 KiB slabs
+]
+
+
+@pytest.mark.parametrize("poison", [False, True], ids=["clean", "poison"])
+@pytest.mark.parametrize("walk", WALKS, ids=["f32_P2", "bf16_P8"])
+def test_paged_decode_chunk_walk(walk, poison):
+    """Sequences of one to many chunks, partial last chunks included, match
+    the oracle.  Poisoned: every slot that no sequence holds within its first
+    ``ceil(len / BLK)`` entries is NaN, and the output stays finite and
+    unchanged, so the kernel copies only held pages.  The TPU interpreter
+    fills VMEM with NaN and runs each copy at its wait, so a chunk that
+    computes over a slot no copy filled shows too."""
+    b, h, kvh, hd, blk, maxb, dtype, pages = walk
+    assert chunk_pages(blk, kvh * hd, dtype, maxb) == pages
+    q, kv_pool, tables, _ = _setup(b, h, kvh, hd, blk, maxb, dtype, seed=11)
+    span = pages * blk
+    lens = jnp.asarray([1, span, span + 1, 2 * span + blk + 1, maxb * blk - 1], jnp.int32)
+    assert -(-int(lens[3]) // blk) > 2 * pages  # three chunks
+    want_out, want_m, want_l = ref.paged_decode_ref(
+        q, kv_pool, tables, lens, kv_heads=kvh, layer=LAYER
+    )
+    if poison:
+        held = np.arange(maxb)[None, :] < -(-np.asarray(lens)[:, None] // blk)
+        unheld = np.setdiff1d(np.arange(kv_pool.shape[0]), np.asarray(tables)[held])
+        assert unheld.size  # the pad entries point at some of them
+        kv_pool = kv_pool.at[unheld].set(jnp.nan)
+    out, m, l = paged_decode_pallas(
+        q.reshape(b, kvh, h // kvh, hd), kv_pool, tables, lens, layer=LAYER,
+        interpret=pltpu.InterpretParams(),
+    )
+    assert np.isfinite(np.asarray(out, np.float32)).all()
+    np.testing.assert_allclose(
+        np.asarray(out.reshape(b, h, hd), np.float32),
+        np.asarray(want_out, np.float32),
+        **_tol(dtype),
+    )
+    np.testing.assert_allclose(np.asarray(m.reshape(b, h)), np.asarray(want_m), **_tol(dtype))
+    np.testing.assert_allclose(np.asarray(l.reshape(b, h)), np.asarray(want_l), **_tol(dtype))
 
 
 def test_paged_decode_softcap():

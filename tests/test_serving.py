@@ -12,6 +12,7 @@ import pytest
 from repro.configs.base import get_config
 from repro.configs.smoke import reduce
 from repro.core import LeapConfig
+from repro.kernels import paged_attn
 from repro.models import lm
 from repro.serving.engine import PagedConfig, PagedEngine
 
@@ -73,6 +74,29 @@ def test_paged_batched_multiple_sequences(setup):
         for i, t in enumerate(outs):
             got[i].append(t)
     assert got == want
+
+
+def test_kv_chunks_read_counts_the_kernels_chunks(setup, monkeypatch):
+    """After a decode step ``kv_chunks_read`` is, for every layer, the sum over
+    the batch of ``ceil(ceil(len / BLK) / P)``: the chunks of ``P`` pages the
+    paged kernel copies each sequence's held pages in."""
+    cfg, params = setup
+    width = cfg.n_kv_heads * cfg.head_dim
+    # two 4-token pages a chunk, so that sequences take one to three chunks
+    monkeypatch.setattr(paged_attn, "CHUNK_BYTES", 2 * 4 * width * 4)
+    eng = _engine(cfg, params, leap=LeapConfig(telemetry=True))
+    blk, maxb = eng.pcfg.block_tokens, eng.pcfg.max_blocks_per_seq
+    pages = paged_attn.chunk_pages(blk, width, eng.driver.state.pool.dtype, maxb)
+    assert pages == 2
+    rng = np.random.default_rng(4)
+    sids = [eng.admit(rng.integers(0, cfg.vocab_size, size=n)) for n in (3, 8, 9, 21)]
+    eng.decode(sids)
+    lens = np.asarray([eng.seqs[s].length for s in sids])  # tokens attended
+    held = -(-lens // blk)
+    assert held.tolist() == [1, 3, 3, 6]
+    assert eng.stats.kv_pages_read == held.sum()
+    assert eng.stats.kv_chunks_read == np.sum(-(-held // pages)) * cfg.n_layers == 16
+    assert eng.driver.telemetry.counter_totals()["serve.kv_chunks_read"] == 16
 
 
 def test_decode_correct_under_live_migration(setup):

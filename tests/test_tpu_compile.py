@@ -90,19 +90,28 @@ def test_heat_scan_compiles(one_chip):
     )
 
 
-def test_paged_decode_compiles_at_granite_widths(one_chip):
-    _compile_paged_decode(one_chip, scale=None)
+# (batch, pages per table, pool slots): a small batch, and the shape of the
+# granite.decode_rebalance cell (32 sequences, 4,096 positions, 2 x 2,048 slots)
+PAGED_SHAPES = [(8, 72, 512), (32, 256, 4096)]
 
 
-def test_paged_decode_compiles_at_granite_attention_multiplier(one_chip):
+@pytest.mark.parametrize("shape", PAGED_SHAPES, ids=["small", "cell"])
+def test_paged_decode_compiles_at_granite_widths(one_chip, shape):
+    _compile_paged_decode(one_chip, shape, scale=None)
+
+
+@pytest.mark.parametrize("shape", PAGED_SHAPES, ids=["small", "cell"])
+def test_paged_decode_compiles_at_granite_attention_multiplier(one_chip, shape):
     """Granite scores q.k / 64 (``attention_multiplier``), not q.k / 8; the
     scale is static in the kernel, so this is a program of its own."""
-    _compile_paged_decode(one_chip, scale=1 / 64)
+    _compile_paged_decode(one_chip, shape, scale=1 / 64)
 
 
-def _compile_paged_decode(one_chip, scale):
-    b, kvh, g, hd, maxb = 8, 8, 4, 64, 72
-    pool = _sds((512,) + GRANITE_PAGE, jnp.bfloat16, one_chip)
+def _compile_paged_decode(one_chip, shape, scale):
+    """Compiles within the default scoped VMEM: no limit is raised."""
+    b, maxb, slots = shape
+    kvh, g, hd = 8, 4, 64
+    pool = _sds((slots,) + GRANITE_PAGE, jnp.bfloat16, one_chip)
     q = _sds((b, kvh * g, hd), jnp.bfloat16, one_chip)
     tables = _sds((b, maxb), jnp.int32, one_chip)
     lens = _sds((b,), jnp.int32, one_chip)
