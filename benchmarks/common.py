@@ -115,6 +115,17 @@ def make_pool(
     return cfg, drv, data
 
 
+def flat_copy_ids(drv, ids, dst_region: int, dst_slots):
+    """Operands of ``migrator.fused_copy`` that copy blocks ``ids`` into
+    ``(dst_region, dst_slots)``: flat slot ids, ``region * S + slot``."""
+    table = drv.host_table()
+    s_per = drv.pool_cfg.slots_per_region
+    ids = np.asarray(ids)
+    src = table[ids, 0] * s_per + table[ids, 1]
+    dst = dst_region * s_per + np.asarray(dst_slots)
+    return jnp.asarray(src, jnp.int32), jnp.asarray(dst, jnp.int32)
+
+
 class WriteBurst:
     """Uniform (or skewed) random single-block writes at a requested
     per-tick count, through the leap write path."""
@@ -165,8 +176,7 @@ def warmup_paths(n_blocks: int, block_kb: int, per_ticks=(1,)):
     import numpy as _np
 
     _, drv, _ = make_pool(n_blocks, block_kb,
-                          leap=LeapConfig(initial_area_blocks=8, chunk_blocks=4,
-                                          budget_blocks_per_tick=16))
+                          leap=LeapConfig(initial_area_blocks=8, budget_blocks_per_tick=16))
     for pt in per_ticks:
         if pt:
             WriteBurst(drv, n_blocks, pt).fire()

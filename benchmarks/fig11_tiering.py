@@ -220,7 +220,6 @@ def _serving_case(tiering: bool, topo):
             # every decode step, and must not drag its area-mates' verdicts
             leap=LeapConfig(
                 initial_area_blocks=2,
-                chunk_blocks=1,
                 budget_blocks_per_tick=8,
                 max_attempts_before_force=4,
                 tiering=tiering,

@@ -14,9 +14,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmarks.common import emit, make_pool, timeit
+from benchmarks.common import emit, flat_copy_ids, make_pool, timeit
 from repro.core import leap_read, leap_write
-from repro.core.migrator import copy_chunk
+from repro.core.migrator import fused_copy
 from repro.roofline.model import HBM_BW, ICI_BW
 
 
@@ -32,17 +32,17 @@ def run(n_blocks=256, block_kb=256):
     def local_read(ids):
         return leap_read(drv.state, ids)
 
-    def remote_read(ids):
+    def remote_read(src, dst):
         # access from region 1 to blocks resident on region 0: the bytes
         # cross the interconnect (staging copy into the reader's region)
-        st = copy_chunk(drv.state, ids, staging_slots, 1)
+        st = fused_copy(drv.state, src, dst)
         out = st.pool[1, staging_slots]
         drv.state = st
         return out
 
     for pattern, ids in (("seq", seq_ids), ("rand", rnd_ids)):
         t_loc = timeit(local_read, ids)
-        t_rem = timeit(remote_read, ids)
+        t_rem = timeit(remote_read, *flat_copy_ids(drv, ids, 1, staging_slots))
         modeled = (1 / HBM_BW) / (1 / ICI_BW)
         emit(
             f"fig1/read_{pattern}_local_{total_mb:.0f}MB",

@@ -10,9 +10,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmarks.common import emit, make_pool, timeit
+from benchmarks.common import emit, flat_copy_ids, make_pool, timeit
 from repro.core import SyncResharder
-from repro.core.migrator import copy_chunk
+from repro.core.migrator import fused_copy
 
 
 def run(n_blocks=256, block_kb=256):
@@ -24,8 +24,9 @@ def run(n_blocks=256, block_kb=256):
     cfg, drv, _ = make_pool(n_blocks, block_kb)
     from benchmarks.common import timeit_inplace
 
-    st = copy_chunk(drv.state, ids, slots, 1)  # pre-touch dst slots
-    t_pooled, st = timeit_inplace(lambda s: copy_chunk(s, ids, slots, 1), st)
+    src, dst = flat_copy_ids(drv, ids, 1, slots)
+    st = fused_copy(drv.state, src, dst)  # pre-touch dst slots
+    t_pooled, st = timeit_inplace(lambda s: fused_copy(s, src, dst), st)
 
     # raw copy into fresh memory (zero-fill pass first, like page faults)
     from repro.core.migrator import zero_fill
@@ -33,7 +34,7 @@ def run(n_blocks=256, block_kb=256):
     def fresh(s):
         s = zero_fill(s, slots, 1)
         jax.block_until_ready(s.pool)
-        return copy_chunk(s, ids, slots, 1)
+        return fused_copy(s, src, dst)
 
     t_fresh, st = timeit_inplace(fresh, st)
 

@@ -11,9 +11,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmarks.common import emit, make_pool, timeit
+from benchmarks.common import emit, flat_copy_ids, make_pool, timeit
 from repro.core import LeapConfig, SyncResharder
-from repro.core.migrator import copy_chunk
+from repro.core.migrator import fused_copy
 
 
 def run(n_blocks=512, block_kb=64):
@@ -23,8 +23,9 @@ def run(n_blocks=512, block_kb=64):
     from benchmarks.common import timeit_inplace
 
     cfg, drv, _ = make_pool(n_blocks, block_kb)
-    st = copy_chunk(drv.state, ids, slots, 1)
-    t_opt, _ = timeit_inplace(lambda s: copy_chunk(s, ids, slots, 1), st)
+    src, dst = flat_copy_ids(drv, ids, 1, slots)
+    st = fused_copy(drv.state, src, dst)
+    t_opt, _ = timeit_inplace(lambda s: fused_copy(s, src, dst), st)
     emit(f"fig4/memcpy_optimum_{total_mb:.0f}MB", t_opt * 1e6, "x1.00")
 
     out = {}
@@ -32,7 +33,6 @@ def run(n_blocks=512, block_kb=64):
         area_kb = area_blocks * block_kb
         lc = LeapConfig(
             initial_area_blocks=area_blocks,
-            chunk_blocks=min(area_blocks, 64),
             budget_blocks_per_tick=max(64, area_blocks),
         )
         ts = []

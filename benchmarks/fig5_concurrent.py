@@ -40,7 +40,6 @@ def _no_migration_throughput(n_blocks, block_kb, per_tick, ticks=60):
 def _leap(n_blocks, block_kb, per_tick, skew, area_blocks, label, huge_factor=1):
     lc = LeapConfig(
         initial_area_blocks=area_blocks,
-        chunk_blocks=min(area_blocks, 32),
         budget_blocks_per_tick=64,
         max_attempts_before_force=6,
     )

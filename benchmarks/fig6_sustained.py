@@ -22,8 +22,7 @@ def run(n_blocks=256, block_kb=64, _warmed=[]):
     if not _warmed:
         for pt in (2, 8, 32, 128):  # compile-cache warmup for every shape
             _, d, _ = make_pool(n_blocks, block_kb,
-                                leap=LeapConfig(initial_area_blocks=64, chunk_blocks=32,
-                                                budget_blocks_per_tick=64,
+                                leap=LeapConfig(initial_area_blocks=64, budget_blocks_per_tick=64,
                                                 max_attempts_before_force=6))
             s = LeapSession(d)
             b = WriteBurst(d, n_blocks, pt)
@@ -46,8 +45,8 @@ def run(n_blocks=256, block_kb=64, _warmed=[]):
         base_thr = b0.done / (time.perf_counter() - t0)
 
         # page_leap (recommended initial area)
-        lc = LeapConfig(initial_area_blocks=64, chunk_blocks=32,
-                        budget_blocks_per_tick=64, max_attempts_before_force=6)
+        lc = LeapConfig(initial_area_blocks=64, budget_blocks_per_tick=64,
+                        max_attempts_before_force=6)
         _, d1, _ = make_pool(n_blocks, block_kb, leap=lc)
         s1 = LeapSession(d1)
         b1 = WriteBurst(d1, n_blocks, per_tick)

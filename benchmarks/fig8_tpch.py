@@ -26,8 +26,7 @@ def _mk(leap=None):
     data = tpch.gen_lineitem(N_ROWS, seed=0)
     store = MorselStore.create(
         data, ROWS_PER_MORSEL, n_regions=2, initial_region=0,
-        leap=leap or LeapConfig(initial_area_blocks=32, chunk_blocks=16,
-                                budget_blocks_per_tick=32,
+        leap=leap or LeapConfig(initial_area_blocks=32, budget_blocks_per_tick=32,
                                 max_attempts_before_force=6),
     )
     return data, store

@@ -1,20 +1,18 @@
 """Fig. 9 (beyond paper) — control-path cost of migration dispatch.
 
-Head-to-head of the three dispatch generations: the legacy per-chunk path
-(one jitted program per 16-block chunk and per area, a fresh XLA compile
-for every distinct batch length the adaptive splitter produces), the
-batched path (shape-bucketed fused multi-area programs, <=3 dispatches per
-tick), and the megastep path (the whole tick as ONE device program with a
-budget-floored shared bucket — DESIGN.md §12).  Two workloads:
+The xla backend hands each tick to the device as ONE program, the megastep,
+whose operands pad to a budget-floored shared bucket (DESIGN.md §12).  Two
+workloads:
 
   * ``quiet``  — the fig4 drain (no concurrent writes): pure dispatch count.
   * ``storm``  — the fig5 "high" case (concurrent writes -> dirty retries ->
                  adaptive splitting): unique batch lengths, i.e. compile storm.
 
-Reported per configuration: drain wall-clock (cold: includes compiles, and
+Reported per workload: drain wall-clock (cold: includes compiles, and
 warm: jit caches hot), dispatches/tick, and migration-program jit cache
-misses during the run.  ``derived`` also carries the over-legacy warm-drain
-speedup on the batched and megastep rows.
+misses during the run.  The batched generation runs only on the ppermute
+backend, which needs a device mesh, so this one-device suite has no row for
+it.
 """
 
 import time
@@ -26,13 +24,11 @@ from benchmarks.common import WriteBurst, emit, make_pool
 from repro.core import LeapConfig
 
 
-def _drain(n_blocks, block_kb, fused, per_tick, seed=0):
+def _drain(n_blocks, block_kb, per_tick, seed=0):
     lc = LeapConfig(
         initial_area_blocks=64,
-        chunk_blocks=16,
         budget_blocks_per_tick=64,
         max_attempts_before_force=6,
-        fused_dispatch=fused,
     )
     _, drv, _ = make_pool(n_blocks, block_kb, leap=lc, seed=seed)
     sess = drv.default_session()
@@ -54,24 +50,20 @@ def _drain(n_blocks, block_kb, fused, per_tick, seed=0):
 def run(n_blocks=256, block_kb=64):
     results = {}
     for wl_label, per_tick in (("quiet", 0), ("storm", 8)):
-        for mode in ("legacy", "batched", "megastep"):
-            # cold: first drain of this (mode, workload) pays its compiles;
-            # warm: same shapes again, so wall-clock isolates dispatch count.
-            t_cold, stats_cold = _drain(n_blocks, block_kb, mode, per_tick, seed=0)
-            t_warm, stats_warm = _drain(n_blocks, block_kb, mode, per_tick, seed=1)
-            results[(wl_label, mode)] = t_warm
-            speedup = ""
-            if mode != "legacy":
-                speedup = f";speedup_warm=x{results[(wl_label, 'legacy')] / t_warm:.2f}"
-            emit(
-                f"fig9/{wl_label}/{mode}",
-                t_warm * 1e6,
-                f"cold_us={t_cold * 1e6:.0f}"
-                f";disp_per_tick={stats_warm.dispatches_per_tick:.2f}"
-                f";jit_misses_cold={stats_cold.jit_cache_misses}"
-                f";jit_misses_warm={stats_warm.jit_cache_misses}"
-                f";retries={stats_warm.dirty_rejections}" + speedup,
-            )
+        # cold: first drain of this workload pays its compiles;
+        # warm: same shapes again, so wall-clock isolates dispatch count.
+        t_cold, stats_cold = _drain(n_blocks, block_kb, per_tick, seed=0)
+        t_warm, stats_warm = _drain(n_blocks, block_kb, per_tick, seed=1)
+        results[wl_label] = t_warm
+        emit(
+            f"fig9/{wl_label}/megastep",
+            t_warm * 1e6,
+            f"cold_us={t_cold * 1e6:.0f}"
+            f";disp_per_tick={stats_warm.dispatches_per_tick:.2f}"
+            f";jit_misses_cold={stats_cold.jit_cache_misses}"
+            f";jit_misses_warm={stats_warm.jit_cache_misses}"
+            f";retries={stats_warm.dirty_rejections}",
+        )
     return results
 
 

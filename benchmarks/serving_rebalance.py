@@ -30,8 +30,7 @@ def _engine(cfg, params):
         cfg, params,
         PagedConfig(block_tokens=4, max_blocks_per_seq=32, n_regions=2,
                     slots_per_region=128,
-                    leap=LeapConfig(initial_area_blocks=2, chunk_blocks=1,
-                                    budget_blocks_per_tick=2,
+                    leap=LeapConfig(initial_area_blocks=2, budget_blocks_per_tick=2,
                                     max_attempts_before_force=4)),
     )
 

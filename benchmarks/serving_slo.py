@@ -54,8 +54,7 @@ def _spec(load: float) -> WorkloadSpec:
 def _run_one(scheduler: str, load: float) -> dict:
     cfg = dataclasses.replace(reduce(get_config("granite_3_2b")), n_layers=2)
     params = lm.init_params(jax.random.key(0), cfg)
-    leap = LeapConfig(initial_area_blocks=2, chunk_blocks=1,
-                      budget_blocks_per_tick=8, max_attempts_before_force=4)
+    leap = LeapConfig(initial_area_blocks=2, budget_blocks_per_tick=8, max_attempts_before_force=4)
     if common.TRACING:
         leap = dataclasses.replace(leap, telemetry=True)
     eng = PagedEngine(

@@ -4,8 +4,8 @@ under concurrent writes (100K-writes/s analogue = the "high" case).
 memory overhead: extra bytes copied due to dirty retries (stats-based).
 time overhead: wall time over copying the same useful bytes via raw copy.
 control-path cost: device dispatches per tick and migration-program jit
-compiles incurred during the run (fig9_dispatch.py measures these head to
-head against the legacy per-chunk dispatch path).
+compiles incurred during the run (fig9_dispatch.py measures these on the
+drain and retry-storm workloads).
 
 Runs the default dispatch generation (megastep: the whole tick as ONE
 device program) with ``warm_dispatch=True``: steady-state variants compile
@@ -20,9 +20,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmarks.common import WriteBurst, emit, make_pool, timeit
+from benchmarks.common import WriteBurst, emit, flat_copy_ids, make_pool, timeit
 from repro.core import LeapConfig
-from repro.core.migrator import copy_chunk
+from repro.core.migrator import fused_copy
 
 
 def run(n_blocks=256, block_kb=64, per_tick=8):
@@ -30,14 +30,14 @@ def run(n_blocks=256, block_kb=64, per_tick=8):
 
     ids, slots = jnp.arange(n_blocks), jnp.arange(n_blocks)
     cfg, drv0, _ = make_pool(n_blocks, block_kb)
-    st = copy_chunk(drv0.state, ids, slots, 1)
-    t_opt, _ = timeit_inplace(lambda s: copy_chunk(s, ids, slots, 1), st)
+    src, dst = flat_copy_ids(drv0, ids, 1, slots)
+    st = fused_copy(drv0.state, src, dst)
+    t_opt, _ = timeit_inplace(lambda s: fused_copy(s, src, dst), st)
     useful_mb = n_blocks * block_kb / 1024
 
     for area_blocks in (1, 8, 64, 256):
         lc = LeapConfig(
             initial_area_blocks=area_blocks,
-            chunk_blocks=min(area_blocks, 32),
             budget_blocks_per_tick=64,
             max_attempts_before_force=8,
             warm_dispatch=True,

@@ -172,7 +172,6 @@ def pool_phase(seed, n_blocks, *, huge_factor=1, burst=32, reads=256, expect_tpu
     tiering = huge_factor > 1
     cfg = PoolConfig(2, n_blocks, BLOCK, jnp.float32, huge_factor=huge_factor)
     leap = LeapConfig(tiering=tiering)
-    check(leap.copy_impl is None, "copy_impl must stay on the default dispatch")
     driver = MigrationDriver(init_state(cfg, n_blocks, np.zeros(n_blocks)), cfg, leap)
     session = driver.default_session()
     rng = np.random.default_rng(seed)
@@ -250,7 +249,6 @@ def serving_phase(seed, *, smoke=False, prompt_lens=(1024, 128), requests=8, tok
         f"heads={cfg.n_heads}/{cfg.n_kv_heads}x{cfg.head_dim}, weights built in "
         f"{time.perf_counter() - t0:.1f} s")
     pcfg = serve.paged_config(smoke, 2, max(prompt_lens) + tokens)
-    check(pcfg.leap.copy_impl is None, "copy_impl must stay on the default dispatch")
     eng = PagedEngine(cfg, params, pcfg)
     rng = np.random.default_rng(seed)
     prompts = [

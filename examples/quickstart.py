@@ -27,7 +27,6 @@ def main():
         cfg,
         LeapConfig(
             initial_area_blocks=16,  # start coarse ("16MB sweet spot")
-            chunk_blocks=4,  # copy 4 blocks per dispatch
             budget_blocks_per_tick=8,  # async budget per tick
             max_attempts_before_force=4,  # write-through escalation
         ),

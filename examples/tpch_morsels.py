@@ -23,8 +23,7 @@ def main():
     data = tpch.gen_lineitem(n_rows, seed=0)
     store = MorselStore.create(
         data, rows_per_morsel=1024, n_regions=2, initial_region=0,
-        leap=LeapConfig(initial_area_blocks=32, chunk_blocks=16,
-                        budget_blocks_per_tick=32),
+        leap=LeapConfig(initial_area_blocks=32, budget_blocks_per_tick=32),
     )
     print(f"lineitem: {n_rows} rows in {store.n_morsels} morsels on region 0")
 

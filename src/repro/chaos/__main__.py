@@ -16,12 +16,11 @@ continues, so one bad seed never hides another.
 from __future__ import annotations
 
 import argparse
-import dataclasses
 import sys
 
 from repro.chaos.driver import run_scenario, run_with_repro
 from repro.chaos.invariants import InvariantViolation
-from repro.chaos.spec import DISPATCH_MODES, ScenarioSpec
+from repro.chaos.spec import ScenarioSpec
 from repro.chaos.strategies import sample_spec
 
 
@@ -49,20 +48,11 @@ def main(argv=None) -> int:
     p.add_argument(
         "--sabotage", default=None, help="deliberately inject a known bug (testing)"
     )
-    p.add_argument(
-        "--dispatch",
-        default=None,
-        choices=DISPATCH_MODES,
-        help="override every scenario's dispatch generation "
-        "(CI runs the sweep under megastep AND legacy)",
-    )
     args = p.parse_args(argv)
 
     if args.replay:
         with open(args.replay) as f:
             spec = ScenarioSpec.from_json(f.read())
-        if args.dispatch:
-            spec = dataclasses.replace(spec, dispatch=args.dispatch)
         try:
             report = run_scenario(spec, sabotage=args.sabotage)
         except InvariantViolation as e:
@@ -74,8 +64,6 @@ def main(argv=None) -> int:
     failures = 0
     for seed in range(args.start, args.start + args.count):
         spec = sample_spec(seed)
-        if args.dispatch:
-            spec = dataclasses.replace(spec, dispatch=args.dispatch)
         try:
             report = run_with_repro(spec, args.repro_dir, sabotage=args.sabotage)
         except InvariantViolation as e:

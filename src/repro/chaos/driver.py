@@ -111,11 +111,9 @@ class ChaosDriver:
         state = leap_write(state, jnp.arange(spec.n_blocks), jnp.asarray(data))
         cfg = LeapConfig(
             initial_area_blocks=spec.initial_area_blocks,
-            chunk_blocks=spec.chunk_blocks,
             budget_blocks_per_tick=spec.budget_blocks_per_tick,
             max_attempts_before_force=spec.max_attempts_before_force,
             demote_after_attempts=spec.demote_after_attempts,
-            fused_dispatch=spec.dispatch,
             tiering=spec.tiering,
             # Always record under chaos: a failing run dumps its trace next
             # to the repro spec, and the drift property test replays the
@@ -148,11 +146,9 @@ class ChaosDriver:
         cfg_m, params = _tiny_model()
         leap = LeapConfig(
             initial_area_blocks=spec.initial_area_blocks,
-            chunk_blocks=spec.chunk_blocks,
             budget_blocks_per_tick=spec.budget_blocks_per_tick,
             max_attempts_before_force=spec.max_attempts_before_force,
             demote_after_attempts=spec.demote_after_attempts,
-            fused_dispatch=spec.dispatch,
             tiering=spec.tiering,
             telemetry=True,
         )

@@ -54,7 +54,6 @@ EVENT_KINDS = (
 
 WORKLOADS = ("drain", "stream", "exchange", "serving", "working_set_shift")
 SCHEDULERS = ("leap", "sync", "sampling", "slo")
-DISPATCH_MODES = ("legacy", "batched", "megastep")
 
 #: Fault kinds a "serving" workload admits.  The others (write_burst,
 #: out_of_slots) address raw pool block ids directly — under serving the
@@ -109,9 +108,7 @@ class ScenarioSpec:
 
     # -- engine -------------------------------------------------------------
     scheduler: str = "leap"
-    dispatch: str = "megastep"  # dispatch generation (LeapConfig.fused_dispatch)
     initial_area_blocks: int = 4
-    chunk_blocks: int = 2
     budget_blocks_per_tick: int = 4
     max_attempts_before_force: int = 3
     demote_after_attempts: int = 2
@@ -175,8 +172,6 @@ class ScenarioSpec:
             raise ValueError(f"workload must be one of {WORKLOADS}")
         if self.scheduler not in SCHEDULERS:
             raise ValueError(f"scheduler must be one of {SCHEDULERS}")
-        if self.dispatch not in DISPATCH_MODES:
-            raise ValueError(f"dispatch must be one of {DISPATCH_MODES}")
         if self.placement not in PLACEMENTS:
             raise ValueError(f"placement must be one of {PLACEMENTS}")
         if self.topology not in TOPOLOGIES:

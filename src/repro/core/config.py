@@ -17,26 +17,22 @@ class LeapConfig:
     initial_area_blocks: int = 64  # "initial area size" (16MB sweet spot)
     reduction_factor: int = 2  # split factor on dirty retry
     min_area_blocks: int = 1
-    chunk_blocks: int = 16  # copy-dispatch granularity (legacy dispatch path)
     budget_blocks_per_tick: int = 64  # async migration budget per tick/step
     max_attempts_before_force: int = 8  # write-through escalation (beyond paper)
+    # Copy backend; it also fixes the dispatch generation (DESIGN.md §3, §12):
+    # "xla" runs the megastep (the whole tick as ONE device program),
+    # "ppermute" the batched programs, whose shard_map copies have static
+    # (src, dst) endpoints and cannot fuse into one variant-stable program.
     backend: str = "xla"  # "xla" | "ppermute"
     axis_name: str | None = None  # region mesh axis (ppermute backend)
-    # Dispatch generation (DESIGN.md §3, §12).  True (default) selects the
-    # megastep — the whole tick as ONE device program; "batched" selects the
-    # previous generation (<=3 bucketed programs per tick); False/"legacy"
-    # selects per-area/per-chunk dispatch.  Booleans are accepted for
-    # backwards compatibility with every existing call site.
-    fused_dispatch: bool | str = True
     # Ahead-of-time compile the megastep's steady-state variants at driver
-    # construction (megastep mode only; no-op otherwise).  Possible because
+    # construction (xla backend only; no-op otherwise).  Possible because
     # the budget-floored shared bucket fixes every steady-state operand shape
     # before any workload runs — moves XLA compiles off the migration path
     # entirely, so the first leap() pays no compile stall.  Off by default:
     # construction grows by a few hundred ms of compile time.
     warm_dispatch: bool = False
     bucket_growth: int = 4  # geometric padding factor for batch shapes
-    copy_impl: str | None = None  # leap_copy impl: None=auto|"pallas"|"ref"
     # Two-tier pool knobs (active when PoolConfig.huge_factor > 1):
     demote_after_attempts: int = 2  # huge-commit rejections before demotion (§4.2)
     promote_cold_ticks: int = 0  # ticks since last write required to promote
@@ -65,31 +61,3 @@ class LeapConfig:
     telemetry_events: int = 65536  # event ring capacity (oldest evicted)
     telemetry_requests: int = 1024  # resolved request spans retained (LRU)
 
-    _DISPATCH_MODES = (True, False, "legacy", "batched", "megastep")
-
-    def __post_init__(self) -> None:
-        if self.fused_dispatch not in self._DISPATCH_MODES:
-            raise ValueError(
-                f"fused_dispatch must be one of {self._DISPATCH_MODES}, "
-                f"got {self.fused_dispatch!r}"
-            )
-
-    @property
-    def dispatch_mode(self) -> str:
-        """Resolved dispatch generation: "legacy" | "batched" | "megastep".
-
-        ``fused_dispatch`` is a bool-or-string knob (booleans kept for
-        backwards compatibility): False/"legacy" is per-area dispatch,
-        "batched" the <=3-programs-per-tick generation, True/"megastep" the
-        single-dispatch tick.  The ppermute backend routes point-to-point
-        copies through shard_map programs with *static* (src, dst) endpoints,
-        which cannot fuse into one variant-stable program — megastep falls
-        back to batched there.
-        """
-        if self.fused_dispatch in (False, "legacy"):
-            return "legacy"
-        if self.fused_dispatch == "batched":
-            return "batched"
-        if self.backend == "ppermute":
-            return "batched"
-        return "megastep"

@@ -333,8 +333,9 @@ class MigrationDriver:
         (verdict stage), (ii) dispatches commits for areas whose copy
         completed in an earlier tick, (iii) advances copies of open epochs
         and opens new epochs within the budget stage's grants (dispatch
-        stage).  By default the whole tick is ONE fused device program (the
-        megastep, DESIGN.md §12; <=3 programs under batched dispatch);
+        stage).  On the xla backend the whole tick is ONE fused device
+        program (the megastep, DESIGN.md §12; the ppermute backend issues
+        one batched program per tick phase);
         dispatches are async either way — interleave application steps
         between ticks for concurrency.
         """

@@ -2,55 +2,42 @@
 
 An area's life cycle (driven from the host by :mod:`repro.core.driver`):
 
-    begin_area   -> open the copy epoch (set in_flight, clear dirty)
-    copy_chunk*  -> physical copy, source region -> pooled destination slots
-                    (budgeted; an epoch may span many steps, which is the
-                    window in which concurrent writes can dirty a block)
-    commit_area  -> the atomic "remap": flip table entries of *clean* blocks
-                    to their destination, return the dirty verdict so the
-                    host can requeue dirty blocks with adaptive splitting
+    begin  -> open the copy epoch (set in_flight, clear dirty)
+    copy   -> physical copy, source region -> pooled destination slots
+              (budgeted; an epoch may span many ticks, which is the window
+              in which concurrent writes can dirty a block)
+    commit -> the atomic "remap": flip table entries of *clean* blocks to
+              their destination, return the dirty verdict so the host can
+              requeue dirty blocks with adaptive splitting
 
-``force_migrate`` fuses copy+flip into one XLA program.  Because writes are
-serialized against programs at step granularity, a fused copy+flip has no
-race window at all — this is the write-through escalation that gives the
-(beyond-paper) deterministic-termination guarantee.
+``force`` fuses copy+flip into one step.  Because writes are serialized
+against programs at step granularity, a fused copy+flip has no race window
+at all — this is the write-through escalation that gives the (beyond-paper)
+deterministic-termination guarantee.
 
-Two copy backends:
+Two copy backends, each with its one dispatch generation (DESIGN.md §3, §12):
 
-  * ``xla``       — indexed gather/scatter across the sharded region dim;
-                    GSPMD materializes the cross-region traffic.  Works on
-                    any mesh (incl. compound ("pod","data") region axes) and
-                    on a single device.
-  * ``ppermute``  — shard_map + ``lax.ppermute`` with *static* src/dst
-                    regions: exactly one point-to-point ICI transfer of the
-                    area bytes (the `memcpy` analogue).  The local HBM
-                    gather/scatter packing inside the shard is the
-                    ``leap_copy`` Pallas kernel on TPU.
+  * ``xla``       — the :func:`megastep` program: the whole tick (commit
+                    verdicts of the previous epoch, then begin/zero/force/
+                    copy/run phases) fused into ONE device program over the
+                    donated flat pool view, its index operands packed into
+                    one int32 vector (one host-to-device transfer) and
+                    padded to one budget-floored bucket, so one compiled
+                    variant serves every tick.  Works on any mesh and on
+                    one device.
+  * ``ppermute``  — the batched programs (``begin_areas``/
+                    ``fused_copy_ppermute``/``commit_areas``/``force_areas``):
+                    one dispatch per tick phase covers every area scheduled
+                    this tick.  The copy is a shard_map + ``lax.ppermute``
+                    with *static* src/dst regions: exactly one point-to-point
+                    ICI transfer of the scheduled bytes (the ``memcpy``
+                    analogue), whose local HBM packing is the ``leap_copy``
+                    Pallas kernel on TPU.  Static endpoints are why this
+                    backend cannot fuse into the megastep.
 
-Three dispatch generations (DESIGN.md §3, §12):
-
-  * the per-area/per-chunk programs (``begin_area``/``copy_chunk``/
-    ``commit_area``/``force_migrate``) — one dispatch per chunk and per area,
-    with the destination region baked in statically; retained as the
-    benchmark baseline and for callers that drive single areas directly;
-  * the batched programs (``begin_areas``/``fused_copy``/``commit_areas``/
-    ``force_areas``) — one dispatch covers every area the driver scheduled
-    this tick (<=3 programs per tick).  Batch lengths are padded to geometric
-    buckets by replicating lane 0 (idempotent duplicate updates), so the jit
-    cache holds O(log n) entries however the adaptive splitter fragments the
-    work, and the destination region is a traced operand rather than a
-    static one;
-  * the :func:`megastep` program — the whole tick (commit verdicts of the
-    previous epoch, then begin/zero/force/copy/run phases) fused into ONE
-    device program over the flat pool view, with the pool buffers donated
-    and the dirty verdict produced on device.  The host packs every index
-    operand into one int32 vector, sliced in the program at static offsets,
-    so a tick makes one host-to-device transfer.  Every phase operand shares a
-    single bucketed batch length, floored at the steady-state tick budget,
-    and phases pad with *out-of-bounds sentinel* lanes (JAX drops
-    out-of-bounds scatter updates) so one compiled variant serves every
-    tick — including retry storms, whose fragmented batch lengths all round
-    up to the same bucket.
+:func:`fused_copy` is the standalone single-program xla copy; no engine path
+calls it, but tests, ``launch/dryrun.py`` and the CPU microbenchmarks use it
+to time or check one copy on its own.
 """
 
 from __future__ import annotations
@@ -71,130 +58,8 @@ from jax.sharding import PartitionSpec as P
 
 
 # --------------------------------------------------------------------------
-# Epoch control
-# --------------------------------------------------------------------------
-
-
-@partial(jax.jit, donate_argnames=("state",))
-def begin_area(state: LeapState, block_ids: jax.Array) -> LeapState:
-    """Open a copy epoch: mark blocks in flight, clear their dirty bits."""
-    in_flight = state.in_flight.at[block_ids].set(True)
-    dirty = state.dirty.at[block_ids].set(False)
-    return dataclasses.replace(state, in_flight=in_flight, dirty=dirty)
-
-
-@partial(jax.jit, donate_argnames=("state",), static_argnames=("dst_region",))
-def copy_chunk(
-    state: LeapState,
-    block_ids: jax.Array,
-    dst_slots: jax.Array,
-    dst_region: int,
-) -> LeapState:
-    """Physical copy of ``block_ids`` into ``(dst_region, dst_slots)``.
-
-    Pure data movement — the table is untouched, so readers keep hitting the
-    source location (non-atomic copy phase, exactly as in the paper).
-    """
-    loc = state.table[block_ids]
-    src = state.pool[loc[:, REGION], loc[:, SLOT]]
-    pool = state.pool.at[dst_region, dst_slots].set(src)
-    return dataclasses.replace(state, pool=pool)
-
-
-def _ppermute_local(src_region, dst_region, axis_name, pool, table, block_ids, dst_slots):
-    # pool arrives as the local shard [R/axis, S, *blk]; with one region per
-    # shard, index 0 is "my region".
-    slots = table[block_ids, SLOT]
-    buf = pool[0, slots]  # garbage on non-source shards; masked below
-    recv = lax.ppermute(buf, axis_name, perm=[(src_region, dst_region)])
-    me = lax.axis_index(axis_name)
-    cur = pool[0, dst_slots]
-    upd = jnp.where(me == dst_region, recv, cur)
-    return pool.at[0, dst_slots].set(upd)
-
-
-@partial(
-    jax.jit,
-    donate_argnames=("state",),
-    static_argnames=("src_region", "dst_region", "axis_name", "mesh"),
-)
-def copy_chunk_ppermute(
-    state: LeapState,
-    block_ids: jax.Array,
-    dst_slots: jax.Array,
-    src_region: int,
-    dst_region: int,
-    axis_name: str,
-    mesh: jax.sharding.Mesh,
-) -> LeapState:
-    """Point-to-point copy backend: one ``ppermute`` of exactly the area bytes."""
-    fn = shard_map(
-        partial(_ppermute_local, src_region, dst_region, axis_name),
-        mesh=mesh,
-        in_specs=(
-            P(axis_name),  # pool: region dim sharded
-            P(),  # table replicated
-            P(),  # block ids replicated
-            P(),  # dst slots replicated
-        ),
-        out_specs=P(axis_name),
-    )
-    pool = fn(state.pool, state.table, block_ids, dst_slots)
-    return dataclasses.replace(state, pool=pool)
-
-
-@partial(jax.jit, donate_argnames=("state",), static_argnames=("dst_region",))
-def commit_area(
-    state: LeapState,
-    block_ids: jax.Array,
-    dst_slots: jax.Array,
-    dst_region: int,
-) -> tuple[LeapState, jax.Array]:
-    """The atomic remap: flip table entries of clean blocks; report dirty ones.
-
-    Mirrors Fig. 3b of the paper: a block that became dirty during its copy
-    epoch keeps its old mapping (the stale destination copy is discarded by
-    the host, which frees the reserved slots and requeues a split area).
-    """
-    verdict = state.dirty[block_ids]  # True => copy invalidated
-    proposed = jnp.stack(
-        [jnp.full_like(dst_slots, dst_region), dst_slots], axis=1
-    ).astype(state.table.dtype)
-    new_entries = jnp.where(verdict[:, None], state.table[block_ids], proposed)
-    table = state.table.at[block_ids].set(new_entries)
-    in_flight = state.in_flight.at[block_ids].set(False)
-    return dataclasses.replace(state, table=table, in_flight=in_flight), verdict
-
-
-@partial(jax.jit, donate_argnames=("state",), static_argnames=("dst_region",))
-def force_migrate(
-    state: LeapState,
-    block_ids: jax.Array,
-    dst_slots: jax.Array,
-    dst_region: int,
-) -> LeapState:
-    """Fused copy+remap (write-through escalation): no race window exists.
-
-    Any write dispatched before this program is copied; any write dispatched
-    after it goes through the already-flipped table.  Used by the driver after
-    ``max_attempts`` dirty rejections to guarantee termination (beyond-paper).
-    """
-    loc = state.table[block_ids]
-    src = state.pool[loc[:, REGION], loc[:, SLOT]]
-    pool = state.pool.at[dst_region, dst_slots].set(src)
-    entries = jnp.stack(
-        [jnp.full_like(dst_slots, dst_region), dst_slots], axis=1
-    ).astype(state.table.dtype)
-    table = state.table.at[block_ids].set(entries)
-    in_flight = state.in_flight.at[block_ids].set(False)
-    dirty = state.dirty.at[block_ids].set(False)
-    return dataclasses.replace(
-        state, pool=pool, table=table, in_flight=in_flight, dirty=dirty
-    )
-
-
-# --------------------------------------------------------------------------
-# Batched dispatch: one device program per tick phase, multi-area, bucketed.
+# Batched dispatch (ppermute backend): one device program per tick phase,
+# multi-area, bucketed.
 #
 # All batch operands are padded to a bucket length by REPLICATING LANE 0
 # (adaptive.pad_to_bucket).  Duplicate lanes re-apply lane 0's update with
@@ -220,9 +85,11 @@ def fused_copy(
     dst_flat: jax.Array,
     impl: str | None = None,
 ) -> LeapState:
-    """Physical copy of the whole tick's chunk plan in one program.
+    """Physical copy of ``src_flat`` into ``dst_flat`` in one program.
 
-    ``src_flat``/``dst_flat`` are flat slot ids (``region * S + slot``,
+    The standalone xla copy: tests, ``launch/dryrun.py`` and the CPU
+    microbenchmarks call it directly (the engine's xla copy rides the
+    megastep).  ``src_flat``/``dst_flat`` are flat slot ids (``region * S + slot``,
     host-computed from the exact table mirror), so one compiled variant moves
     blocks between arbitrary region pairs.  The move itself is the
     ``leap_copy`` intra-pool kernel: on TPU one HBM-to-HBM DMA per block,
@@ -231,60 +98,6 @@ def fused_copy(
     flat = flat_pool_view(state.pool)
     flat = ops.copy_blocks_impl(flat, src_flat, dst_flat, impl=impl)
     return dataclasses.replace(state, pool=flat.reshape(state.pool.shape))
-
-
-@partial(jax.jit, donate_argnames=("state",), static_argnames=("run", "impl"))
-def fused_copy_runs(
-    state: LeapState,
-    src_starts: jax.Array,
-    dst_starts: jax.Array,
-    run: int,
-    impl: str | None = None,
-) -> LeapState:
-    """Physical copy of whole huge blocks: one contiguous-run move per block.
-
-    ``src_starts``/``dst_starts`` are flat slot ids of each run's first slot
-    (``region * S + start``; G-aligned and intra-region because the buddy
-    allocator hands out aligned runs and G divides S).  A huge block moves as
-    ONE area through ONE kernel step — a single DMA of ``run`` contiguous
-    slots via ``copy_runs`` — instead of ``run`` per-slot gathers.
-    """
-    flat = flat_pool_view(state.pool)
-    flat = ops.copy_runs_impl(flat, src_starts, dst_starts, run=run, impl=impl)
-    return dataclasses.replace(state, pool=flat.reshape(state.pool.shape))
-
-
-@partial(jax.jit, donate_argnames=("state",), static_argnames=("group",))
-def commit_groups(
-    state: LeapState,
-    block_ids: jax.Array,
-    dst_regions: jax.Array,
-    dst_starts: jax.Array,
-    group: int,
-) -> tuple[LeapState, jax.Array]:
-    """All-or-nothing remap of huge areas; one verdict lane per group.
-
-    ``block_ids`` is ``[K * group]`` (K huge areas' members, group-major);
-    ``dst_regions``/``dst_starts`` are ``[K]`` level-1 destinations.  A group
-    is dirty iff ANY member was written during the copy epoch — a huge entry
-    maps all its small blocks at once, so a partially-stale run cannot flip
-    (mirroring a huge-page PTE: there is no per-4K remap under a 2M mapping).
-    Padding replicates lane-0's whole GROUP, which keeps the program
-    idempotent under duplicate lanes just like the per-block programs.
-    """
-    k = dst_starts.shape[0]
-    members = block_ids.reshape(k, group)
-    verdict = state.dirty[members].any(axis=1)  # True => whole run invalidated
-    member_slots = dst_starts[:, None] + jnp.arange(group)[None, :]
-    proposed = jnp.stack(
-        [jnp.broadcast_to(dst_regions[:, None], (k, group)), member_slots], axis=-1
-    ).astype(state.table.dtype)
-    new_entries = jnp.where(
-        verdict[:, None, None], state.table[members], proposed
-    )
-    table = state.table.at[members.reshape(-1)].set(new_entries.reshape(-1, 2))
-    in_flight = state.in_flight.at[block_ids].set(False)
-    return dataclasses.replace(state, table=table, in_flight=in_flight), verdict
 
 
 @partial(jax.jit, donate_argnames=("state",))
@@ -296,8 +109,11 @@ def commit_areas(
 ) -> tuple[LeapState, jax.Array]:
     """Atomic remap of every commit-ready area, returning one packed verdict.
 
-    Same per-block semantics as :func:`commit_area`; the host slices the
-    packed verdict vector back into per-area views at known offsets.
+    Mirrors Fig. 3b of the paper: a block that became dirty during its copy
+    epoch keeps its old mapping (the stale destination copy is discarded by
+    the host, which frees the reserved slots and requeues a split area).
+    The host slices the packed verdict vector back into per-area views at
+    known offsets.
     """
     verdict = state.dirty[block_ids]  # True => copy invalidated
     proposed = jnp.stack([dst_regions, dst_slots], axis=1).astype(state.table.dtype)
@@ -314,7 +130,12 @@ def force_areas(
     dst_regions: jax.Array,
     dst_slots: jax.Array,
 ) -> LeapState:
-    """Batched write-through escalation: fused copy+flip for every forced area."""
+    """Batched write-through escalation: fused copy+flip for every forced area.
+
+    Any write dispatched before this program is copied; any write dispatched
+    after it goes through the already-flipped table.  Used by the driver after
+    ``max_attempts`` dirty rejections to guarantee termination (beyond-paper).
+    """
     loc = state.table[block_ids]
     src = state.pool[loc[:, REGION], loc[:, SLOT]]
     pool = state.pool.at[dst_regions, dst_slots].set(src)
@@ -502,6 +323,9 @@ def megastep(
     else:
         verdict_small = jnp.zeros((0,), dtype=jnp.bool_)
 
+    # A group is dirty iff ANY member was written during its copy epoch: a
+    # huge entry maps all its small blocks at once (like a huge-page PTE), so
+    # a partially stale run cannot flip.
     if grp_starts.shape[0]:
         k = grp_starts.shape[0]
         members = grp_members.reshape(k, group)
@@ -566,9 +390,9 @@ def heat_update(
     decay: float,
     impl: str | None = None,
 ) -> jax.Array:
-    """Standalone access-heat pass for the batched/legacy dispatch
-    generations (under megastep the same update rides the tick's single
-    program as its trailing phase)."""
+    """Standalone access-heat pass for the batched generation (under the
+    megastep the same update rides the tick's single program as its
+    trailing phase)."""
     return ops.heat_scan_impl(heat, ids, w, decay, impl=impl)
 
 
@@ -580,16 +404,9 @@ _PROGRAMS = {
     "megastep": megastep,
     "heat_update": heat_update,
     "zero_fill": zero_fill,
-    "begin_area": begin_area,
-    "copy_chunk": copy_chunk,
-    "copy_chunk_ppermute": copy_chunk_ppermute,
-    "commit_area": commit_area,
-    "force_migrate": force_migrate,
     "begin_areas": begin_areas,
     "fused_copy": fused_copy,
-    "fused_copy_runs": fused_copy_runs,
     "commit_areas": commit_areas,
-    "commit_groups": commit_groups,
     "force_areas": force_areas,
     "fused_copy_ppermute": fused_copy_ppermute,
 }

@@ -2,10 +2,10 @@
 
 Owns the per-tick scheduling loop (``run_tick``): advances copies of open
 epochs, opens new epochs off the priority queue, and hands the tick's work
-to the device in one of three dispatch generations
-(``LeapConfig.dispatch_mode``):
+to the device in the one dispatch generation its copy backend
+(``LeapConfig.backend``) runs:
 
-  * ``"megastep"`` (default) — the entire tick is ONE device program
+  * ``xla`` (default): the megastep — the entire tick is ONE device program
     (:func:`repro.core.migrator.megastep`): the previous epoch's commits,
     then begin/zero/force/copy, over the donated flat pool view.  The host
     side of this stage is pure *plan assembly*: it gathers numpy id vectors,
@@ -14,11 +14,13 @@ to the device in one of three dispatch generations
     crosses back here — it stays device-resident inside the
     :class:`~repro.core.queues.CommitBatch` future, harvested by the verdict
     stage off the tick critical path (DESIGN.md §12).
-  * ``"batched"`` — the previous generation: at most three fused programs
-    per tick (``begin_areas``, ``fused_copy`` + one contiguous-run program
-    for huge blocks, ``commit_areas``/``commit_groups``), padded to
-    geometric buckets so the jit cache stays O(log n) (DESIGN.md §3).
-  * ``"legacy"`` — per-chunk/per-area dispatch (the benchmark baseline).
+  * ``ppermute``: the batched programs — one fused program per tick phase
+    (``begin_areas``, zero fill, ``force_areas``, one ``fused_copy_ppermute``
+    per region pair, ``commit_areas``), padded to geometric buckets so the
+    jit cache stays O(log n) (DESIGN.md §3).  Its copies are shard_map
+    programs with static endpoints, which cannot fuse into the megastep.
+    The driver refuses a two-tier pool on this backend, so no area here is
+    huge.
 
 Budget decisions (how much a link grants, congestion deferral) come from
 the budget stage; dirty verdicts are harvested later by the verdict stage.
@@ -50,11 +52,9 @@ class DispatchStage:
         self.ctx = ctx
         self.budget = budget
         self.accounting = accounting
-        # Dispatch generation, resolved once ("legacy"|"batched"|"megastep").
-        # cfg.fused_dispatch is a bool-or-string knob and the string "legacy"
-        # is truthy, so every branch below compares modes, never truthiness.
-        self._mode = ctx.cfg.dispatch_mode
-        self._fused = self._mode != "legacy"
+        # Dispatch generation, fixed by the copy backend: batched programs
+        # for ppermute, the megastep for xla.
+        self._batched = ctx.cfg.backend == "ppermute"
         # Source slots freed by this tick's forced escalations, quarantined
         # until the tick's device batches are dispatched (see run_tick).
         self._freed: list[np.ndarray] = []
@@ -62,7 +62,7 @@ class DispatchStage:
         # tick's single dispatch (they stay in ctx.active until it fires).
         self._staged_small: list[Area] = []
         self._staged_huge: list[Area] = []
-        if self._mode == "megastep" and ctx.cfg.warm_dispatch:
+        if not self._batched and ctx.cfg.warm_dispatch:
             self._warm_megastep()
 
     # -- the per-tick scheduling loop --------------------------------------
@@ -76,21 +76,14 @@ class DispatchStage:
         ctx = self.ctx
         with ctx.telemetry.stage("dispatch.commit_ready"):
             ready = [a for a in ctx.active if a.copied == len(a)]
-            if self._mode == "megastep":
+            if self._batched:
+                self._dispatch_commit_batch(ready)  # small areas only
+            else:
                 # No dispatch here: the commits ride this tick's megastep.
                 # Ready areas stay in ctx.active until it fires, so emptiness
                 # checks (huge stall detection, done()) see them as live.
                 self._staged_small = [a for a in ready if not a.huge]
                 self._staged_huge = [a for a in ready if a.huge]
-            elif self._mode == "batched":
-                self._dispatch_commit_batch([a for a in ready if not a.huge])
-                self._dispatch_commit_groups([a for a in ready if a.huge])
-            else:
-                for area in ready:
-                    if area.huge:
-                        self._dispatch_commit_groups([area])
-                    else:
-                        self._dispatch_commit(area)
 
     def run_tick(self, tb: TickBudget) -> None:
         """Spend the tick budget: advance open epochs, open new ones."""
@@ -101,14 +94,14 @@ class DispatchStage:
         ctx = self.ctx
         with ctx.telemetry.stage("dispatch.plan"):
             opened, forced, zeros, plan, run_plan = self._plan(tb)
-        if self._mode == "megastep":
+        if not self._batched:
             # The whole tick — staged commits, begins, zeros, forces, copies —
             # crosses the host/device boundary as ONE program.  Phase order
             # inside the program matches the batched generation's cross-
             # program order; the quarantine note below applies identically.
             with ctx.telemetry.stage("dispatch.device"):
                 self._dispatch_megastep(opened, zeros, forced, plan, run_plan)
-        elif self._fused:
+        else:
             # Device order matters: begin before copy (epoch flags gate dirty
             # tracking), force before copy (a forced block's freed source slot
             # may be reallocated as a copy destination next tick), zero-fill
@@ -123,10 +116,8 @@ class DispatchStage:
                 self._dispatch_zero_batch(zeros)
                 self._dispatch_force_batch(forced)
                 self._dispatch_copy_batch(plan)
-                self._dispatch_copy_runs(run_plan)
-        if self._mode != "megastep":
-            # Batched/legacy: the tick's access-heat samples flush as their
-            # own program (megastep folds them into its single dispatch).
+            # The tick's access-heat samples flush as their own program (the
+            # megastep folds them into its single dispatch).
             self._flush_heat()
         # End of tick: every program that reads a forced area's old source
         # slots is dispatched; release them for the next tick's allocations.
@@ -138,16 +129,15 @@ class DispatchStage:
     def _plan(self, tb: TickBudget):
         """The tick's scheduling loop: grant copies to open epochs and open
         new ones off the queue within ``tb``; requeue congested and blocked
-        areas.  Returns ``(opened, forced, zeros, plan, run_plan)`` for the
-        fused dispatches (legacy mode dispatches as it goes)."""
+        areas.  Returns ``(opened, forced, zeros, plan, run_plan)``, which the
+        tick dispatches after planning."""
         ctx = self.ctx
-        fused = self._fused
         skipped: set[int] = set()  # active areas deferred this tick (link dry)
-        opened: list[Area] = []  # epochs opened this tick (fused: batch begin)
-        forced: list[Area] = []  # escalations this tick (fused: batch force)
+        opened: list[Area] = []  # epochs opened this tick (begin phase)
+        forced: list[Area] = []  # escalations this tick (force phase)
         blocked: list[Area] = []  # areas whose destination is out of slots
         congested: list[Area] = []  # queued areas whose link budget ran dry
-        zeros: list[Area] = []  # fresh-alloc epochs (fused: batch zero-fill)
+        zeros: list[Area] = []  # fresh-alloc epochs (zero-fill phase)
         plan: list[tuple[Area, np.ndarray, np.ndarray]] = []  # copy chunks
         run_plan: list[Area] = []  # huge areas copied as whole contiguous runs
         while tb.blocks > 0:
@@ -158,25 +148,18 @@ class DispatchStage:
                     if self.budget.grant_huge(tb, area, need) == 0:
                         skipped.add(id(area))
                         continue
-                    if fused:
-                        run_plan.append(area)
-                    else:
-                        self._dispatch_copy_runs([area])
+                    run_plan.append(area)
                     tb.blocks -= need
                     area.copied = len(area)
                     continue
-                per_area = len(area) - area.copied if fused else ctx.cfg.chunk_blocks
-                want = min(per_area, len(area) - area.copied, tb.blocks)
+                want = min(len(area) - area.copied, tb.blocks)
                 n = self.budget.grant_copy(tb, area, want)
                 if n == 0:
                     skipped.add(id(area))
                     continue
                 ids = area.block_ids[area.copied : area.copied + n]
                 slots = area.dst_slots[area.copied : area.copied + n]
-                if fused:
-                    plan.append((area, ids, slots))
-                else:
-                    self._dispatch_copy(area, ids, slots)
+                plan.append((area, ids, slots))
                 area.copied += n
                 tb.blocks -= n
                 continue
@@ -236,7 +219,7 @@ class DispatchStage:
         area: Area,
         opened: list[Area],
         forced: list[Area],
-        zeros: list[Area] | None = None,
+        zeros: list[Area],
     ) -> bool:
         ctx = self.ctx
         cfg = ctx.cfg
@@ -290,13 +273,9 @@ class DispatchStage:
         area.copied = 0
         if area.fresh_alloc:
             # Fresh-destination policies (move_pages()/autonuma analogues)
-            # pay the kernel's zero-fill pass before their copy/force lands.
-            # Fused: one batched zero program per tick, sequenced before the
-            # force/copy batches; legacy: immediate, in open order.
-            if self._fused:
-                zeros.append(area)
-            else:
-                self._dispatch_zero_fill(area)
+            # pay the kernel's zero-fill pass before their copy/force lands:
+            # one zero phase per tick, sequenced before the force/copy phases.
+            zeros.append(area)
         if area.attempts >= cfg.max_attempts_before_force:
             # Write-through escalation: fused copy+flip, cannot be dirtied.
             # Deliberately exempt from the per-link budgets (escalation must
@@ -313,26 +292,13 @@ class DispatchStage:
                 attempts=area.attempts,
                 forced=True,
             )
-            if self._fused:
-                forced.append(area)  # device dispatch batched at end of tick
-            else:
-                ctx.state = migrator.force_migrate(
-                    ctx.state,
-                    jax.numpy.asarray(area.block_ids),
-                    jax.numpy.asarray(area.dst_slots),
-                    int(area.dst_region),
-                )
-                ctx.count("dispatches", 1, program="force_migrate")
+            forced.append(area)  # device dispatch at end of tick
             self._finalize_success(area)
             return True
         ctx.telemetry.request_phase(
             area.request_id, "EPOCH_OPEN", n=len(area), attempts=area.attempts
         )
-        if self._fused:
-            opened.append(area)  # begin batched at end of tick, before copies
-        else:
-            ctx.state = migrator.begin_area(ctx.state, jax.numpy.asarray(area.block_ids))
-            ctx.count("dispatches", 1, program="begin_area")
+        opened.append(area)  # begin dispatched at end of tick, before copies
         ctx.active.append(area)
         return True
 
@@ -362,32 +328,25 @@ class DispatchStage:
         ctx.telemetry.request_phase(
             area.request_id, "EPOCH_OPEN", n=len(area), attempts=area.attempts, huge=True
         )
-        if self._fused:
-            opened.append(area)  # members share the tick's begin batch
-        else:
-            ctx.state = migrator.begin_area(ctx.state, jax.numpy.asarray(area.block_ids))
-            ctx.count("dispatches", 1, program="begin_area")
+        opened.append(area)  # members share the tick's begin phase
         ctx.active.append(area)
         return True
 
     def _finalize_success(self, area: Area) -> None:
         # Force path: all blocks flipped on device; mirror and free sources.
         # Never a relay hop (escalation forces direct to the final
-        # destination), so the credit is always terminal.  In fused mode the
-        # force program itself runs at end of tick, so the freed source
-        # slots are quarantined (self._freed) instead of released: handing
-        # one out to a later open this tick would let that area's batched
-        # zero/force/copy write the slot before this force has read it.
+        # destination), so the credit is always terminal.  The force itself
+        # runs on device at end of tick, so the freed source slots are
+        # quarantined (self._freed) instead of released: handing one out to
+        # a later open this tick would let that area's zero/force/copy write
+        # the slot before this force has read it.
         ctx = self.ctx
-        if self._fused:
-            ids = area.block_ids
-            self._freed.append(ctx.table[ids].copy())
-            ctx.table[ids, REGION] = area.dst_region
-            ctx.table[ids, SLOT] = area.dst_slots
-            ctx.migrating[ids] = False
-            ctx.note_migrated(ids)
-        else:
-            ctx.remap_host(area.block_ids, area.dst_region, area.dst_slots)
+        ids = area.block_ids
+        self._freed.append(ctx.table[ids].copy())
+        ctx.table[ids, REGION] = area.dst_region
+        ctx.table[ids, SLOT] = area.dst_slots
+        ctx.migrating[ids] = False
+        ctx.note_migrated(ids)
         self.accounting.credit(area, forced=len(area))
 
     # -- access-heat plane (closed-loop tiering) ----------------------------
@@ -405,7 +364,7 @@ class DispatchStage:
         return ids, w
 
     def _flush_heat(self) -> None:
-        """Batched/legacy: fold the tick's heat samples as their own program."""
+        """Batched: fold the tick's heat samples as their own program."""
         ctx = self.ctx
         ids, w = self._pop_heat()
         n = len(ids)
@@ -420,7 +379,6 @@ class DispatchStage:
             jax.numpy.asarray(ids),
             jax.numpy.asarray(hw),
             ctx.cfg.tier_heat_decay,
-            impl=ctx.cfg.copy_impl,
         )
         ctx.count("dispatches", 1, program="heat_update")
 
@@ -521,7 +479,6 @@ class DispatchStage:
             heat_w,
             layout=layout,
             group=ctx.pool_cfg.huge_factor,
-            impl=ctx.cfg.copy_impl,
             heat_decay=ctx.cfg.tier_heat_decay,
         )
         if heat_w is not None:
@@ -725,20 +682,12 @@ class DispatchStage:
         if huge:
             ctx.pending.append(CommitBatch(huge, np.arange(k + 1), verdict_groups))
 
-    # -- batched dispatch (fused path) -------------------------------------
+    # -- batched dispatch (ppermute backend) --------------------------------
 
     def _pad(self, *arrays: np.ndarray) -> tuple[np.ndarray, ...]:
         return pad_to_bucket(
             bucket_size(len(arrays[0]), self.ctx.cfg.bucket_growth), *arrays
         )
-
-    def _dispatch_zero_fill(self, area: Area) -> None:
-        ctx = self.ctx
-        (slots,) = self._pad(area.dst_slots)
-        ctx.state = migrator.zero_fill(
-            ctx.state, jax.numpy.asarray(slots), int(area.dst_region)
-        )
-        ctx.count("dispatches", 1, program="zero_fill")
 
     def _dispatch_zero_batch(self, zeros: list[Area]) -> None:
         """One zero-fill program per destination region covers every
@@ -787,36 +736,10 @@ class DispatchStage:
         if not plan:
             return
         ctx = self.ctx
-        n_blocks = sum(len(ids) for _, ids, _ in plan)
-        ctx.count("bytes_copied", n_blocks * ctx.pool_cfg.block_bytes)
-        if ctx.cfg.backend == "ppermute":
-            self._dispatch_copy_batch_ppermute(plan)
-            return
-        s_per = ctx.pool_cfg.slots_per_region
-        ids = np.concatenate([ids for _, ids, _ in plan])
-        dst_regions = np.concatenate(
-            [np.full(len(c), a.dst_region, np.int32) for a, c, _ in plan]
-        )
-        dst_slots = np.concatenate([slots for _, _, slots in plan])
-        # Flat slot ids from the exact host mirror: table entries of in-flight
-        # blocks cannot change until their commit, which this driver issues.
-        src_flat = ctx.table[ids, REGION] * s_per + ctx.table[ids, SLOT]
-        dst_flat = dst_regions * s_per + dst_slots
-        src_flat, dst_flat = self._pad(src_flat, dst_flat)
-        ctx.state = migrator.fused_copy(
-            ctx.state,
-            jax.numpy.asarray(src_flat),
-            jax.numpy.asarray(dst_flat),
-            impl=ctx.cfg.copy_impl,
-        )
-        ctx.count("dispatches", 1, program="fused_copy")
-
-    def _dispatch_copy_batch_ppermute(
-        self, plan: list[tuple[Area, np.ndarray, np.ndarray]]
-    ) -> None:
-        ctx = self.ctx
         if ctx.mesh is None or ctx.cfg.axis_name is None:
             raise ValueError("ppermute backend requires mesh and axis_name")
+        n_blocks = sum(len(ids) for _, ids, _ in plan)
+        ctx.count("bytes_copied", n_blocks * ctx.pool_cfg.block_bytes)
         # One point-to-point program per (src, dst) region pair this tick;
         # areas are single-source so chunks group cleanly.
         pairs: dict[tuple[int, int], list[tuple[np.ndarray, np.ndarray]]] = {}
@@ -836,7 +759,6 @@ class DispatchStage:
                 int(dst),
                 ctx.cfg.axis_name,
                 ctx.mesh,
-                impl=ctx.cfg.copy_impl,
             )
             ctx.count("dispatches", 1, program="fused_copy_ppermute")
 
@@ -861,101 +783,6 @@ class DispatchStage:
         for a in ready:
             ctx.active.remove(a)
         ctx.pending.append(CommitBatch(ready, offsets, verdict))
-
-    # -- huge-tier dispatch (contiguous runs + grouped commits) ------------
-
-    def _dispatch_copy_runs(self, run_plan: list[Area]) -> None:
-        """One device program copies every huge block scheduled this tick —
-        each as a single contiguous-run move, not G per-slot gathers."""
-        if not run_plan:
-            return
-        ctx = self.ctx
-        G = ctx.pool_cfg.huge_factor
-        s_per = ctx.pool_cfg.slots_per_region
-        nbytes = len(run_plan) * G * ctx.pool_cfg.block_bytes
-        ctx.count("bytes_copied", nbytes)
-        ctx.count("bytes_copied_huge", nbytes)
-        firsts = np.asarray([a.block_ids[0] for a in run_plan])
-        src = (ctx.table[firsts, REGION] * s_per + ctx.table[firsts, SLOT]).astype(np.int32)
-        dst = np.asarray(
-            [a.dst_region * s_per + a.dst_slots[0] for a in run_plan], np.int32
-        )
-        src, dst = self._pad(src, dst)
-        ctx.state = migrator.fused_copy_runs(
-            ctx.state,
-            jax.numpy.asarray(src),
-            jax.numpy.asarray(dst),
-            run=G,
-            impl=ctx.cfg.copy_impl,
-        )
-        ctx.count("dispatches", 1, program="fused_copy_runs")
-
-    def _dispatch_commit_groups(self, ready: list[Area]) -> None:
-        """All-or-nothing commit of every copy-complete huge area (one program,
-        one verdict lane per huge block)."""
-        if not ready:
-            return
-        ctx = self.ctx
-        G = ctx.pool_cfg.huge_factor
-        k = len(ready)
-        bucket = bucket_size(k, ctx.cfg.bucket_growth)
-        members = np.concatenate([a.block_ids for a in ready]).reshape(k, G)
-        regions = np.asarray([a.dst_region for a in ready], np.int32)
-        starts = np.asarray([a.dst_slots[0] for a in ready], np.int32)
-        # pad by replicating lane-0's whole GROUP (idempotent duplicate remap)
-        members = np.concatenate([members, np.repeat(members[:1], bucket - k, axis=0)])
-        regions, starts = pad_to_bucket(bucket, regions, starts)
-        ctx.state, verdict = migrator.commit_groups(
-            ctx.state,
-            jax.numpy.asarray(members.reshape(-1)),
-            jax.numpy.asarray(regions),
-            jax.numpy.asarray(starts),
-            group=G,
-        )
-        ctx.count("dispatches", 1, program="commit_groups")
-        for a in ready:
-            ctx.active.remove(a)
-        ctx.pending.append(
-            CommitBatch(ready, np.arange(k + 1), verdict)  # 1 lane per area
-        )
-
-    # -- legacy per-area dispatch (fused_dispatch=False baseline) ----------
-
-    def _dispatch_copy(self, area: Area, ids: np.ndarray, slots: np.ndarray) -> None:
-        ctx = self.ctx
-        if ctx.cfg.backend == "ppermute":
-            if ctx.mesh is None or ctx.cfg.axis_name is None:
-                raise ValueError("ppermute backend requires mesh and axis_name")
-            ctx.state = migrator.copy_chunk_ppermute(
-                ctx.state,
-                jax.numpy.asarray(ids),
-                jax.numpy.asarray(slots),
-                int(area.src_region),
-                int(area.dst_region),
-                ctx.cfg.axis_name,
-                ctx.mesh,
-            )
-        else:
-            ctx.state = migrator.copy_chunk(
-                ctx.state,
-                jax.numpy.asarray(ids),
-                jax.numpy.asarray(slots),
-                int(area.dst_region),
-            )
-        ctx.count("dispatches", 1, program="copy_chunk")
-        ctx.count("bytes_copied", len(ids) * ctx.pool_cfg.block_bytes)
-
-    def _dispatch_commit(self, area: Area) -> None:
-        ctx = self.ctx
-        ctx.state, verdict = migrator.commit_area(
-            ctx.state,
-            jax.numpy.asarray(area.block_ids),
-            jax.numpy.asarray(area.dst_slots),
-            int(area.dst_region),
-        )
-        ctx.count("dispatches", 1, program="commit_area")
-        ctx.active.remove(area)
-        ctx.pending.append(CommitBatch([area], np.asarray([0, len(area)]), verdict))
 
     # -- tier transitions (two-tier pool) ----------------------------------
 

@@ -1,8 +1,8 @@
 """Verdict stage: harvest commit verdicts, split/demote/credit outcomes.
 
 This stage is the pipeline's ONLY device→host synchronization point.
-Commit dispatches — the batched ``commit_areas``/``commit_groups``
-programs, or the commit phase of the megastep (DESIGN.md §12) — return
+Commit dispatches — the batched ``commit_areas`` program (ppermute
+backend), or the commit phase of the megastep (DESIGN.md §12) — return
 packed dirty vectors that stay on device, wrapped in ``CommitBatch``
 futures on ``ctx.pending``.  Harvest materializes them opportunistically
 (``is_ready()`` first, so a tick never stalls on an unfinished verdict)

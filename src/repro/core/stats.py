@@ -24,11 +24,11 @@ class MigrationStats:
     splits: int = 0
     # Device programs issued.  One fused megastep counts as ONE dispatch
     # (the whole point of the single-dispatch tick), not one per fused
-    # phase; the batched generation counts each of its <=3 programs.
+    # phase; the batched generation (ppermute backend) counts each program.
     dispatches: int = 0
     # Host-to-device operand transfers the megastep dispatches made: one
     # packed index vector per megastep, plus the heat weights on a tick
-    # with a heat phase (zero under the batched and legacy generations).
+    # with a heat phase (zero under the batched generation).
     h2d_transfers: int = 0
     ticks: int = 0
     jit_cache_misses: int = 0  # migration-program compiles since driver init
@@ -59,8 +59,8 @@ class MigrationStats:
         """Device programs issued per migration tick (control-path cost).
 
         ~1.0 under megastep dispatch (idle ticks dispatch nothing, so a
-        drain's warm steady state sits at or just under 1.0), <= 3 under
-        batched dispatch, O(areas + chunks) on the legacy path.
+        drain's warm steady state sits at or just under 1.0); one program
+        per tick phase under batched dispatch (the ppermute backend).
         """
         return self.dispatches / self.ticks if self.ticks else 0.0
 

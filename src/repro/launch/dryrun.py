@@ -242,24 +242,26 @@ def build_leap_cell(mesh, ctx, backend: str):
         dirty=rep,
         in_flight=rep,
     )
-    ids = jax.ShapeDtypeStruct((16,), jnp.int32)
-    slots_sd = jax.ShapeDtypeStruct((16,), jnp.int32)
+    # source and destination slots: per region for ppermute, flat
+    # (region * slots + slot) for the xla copy
+    src_sd = jax.ShapeDtypeStruct((16,), jnp.int32)
+    dst_sd = jax.ShapeDtypeStruct((16,), jnp.int32)
     if backend == "ppermute":
         fn = jax.jit(
-            lambda s, i, d: migrator.copy_chunk_ppermute(
-                s, i, d, 0, 1, "data", mesh
+            lambda s, a, b: migrator.fused_copy_ppermute(
+                s, a, b, 0, 1, "data", mesh
             ),
             in_shardings=(state_sh, rep, rep),
             donate_argnums=(0,),
         )
     else:
         fn = jax.jit(
-            lambda s, i, d: migrator.copy_chunk(s, i, d, 1),
+            lambda s, a, b: migrator.fused_copy(s, a, b),
             in_shardings=(state_sh, rep, rep),
             donate_argnums=(0,),
         )
     area_bytes = 16 * int(np.prod(payload)) * 2
-    return fn, (state_struct, ids, slots_sd), float(area_bytes), {}
+    return fn, (state_struct, src_sd, dst_sd), float(area_bytes), {}
 
 
 # ---------------------------------------------------------------------------

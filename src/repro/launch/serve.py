@@ -47,7 +47,7 @@ def paged_config(smoke: bool, regions: int, max_tokens: int) -> PagedConfig:
     """KV pool for sequences of up to ``max_tokens`` (prompt + decode)."""
     blk = 4 if smoke else 16
     leap = (
-        LeapConfig(initial_area_blocks=4, chunk_blocks=2, budget_blocks_per_tick=4)
+        LeapConfig(initial_area_blocks=4, budget_blocks_per_tick=4)
         if smoke
         else LeapConfig()
     )

@@ -27,7 +27,7 @@ def make(n_blocks=16, slots=24, n_regions=2, huge_factor=1, **leap_kw):
     state = init_state(cfg, n_blocks, np.zeros(n_blocks, np.int32))
     data = np.arange(n_blocks * 4, dtype=np.float32).reshape(n_blocks, 4)
     state = leap_write(state, jnp.arange(n_blocks), jnp.asarray(data))
-    kw = dict(initial_area_blocks=4, chunk_blocks=2, budget_blocks_per_tick=4)
+    kw = dict(initial_area_blocks=4, budget_blocks_per_tick=4)
     kw.update(leap_kw)
     drv = MigrationDriver(state, cfg, LeapConfig(**kw))
     return cfg, drv, LeapSession(drv), data

@@ -22,7 +22,7 @@ from repro.core import (
     init_state,
     leap_write,
 )
-from repro.core.migrator import begin_area
+from repro.core.migrator import begin_areas
 
 
 def make(n_blocks=8, n_regions=2, slots=16):
@@ -50,7 +50,7 @@ def test_sync_reshard_moves_and_preserves():
 
 def test_sync_reshard_skips_busy_blocks():
     cfg, drv, data = make()
-    drv.state = begin_area(drv.state, jnp.asarray([2, 5]))  # blocks 2,5 are "busy"
+    drv.state = begin_areas(drv.state, jnp.asarray([2, 5]))  # blocks 2,5 are "busy"
     rs = SyncResharder(cfg)
     res = rs.migrate_driver(drv, np.arange(8), dst_region=1)
     assert sorted(res.failed.tolist()) == [2, 5]  # no retry: unreliable

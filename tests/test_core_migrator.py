@@ -17,8 +17,8 @@ from repro.core import (
     leap_write,
 )
 from repro.core.adaptive import Area, split_area
-from repro.core.migrator import begin_area, commit_area, copy_chunk, force_migrate
-from repro.core.state import REGION
+from repro.core.migrator import begin_areas, commit_areas, force_areas, fused_copy
+from repro.core.state import REGION, SLOT
 
 
 def make(n_regions=2, slots=32, n_blocks=16, block_shape=(4,), seed=0):
@@ -31,6 +31,18 @@ def make(n_regions=2, slots=32, n_blocks=16, block_shape=(4,), seed=0):
     return cfg, state, data
 
 
+def copy_to(cfg, state, ids, slots, dst_region):
+    """``fused_copy`` of blocks ``ids`` into ``(dst_region, slots)``."""
+    loc = np.asarray(state.table)[np.asarray(ids)]
+    src = loc[:, REGION] * cfg.slots_per_region + loc[:, SLOT]
+    dst = dst_region * cfg.slots_per_region + np.asarray(slots)
+    return fused_copy(state, jnp.asarray(src), jnp.asarray(dst))
+
+
+def commit_to(state, ids, slots, dst_region):
+    return commit_areas(state, ids, jnp.full(len(ids), dst_region), slots)
+
+
 # ---------------------------------------------------------------------------
 # Low-level program semantics
 # ---------------------------------------------------------------------------
@@ -40,11 +52,11 @@ def test_copy_then_commit_clean_flips_table():
     cfg, state, data = make()
     ids = jnp.asarray([0, 1, 2])
     slots = jnp.asarray([0, 1, 2])
-    state = begin_area(state, ids)
-    state = copy_chunk(state, ids, slots, dst_region=1)
+    state = begin_areas(state, ids)
+    state = copy_to(cfg, state, ids, slots, dst_region=1)
     # table still points at region 0 during the copy (readers see source)
     assert np.asarray(state.table)[:3, REGION].tolist() == [0, 0, 0]
-    state, verdict = commit_area(state, ids, slots, dst_region=1)
+    state, verdict = commit_to(state, ids, slots, dst_region=1)
     assert not np.asarray(verdict).any()
     assert np.asarray(state.table)[:3, REGION].tolist() == [1, 1, 1]
     np.testing.assert_array_equal(np.asarray(leap_read(state, ids)), data[:3])
@@ -54,12 +66,12 @@ def test_dirty_write_invalidates_commit():
     cfg, state, data = make()
     ids = jnp.asarray([0, 1])
     slots = jnp.asarray([0, 1])
-    state = begin_area(state, ids)
-    state = copy_chunk(state, ids, slots, dst_region=1)
+    state = begin_areas(state, ids)
+    state = copy_to(cfg, state, ids, slots, dst_region=1)
     # concurrent write to block 1 *after* its copy
     new = np.full((1, 4), 42.0, np.float32)
     state = leap_write(state, jnp.asarray([1]), jnp.asarray(new))
-    state, verdict = commit_area(state, ids, slots, dst_region=1)
+    state, verdict = commit_to(state, ids, slots, dst_region=1)
     v = np.asarray(verdict)
     assert v.tolist() == [False, True]
     table = np.asarray(state.table)
@@ -73,23 +85,23 @@ def test_write_before_copy_is_carried():
     cfg, state, data = make()
     ids = jnp.asarray([3])
     slots = jnp.asarray([5])
-    state = begin_area(state, ids)
+    state = begin_areas(state, ids)
     new = np.full((1, 4), 7.0, np.float32)
     state = leap_write(state, ids, jnp.asarray(new))  # write DURING epoch, before copy
-    state = copy_chunk(state, ids, slots, dst_region=1)
-    state, verdict = commit_area(state, ids, slots, dst_region=1)
+    state = copy_to(cfg, state, ids, slots, dst_region=1)
+    state, verdict = commit_to(state, ids, slots, dst_region=1)
     # footnote-1 semantics: conservatively dirty (unnecessary retry), but the
     # write is never lost.
     assert np.asarray(verdict)[0]
     np.testing.assert_array_equal(np.asarray(leap_read(state, ids)), new)
 
 
-def test_force_migrate_unconditional():
+def test_force_areas_unconditional():
     cfg, state, data = make()
     ids = jnp.asarray([0])
-    state = begin_area(state, ids)
+    state = begin_areas(state, ids)
     state = leap_write(state, ids, jnp.full((1, 4), 9.0))
-    state = force_migrate(state, ids, jnp.asarray([4]), dst_region=1)
+    state = force_areas(state, ids, jnp.asarray([1]), jnp.asarray([4]))
     t = np.asarray(state.table)
     assert t[0].tolist() == [1, 4]
     assert not np.asarray(state.dirty)[0] and not np.asarray(state.in_flight)[0]
@@ -127,7 +139,7 @@ def test_split_respects_min_area():
 
 def test_driver_migrates_all_without_writes():
     cfg, state, data = make(n_blocks=16)
-    drv = MigrationDriver(state, cfg, LeapConfig(initial_area_blocks=8, chunk_blocks=4))
+    drv = MigrationDriver(state, cfg, LeapConfig(initial_area_blocks=8))
     n = drv.request(np.arange(16), dst_region=1)
     assert n == 16
     assert drv.drain()
@@ -153,7 +165,7 @@ def test_driver_migration_under_interleaved_writes_preserves_data():
     drv = MigrationDriver(
         state,
         cfg,
-        LeapConfig(initial_area_blocks=16, chunk_blocks=4, budget_blocks_per_tick=8),
+        LeapConfig(initial_area_blocks=16, budget_blocks_per_tick=8),
     )
     drv.request(np.arange(32), dst_region=1)
     rng = np.random.default_rng(1)
@@ -182,7 +194,6 @@ def test_driver_force_escalation_terminates_adversarial_writer():
         cfg,
         LeapConfig(
             initial_area_blocks=4,
-            chunk_blocks=1,
             budget_blocks_per_tick=2,
             max_attempts_before_force=2,
         ),

@@ -75,9 +75,9 @@ def test_write_sets_dirty_only_when_in_flight():
     state = leap_write(state, ids, vals)
     assert not bool(np.asarray(state.dirty).any())
     # open an epoch on block 2 only
-    from repro.core.migrator import begin_area
+    from repro.core.migrator import begin_areas
 
-    state = begin_area(state, jnp.asarray([2]))
+    state = begin_areas(state, jnp.asarray([2]))
     state = leap_write(state, ids, vals)
     dirty = np.asarray(state.dirty)
     assert not dirty[1] and dirty[2]
@@ -85,9 +85,9 @@ def test_write_sets_dirty_only_when_in_flight():
 
 def test_write_rows_sets_dirty_when_in_flight():
     cfg, state = make()
-    from repro.core.migrator import begin_area
+    from repro.core.migrator import begin_areas
 
-    state = begin_area(state, jnp.asarray([5]))
+    state = begin_areas(state, jnp.asarray([5]))
     state = leap_write_rows(
         state, jnp.asarray([5]), jnp.asarray([1]), jnp.ones((1, 8), jnp.float32)
     )

@@ -2,10 +2,9 @@
 
 Covers the acceptance criteria of the dispatch-batching redesign:
   * <= 3 device dispatches per tick on a drain workload,
-  * jit cache stability: a full adaptive-splitting run compiles at most the
-    bucket-count number of copy/commit program variants,
-  * batched commits preserve dirty-rejection semantics and the host mirror,
-  * the legacy per-chunk path and the batched path produce identical results.
+  * jit cache stability: a full adaptive-splitting run compiles a bounded
+    number of megastep variants,
+  * batched commits preserve dirty-rejection semantics and the host mirror.
 """
 
 import jax.numpy as jnp
@@ -122,51 +121,8 @@ def test_force_areas_mixed_destinations():
 
 
 # ---------------------------------------------------------------------------
-# Driver: dispatch counts, cache stability, legacy equivalence
+# Driver: dispatch counts, cache stability
 # ---------------------------------------------------------------------------
-
-
-def _run_interleaved(fused: bool, seed=3, n_blocks=32):
-    cfg, state, data = make(n_blocks=n_blocks, slots=n_blocks * 2, seed=seed)
-    drv = MigrationDriver(
-        state,
-        cfg,
-        LeapConfig(
-            initial_area_blocks=8,
-            chunk_blocks=4,
-            budget_blocks_per_tick=8,
-            max_attempts_before_force=3,
-            fused_dispatch=fused,
-        ),
-    )
-    drv.request(np.arange(n_blocks), 1)
-    rng = np.random.default_rng(seed)
-    expected = data.copy()
-    steps = 0
-    while not drv.done and steps < 1000:
-        drv.tick()
-        ids = rng.choice(n_blocks, size=2, replace=False)
-        vals = rng.normal(size=(2, 4)).astype(np.float32)
-        drv.write(jnp.asarray(ids), jnp.asarray(vals))
-        expected[ids] = vals
-        steps += 1
-    assert drv.drain()
-    return drv, expected
-
-
-def test_batched_matches_legacy_under_writes():
-    drv_f, exp_f = _run_interleaved(fused=True)
-    drv_l, exp_l = _run_interleaved(fused=False)
-    for drv, expected in ((drv_f, exp_f), (drv_l, exp_l)):
-        assert (drv.host_placement() == 1).all()
-        assert drv.verify_mirror()
-        np.testing.assert_array_equal(
-            np.asarray(drv.read(np.arange(32))), expected
-        )
-    # same write schedule => identical logical outcome on both paths
-    np.testing.assert_array_equal(exp_f, exp_l)
-    # and the batched path pays far fewer dispatches for the same work
-    assert drv_f.stats.dispatches < drv_l.stats.dispatches
 
 
 def test_dispatches_per_tick_at_most_three():
@@ -175,7 +131,7 @@ def test_dispatches_per_tick_at_most_three():
     drv = MigrationDriver(
         state,
         cfg,
-        LeapConfig(initial_area_blocks=64, chunk_blocks=16, budget_blocks_per_tick=64),
+        LeapConfig(initial_area_blocks=64, budget_blocks_per_tick=64),
     )
     drv.request(np.arange(128), 1)
     assert drv.drain()
@@ -186,15 +142,17 @@ def test_dispatches_per_tick_at_most_three():
 
 def test_full_adaptive_run_compiles_at_most_bucket_count_variants():
     """Recompilation stability: however the splitter fragments the work, the
-    copy/commit programs compile at most the bucket-set number of shapes.
+    megastep compiles a bounded number of variants.
 
-    With budget 64 and growth 4 the bucket set is {1, 4, 16, 64}: <= 4 shapes
-    each for fused_copy and commit_areas, <= 8 combined.  Measured as the
-    process-wide jit-cache delta across two full adaptive-splitting drains
-    (distinct write schedules => distinct raw batch lengths)."""
-    before = migrator.program_cache_sizes()
+    Every per-block operand pads to the budget-floored bucket (64 here), so
+    a variant is a set of phases present, not a batch length.  Measured as
+    the process-wide jit-cache delta of the megastep across two full
+    adaptive-splitting drains (distinct write schedules => distinct raw
+    batch lengths).  The block shape is this test's own, so the drains
+    compile their variants here, whatever ran before in the process."""
+    before = migrator.program_cache_sizes()["megastep"]
     for seed in (11, 12):
-        cfg, state, data = make(n_blocks=64, slots=128, seed=seed)
+        cfg, state, data = make(n_blocks=64, slots=128, block_shape=(7,), seed=seed)
         drv = MigrationDriver(
             state,
             cfg,
@@ -210,17 +168,15 @@ def test_full_adaptive_run_compiles_at_most_bucket_count_variants():
         while not drv.done and steps < 2000:
             drv.tick()
             ids = rng.choice(64, size=4, replace=False)
-            drv.write(jnp.asarray(ids), jnp.asarray(rng.normal(size=(4, 4)).astype(np.float32)))
+            drv.write(jnp.asarray(ids), jnp.asarray(rng.normal(size=(4, 7)).astype(np.float32)))
             steps += 1
         assert drv.drain()
         assert drv.verify_mirror()
         assert drv.stats.dirty_rejections > 0, "workload must exercise splitting"
-    after = migrator.program_cache_sizes()
-    copy_commit_delta = (
-        after["fused_copy"] - before["fused_copy"]
-        + after["commit_areas"] - before["commit_areas"]
-    )
-    assert copy_commit_delta <= 8, (before, after)
+    growth = migrator.program_cache_sizes()["megastep"] - before
+    # one variant per phase set a drain meets (begin+copy, commit+begin+copy,
+    # the commit-only tail), however fragmented the batch lengths
+    assert 0 < growth <= 3, growth
     # driver-level stat agrees: bounded compiles despite the length storm
     assert drv.stats.jit_cache_misses <= 16
 

@@ -198,8 +198,7 @@ def test_slo_priority_overtakes_background_drain():
     state = init_state(pool_cfg, 32, np.zeros(32, np.int32))
     driver = MigrationDriver(
         state, pool_cfg,
-        LeapConfig(initial_area_blocks=2, chunk_blocks=2,
-                   budget_blocks_per_tick=2),
+        LeapConfig(initial_area_blocks=2, budget_blocks_per_tick=2),
     )
     session = driver.default_session()
     sched = SloScheduler(SloConfig(window=8))

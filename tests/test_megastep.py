@@ -3,15 +3,15 @@
 Covers the acceptance criteria of the device-resident tick loop:
   * the single-dispatch invariant — a warm fused-megastep drain issues at
     most one device program per tick (``dispatches_per_tick`` ~ 1.0),
-  * verdict-carry correctness — megastep, batched, and legacy dispatch
-    produce the same logical outcome on identical seeds (and megastep vs
-    batched the bit-identical physical pool), with per-request accounting
-    closure on every path,
+  * verdict-carry correctness — under interleaved writes the megastep
+    reads back the expected payload, with per-request accounting closure
+    (the batched generation is checked against it on the ppermute backend
+    in test_multidevice.py),
   * jit-cache stability — a retry storm's fragmented batch lengths all
     round up to the shared floored bucket, so megastep compiles a bounded
     number of variants after warmup,
-  * the config tri-state (``LeapConfig.fused_dispatch`` / ``dispatch_mode``)
-    including the ppermute fallback.
+  * the dispatch generation follows the copy backend (megastep on xla,
+    batched on ppermute).
 """
 
 import jax
@@ -39,18 +39,16 @@ def make(n_regions=2, slots=64, n_blocks=32, block_shape=(4,), seed=0):
     return cfg, state, data
 
 
-def _run_interleaved(mode, seed=3, n_blocks=32):
-    """Identical request + write schedule under a given dispatch mode."""
+def _run_interleaved(seed=3, n_blocks=32):
+    """A request + interleaved write schedule with escalation."""
     cfg, state, data = make(n_blocks=n_blocks, slots=n_blocks * 2, seed=seed)
     drv = MigrationDriver(
         state,
         cfg,
         LeapConfig(
             initial_area_blocks=8,
-            chunk_blocks=4,
             budget_blocks_per_tick=8,
             max_attempts_before_force=3,
-            fused_dispatch=mode,
         ),
     )
     session = drv.default_session()
@@ -70,28 +68,26 @@ def _run_interleaved(mode, seed=3, n_blocks=32):
 
 
 # ---------------------------------------------------------------------------
-# Config tri-state
+# One dispatch generation per copy backend
 # ---------------------------------------------------------------------------
 
 
-def test_dispatch_mode_tri_state():
-    assert LeapConfig().dispatch_mode == "megastep"
-    assert LeapConfig(fused_dispatch=True).dispatch_mode == "megastep"
-    assert LeapConfig(fused_dispatch="megastep").dispatch_mode == "megastep"
-    assert LeapConfig(fused_dispatch="batched").dispatch_mode == "batched"
-    assert LeapConfig(fused_dispatch=False).dispatch_mode == "legacy"
-    assert LeapConfig(fused_dispatch="legacy").dispatch_mode == "legacy"
-    with pytest.raises(ValueError):
-        LeapConfig(fused_dispatch="warp")
+def test_dispatch_generation_follows_backend():
+    """The copy backend alone picks the generation: no option selects it."""
+    cfg, state, _ = make(n_blocks=8, slots=16)
+    drv = MigrationDriver(state, cfg, LeapConfig())
+    assert not drv._dispatch._batched
+    drv.default_session().leap(np.arange(8), 1)
+    assert drv.drain()
+    assert drv.stats.dispatches == drv.stats.h2d_transfers > 0  # megastep only
 
 
 def test_megastep_falls_back_to_batched_on_ppermute():
     """shard_map programs have static (src, dst) endpoints: they cannot fuse
-    into one variant-stable program, so megastep demotes to batched there."""
-    cfg = LeapConfig(fused_dispatch=True, backend="ppermute")
-    assert cfg.dispatch_mode == "batched"
-    # an explicit legacy request survives the backend
-    assert LeapConfig(fused_dispatch=False, backend="ppermute").dispatch_mode == "legacy"
+    into one variant-stable program, so the ppermute backend runs batched."""
+    cfg, state, _ = make(n_blocks=8, slots=16)
+    drv = MigrationDriver(state, cfg, LeapConfig(backend="ppermute", axis_name="data"))
+    assert drv._dispatch._batched
 
 
 # ---------------------------------------------------------------------------
@@ -107,7 +103,7 @@ def test_single_dispatch_per_tick_on_drain():
     drv = MigrationDriver(
         state,
         cfg,
-        LeapConfig(initial_area_blocks=64, chunk_blocks=16, budget_blocks_per_tick=64),
+        LeapConfig(initial_area_blocks=64, budget_blocks_per_tick=64),
     )
     drv.default_session().leap(np.arange(128), 1)
     assert drv.drain()
@@ -131,35 +127,24 @@ def test_idle_ticks_dispatch_nothing():
 
 
 def test_megastep_matches_batched_and_legacy_under_writes():
-    drv_m, exp_m = _run_interleaved("megastep")
-    drv_b, exp_b = _run_interleaved("batched")
-    drv_l, exp_l = _run_interleaved("legacy")
-    for drv, expected in ((drv_m, exp_m), (drv_b, exp_b), (drv_l, exp_l)):
-        assert (drv.host_placement() == 1).all()
-        assert drv.verify_mirror()
-        np.testing.assert_array_equal(np.asarray(drv.read(np.arange(32))), expected)
-    # same write schedule => identical logical outcome on all three paths
-    np.testing.assert_array_equal(exp_m, exp_b)
-    np.testing.assert_array_equal(exp_m, exp_l)
-    # megastep and batched make byte-identical scheduling decisions, so the
-    # physical pools (slot placement included) match bit for bit
-    np.testing.assert_array_equal(np.asarray(drv_m.state.pool), np.asarray(drv_b.state.pool))
-    np.testing.assert_array_equal(np.asarray(drv_m.state.table), np.asarray(drv_b.state.table))
-    # and the megastep pays no more dispatches than either prior generation
-    assert drv_m.stats.dispatches <= drv_b.stats.dispatches
-    assert drv_m.stats.dispatches < drv_l.stats.dispatches
+    drv, expected = _run_interleaved()
+    assert (drv.host_placement() == 1).all()
+    assert drv.verify_mirror()
+    np.testing.assert_array_equal(np.asarray(drv.read(np.arange(32))), expected)
+    # at most one device program per tick, whatever the retries and forces
+    assert drv.stats.dirty_rejections > 0
+    assert 0 < drv.stats.dispatches <= drv.stats.ticks
 
 
 def test_accounting_closure_every_mode():
     """committed + forced + cancelled == requested at termination, and the
-    retry traffic the stats report covers the re-copied bytes, on all paths."""
-    for mode in ("megastep", "batched", "legacy"):
-        drv, _ = _run_interleaved(mode, seed=7)
-        for req in drv.requests.values():
-            assert req.done
-            assert req.committed + req.forced + req.cancelled == req.requested
-        s = drv.stats
-        assert s.blocks_migrated + s.blocks_forced + s.blocks_cancelled == s.blocks_requested
+    retry traffic the stats report covers the re-copied bytes."""
+    drv, _ = _run_interleaved(seed=7)
+    for req in drv.requests.values():
+        assert req.done
+        assert req.committed + req.forced + req.cancelled == req.requested
+    s = drv.stats
+    assert s.blocks_migrated + s.blocks_forced + s.blocks_cancelled == s.blocks_requested
 
 
 def test_megastep_huge_tier_drain():
@@ -305,16 +290,19 @@ def test_packed_operands_slice_back_to_each_phase(warm_operands, sig):
 
 
 @pytest.mark.parametrize(
-    "mode,tiering", [("megastep", False), ("megastep", True), ("batched", False)]
+    "huge,tiering",
+    [(1, False), (1, True), (2, False)],
+    ids=["megastep-False", "megastep-True", "huge-False"],
 )
-def test_h2d_transfers_per_dispatch(mode, tiering):
+def test_h2d_transfers_per_dispatch(huge, tiering):
     """A tiering-off megastep drain makes exactly one operand transfer per
-    dispatch; a tick with a heat phase adds the weights; the batched
-    generation does not count them."""
-    cfg, state, _ = make(n_blocks=32, slots=64, seed=41)
-    drv = MigrationDriver(
-        state, cfg, LeapConfig(budget_blocks_per_tick=8, fused_dispatch=mode, tiering=tiering)
-    )
+    dispatch, on a two-tier pool too; a tick with a heat phase adds the
+    weights."""
+    cfg = PoolConfig(2, 64, (4,), huge_factor=huge)
+    state = init_state(cfg, 32, np.zeros(32, np.int32))
+    drv = MigrationDriver(state, cfg, LeapConfig(budget_blocks_per_tick=8, tiering=tiering))
+    if huge > 1:
+        assert drv.adopt_huge(np.arange(32 // huge)) == 32 // huge
     drv.default_session().leap(np.arange(32), 1)
     rng = np.random.default_rng(41)
     while not drv.done:
@@ -324,9 +312,9 @@ def test_h2d_transfers_per_dispatch(mode, tiering):
     assert drv.default_session().drain()
     s = drv.stats
     assert s.dispatches > 0
-    if mode == "batched":
-        assert s.h2d_transfers == 0
-    elif tiering:
+    if huge > 1:
+        assert s.huge_areas_committed > 0
+    if tiering:
         # writes feed the heat plane on every tick that dispatches after one
         assert s.dispatches < s.h2d_transfers <= 2 * s.dispatches
     else:

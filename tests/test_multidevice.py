@@ -2,10 +2,13 @@
 process must keep seeing 1 device, so these run via ``subprocess``).
 
 Covers: sharded leap state + ppermute copy backend correctness on a real
-mesh, a sharded train step matching the single-device step, and a mini
-dry-run (lower+compile with the production sharding rules on 8 devices).
+mesh, the batched dispatch generation (the ppermute backend's) against the
+megastep on the same schedules, a sharded train step matching the
+single-device step, and a mini dry-run (lower+compile with the production
+sharding rules on 8 devices).
 """
 
+import json
 import os
 import subprocess
 import sys
@@ -57,9 +60,10 @@ def test_ppermute_copy_backend_on_mesh():
 
         # blocks 0,1 live on region 0; copy them to region 5 slots 2,3
         ids = jnp.asarray([0, 1]); slots = jnp.asarray([2, 3])
-        state = migrator.begin_area(state, ids)
-        state = migrator.copy_chunk_ppermute(state, ids, slots, 0, 5, "data", mesh)
-        state, verdict = migrator.commit_area(state, ids, slots, dst_region=5)
+        src_slots = jnp.asarray(np.asarray(state.table)[:2, 1])
+        state = migrator.begin_areas(state, ids)
+        state = migrator.fused_copy_ppermute(state, src_slots, slots, 0, 5, "data", mesh)
+        state, verdict = migrator.commit_areas(state, ids, jnp.full(2, 5), slots)
         assert not np.asarray(verdict).any()
         table = np.asarray(state.table)
         assert table[0].tolist() == [5, 2] and table[1].tolist() == [5, 3]
@@ -69,6 +73,129 @@ def test_ppermute_copy_backend_on_mesh():
         print("PPERMUTE_OK")
         """
     )
+
+
+#: One schedule per case, each run twice on a region-sharded 8-region pool:
+#: with ``LeapConfig()`` (xla backend: the megastep) and with the ppermute
+#: backend (the batched programs).  The child prints one JSON line per case.
+BATCHED_VS_MEGASTEP = """
+import dataclasses, json
+from repro.core import LeapConfig, MigrationDriver, PoolConfig, init_state
+
+mesh = jax.make_mesh((8,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
+N = 32
+# the MigrationStats counters that telemetry mirrors (tests/test_obs_drift.py)
+MIRRORED = ("blocks_requested", "blocks_migrated", "blocks_forced", "blocks_cancelled",
+            "bytes_copied", "dispatches", "h2d_transfers", "dirty_rejections", "splits",
+            "huge_areas_committed", "demotions", "promotions", "bytes_copied_huge",
+            "deferred_congested", "multi_hop_areas")
+CASES = {
+    # case: (huge_factor, scheduler, LeapConfig fields, write blocks/tick, reads/tick)
+    "writes": (1, None, dict(max_attempts_before_force=3), 8, 0),
+    "huge": (2, None, dict(), 2, 0),
+    "tiering": (1, None, dict(tiering=True), 2, 4),
+    "sync": (1, "sync", dict(), 0, 0),
+    "drift": (1, None, dict(max_attempts_before_force=3, telemetry=True), 2, 0),
+}
+
+def run(case, backend):
+    huge, scheduler, fields, n_writes, n_reads = CASES[case]
+    cfg = PoolConfig(8, 2 * N, (4,), region_axis="data", huge_factor=huge)
+    data = np.random.default_rng(3).normal(size=(N, 4)).astype(np.float32)
+    state = init_state(cfg, N, np.zeros(N, np.int32), mesh=mesh)
+    leap = LeapConfig(initial_area_blocks=8, budget_blocks_per_tick=8, **fields)
+    if backend == "ppermute":
+        leap = dataclasses.replace(leap, backend="ppermute", axis_name="data")
+    drv = MigrationDriver(state, cfg, leap, scheduler=scheduler, mesh=mesh)
+    if huge > 1:
+        drv.adopt_huge(np.arange(N // huge))
+    drv.write(jnp.arange(N), jnp.asarray(data))
+    expected = data.copy()
+    session = drv.default_session()
+    session.leap(np.arange(N), 5)
+    rng = np.random.default_rng(3)
+    steps = 0
+    while not drv.done and steps < 1000:
+        drv.tick()
+        # every program the tick dispatched has finished before the next
+        # harvest, so both generations harvest each verdict on the same tick
+        jax.block_until_ready(drv.state)
+        if n_writes:
+            ids = rng.choice(N, size=n_writes, replace=False)
+            vals = rng.normal(size=(n_writes, 4)).astype(np.float32)
+            drv.write(jnp.asarray(ids), jnp.asarray(vals))
+            expected[ids] = vals
+        if n_reads:
+            drv.note_reads(rng.choice(N, size=n_reads))
+        steps += 1
+    assert session.drain()
+    s = drv.stats
+    out = {
+        "payload": bool(np.array_equal(np.asarray(drv.read(np.arange(N))), expected)),
+        "mirror": bool(drv.verify_mirror()),
+        "placed": bool((drv.host_placement() == 5).all()),
+        "closed": all(r.done and r.committed + r.forced + r.cancelled == r.requested
+                      for r in drv.requests.values())
+        and s.blocks_migrated + s.blocks_forced + s.blocks_cancelled == s.blocks_requested,
+        "table": np.asarray(drv.state.table).tolist(),
+        "pool": np.asarray(drv.state.pool).tolist(),
+        "stats": {k: getattr(s, k) for k in MIRRORED},
+    }
+    if leap.tiering:
+        out["heat"] = drv.heat_snapshot().tolist()
+    if leap.telemetry:
+        totals = drv.telemetry.counter_totals()
+        out["drift"] = {k: [totals.get(k, 0), getattr(s, k)] for k in MIRRORED}
+    return out
+
+for case in CASES:
+    result = {"case": case, "megastep": run(case, "xla")}
+    try:
+        result["batched"] = run(case, "ppermute")
+    except ValueError as e:  # the ppermute backend refuses a two-tier pool
+        result["batched_refused"] = str(e)
+    print("CASE " + json.dumps(result))
+"""
+
+
+@pytest.fixture(scope="module")
+def batched_vs_megastep():
+    out = run_sub(BATCHED_VS_MEGASTEP)
+    cases = [json.loads(line[5:]) for line in out.splitlines() if line.startswith("CASE ")]
+    return {c["case"]: c for c in cases}
+
+
+@pytest.mark.parametrize("case", ["writes", "huge", "tiering", "sync", "drift"])
+def test_batched_ppermute_matches_megastep(batched_vs_megastep, case):
+    """The batched generation runs only on the ppermute backend: on the same
+    schedule it must end where the megastep ends, bit for bit."""
+    result = batched_vs_megastep[case]
+    runs = [result["megastep"]] + ([result["batched"]] if "batched" in result else [])
+    for run in runs:
+        assert run["payload"], "expected payload not read back"
+        assert run["mirror"] and run["placed"]
+        assert run["closed"], "committed + forced + cancelled != requested"
+    mega = result["megastep"]
+    if case == "huge":
+        # the huge tier runs under the megastep only: a two-tier pool on the
+        # ppermute backend is refused at construction
+        assert "two-tier pool requires the xla copy backend" in result["batched_refused"]
+        assert mega["stats"]["huge_areas_committed"] > 0
+        return
+    batched = result["batched"]
+    assert batched["table"] == mega["table"]
+    assert batched["pool"] == mega["pool"]
+    if case == "writes":
+        assert mega["stats"]["dirty_rejections"] > 0 and mega["stats"]["blocks_forced"] > 0
+        assert mega["stats"]["dispatches"] < batched["stats"]["dispatches"]
+    if case == "tiering":
+        assert batched["heat"] == mega["heat"] and max(mega["heat"]) > 0
+    if case == "sync":
+        assert mega["stats"]["blocks_forced"] == batched["stats"]["blocks_forced"] > 0
+    if case == "drift":
+        for run in (mega, batched):
+            for key, (ring, stat) in run["drift"].items():
+                assert ring == stat, (key, ring, stat)
 
 
 def test_sharded_train_step_matches_single_device():

@@ -48,7 +48,7 @@ def make(n_blocks=16, slots=24, n_regions=2, telemetry=True, **leap_kw):
     data = np.arange(n_blocks * 4, dtype=np.float32).reshape(n_blocks, 4)
     state = leap_write(state, jnp.arange(n_blocks), jnp.asarray(data))
     kw = dict(
-        initial_area_blocks=4, chunk_blocks=2, budget_blocks_per_tick=4,
+        initial_area_blocks=4, budget_blocks_per_tick=4,
         telemetry=telemetry,
     )
     kw.update(leap_kw)
@@ -77,7 +77,7 @@ def test_recorder_stage_counter_and_event_families():
     rec.begin_tick(3)
     with rec.stage("dispatch.run_tick", opened=2):
         pass
-    rec.count("dispatches", 1, program="copy_chunk")
+    rec.count("dispatches", 1, program="fused_copy")
     rec.count("dispatches", 2)
     rec.event("jit", "jit_miss", n=1)
     kinds = [(e["kind"], e["name"]) for e in rec.events()]
@@ -214,8 +214,7 @@ def test_jit_misses_land_as_events():
     state = init_state(cfg, 16, np.zeros(16, np.int32))
     drv = MigrationDriver(
         state, cfg,
-        LeapConfig(initial_area_blocks=4, chunk_blocks=3,
-                   budget_blocks_per_tick=6, telemetry=True),
+        LeapConfig(initial_area_blocks=4, budget_blocks_per_tick=6, telemetry=True),
     )
     sess = LeapSession(drv)
     sess.leap(np.arange(16), 1)

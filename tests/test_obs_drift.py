@@ -12,8 +12,6 @@ Deterministic seeded sweep runs in tier-1; the Hypothesis exploration at
 the bottom is importorskip'd like the rest of the generative chaos suite.
 """
 
-import dataclasses
-
 import pytest
 
 from repro.chaos import ChaosDriver, sample_spec
@@ -73,16 +71,6 @@ def test_seeded_chaos_scenarios_never_drift(seed):
     report = chaos.run()
     assert report.completed
     assert chaos.driver.stats.blocks_requested > 0  # scenario actually moved
-    _assert_no_drift(chaos.driver)
-
-
-@pytest.mark.parametrize("mode", ["legacy", "batched"])
-def test_seeded_chaos_never_drifts_on_prior_dispatch_generations(mode):
-    # sample_spec defaults to megastep (covered above); the same scenario
-    # must stay drift-free when replayed on the earlier dispatch paths.
-    chaos = ChaosDriver(dataclasses.replace(sample_spec(2), dispatch=mode))
-    report = chaos.run()
-    assert report.completed
     _assert_no_drift(chaos.driver)
 
 

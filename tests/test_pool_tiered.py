@@ -63,9 +63,11 @@ def test_commit_groups_all_or_nothing():
     state = migrator.begin_areas(state, members)
     state = leap_write(state, jnp.asarray([2]), jnp.zeros((1, 1, 8)))  # dirty one
     assert bool(group_dirty(state, jnp.asarray([0]), G)[0])
-    state, verdict = migrator.commit_groups(
-        state, members, jnp.asarray([1]), jnp.asarray([0]), group=G
+    # the megastep's group-commit phase alone: group 0 to region 1, slot 0
+    packed, layout = migrator.pack_operands(
+        {"grp_members": np.arange(G), "grp_regions": [1], "grp_starts": [0]}
     )
+    state, _, verdict, _ = migrator.megastep(state, packed, layout=layout, group=G)
     assert verdict.tolist() == [True]
     table = np.asarray(state.table)
     assert (table[:G, 0] == 0).all()  # nothing flipped, not even clean members
@@ -113,15 +115,6 @@ def test_huge_drain_full_pool():
     assert drv.stats.huge_areas_committed == 4
     assert (drv.host_placement() == 1).all()
     assert drv.verify_tiers()
-    np.testing.assert_array_equal(np.asarray(drv.read(np.arange(16))), data)
-
-
-def test_legacy_dispatch_path_supports_huge():
-    cfg, drv, data = make_tiered(fused_dispatch=False)
-    drv.request(np.arange(16), 1)
-    assert drv.drain()
-    assert drv.stats.huge_areas_committed == 4
-    assert drv.verify_mirror() and drv.verify_tiers()
     np.testing.assert_array_equal(np.asarray(drv.read(np.arange(16))), data)
 
 

@@ -42,7 +42,6 @@ def test_property_leap_cancel_write_interleavings(seed, n_blocks, n_regions, ops
         cfg,
         LeapConfig(
             initial_area_blocks=4,
-            chunk_blocks=2,
             budget_blocks_per_tick=4,
             max_attempts_before_force=3,
         ),

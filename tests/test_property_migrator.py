@@ -44,7 +44,6 @@ def test_property_interleaved_writes_preserve_contents(
         cfg,
         LeapConfig(
             initial_area_blocks=initial_area,
-            chunk_blocks=2,
             budget_blocks_per_tick=4,
             max_attempts_before_force=3,
         ),
@@ -79,7 +78,7 @@ def test_property_random_requests_slot_conservation(seed):
     n_blocks, n_regions = 12, 3
     cfg = PoolConfig(n_regions, 24, (2,))
     state = init_state(cfg, n_blocks, np.zeros(n_blocks, np.int32))
-    drv = MigrationDriver(state, cfg, LeapConfig(initial_area_blocks=4, chunk_blocks=2))
+    drv = MigrationDriver(state, cfg, LeapConfig(initial_area_blocks=4))
     checker = InvariantChecker(drv)
     for _ in range(4):
         ids = rng.choice(n_blocks, size=rng.integers(1, n_blocks + 1), replace=False)

@@ -32,7 +32,7 @@ def test_property_decode_invariant_under_any_migration_schedule(setup, seed, sch
 
     def run(with_migration: bool):
         eng = _engine(cfg, params, leap=LeapConfig(
-            initial_area_blocks=2, chunk_blocks=1, budget_blocks_per_tick=1,
+            initial_area_blocks=2, budget_blocks_per_tick=1,
             max_attempts_before_force=2,
         ))
         sids = [eng.admit(p, region=i % 2) for i, p in enumerate(prompts)]

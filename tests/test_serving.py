@@ -110,7 +110,7 @@ def test_decode_correct_under_live_migration(setup):
     want = _contiguous_decode(cfg, params, prompt, n_steps)
 
     eng = _engine(cfg, params, leap=LeapConfig(
-        initial_area_blocks=2, chunk_blocks=1, budget_blocks_per_tick=1,
+        initial_area_blocks=2, budget_blocks_per_tick=1,
         max_attempts_before_force=3,
     ))
     sid = eng.admit(prompt)

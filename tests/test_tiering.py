@@ -188,22 +188,6 @@ def test_heat_warm_path_does_not_recompile():
     assert drv.stats.jit_cache_misses == misses
 
 
-def test_heat_flush_on_batched_and_legacy_modes():
-    """Non-megastep modes fold pending samples through the standalone
-    heat_update program — heat still accumulates, one extra dispatch."""
-    for mode in ("batched", "legacy"):
-        cfg, state, _ = make(n_blocks=16, slots=32, seed=5)
-        drv = MigrationDriver(
-            state, cfg, LeapConfig(tiering=True, fused_dispatch=mode)
-        )
-        for _ in range(4):
-            drv.read(np.array([3, 3, 9]))
-            drv.tick()
-        heat = drv.heat_snapshot()
-        assert heat[3] > heat[9] > 0
-        assert heat[4] == 0
-
-
 def test_heat_decay_orders_recency():
     """Blocks read longer ago decay below recently read ones."""
     cfg, state, _ = make(n_blocks=16, slots=32, seed=6)
