@@ -73,6 +73,20 @@ class PipelineContext:
         setattr(self.stats, name, getattr(self.stats, name) + n)
         self.telemetry.count(name, n, **args)
 
+    def await_verdict(self, areas: list[Area], offsets: np.ndarray, verdict) -> None:
+        """Queue one commit dispatch's packed verdict for the verdict stage.
+
+        The only way a :class:`CommitBatch` joins ``pending``, on both copy
+        backends.  The verdict's device→host copy starts here, at dispatch:
+        it lands while the host runs the next foreground step, so the
+        harvest's read finds it on the host instead of paying the round
+        trip.  The harvest itself, and the tick it happens in, are
+        unchanged.
+        """
+        verdict.copy_to_host_async()
+        self.count("verdict_prefetches", 1)
+        self.pending.append(CommitBatch(areas, offsets, verdict))
+
     # -- host-mirror primitives (shared by dispatch and verdict) -----------
 
     def alloc(self, region: int, n: int) -> np.ndarray | None:

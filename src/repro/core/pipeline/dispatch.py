@@ -10,9 +10,10 @@ to the device in the one dispatch generation its copy backend
     then begin/zero/force/copy, over the donated flat pool view.  The host
     side of this stage is pure *plan assembly*: it gathers numpy id vectors,
     pads them with out-of-bounds sentinels to one shared bucket, and crosses
-    the host/device boundary exactly once per tick.  The dirty verdict never
-    crosses back here — it stays device-resident inside the
-    :class:`~repro.core.queues.CommitBatch` future, harvested by the verdict
+    the host/device boundary exactly once per tick.  The dirty verdict is
+    never read here: it joins ``pending`` as a
+    :class:`~repro.core.queues.CommitBatch` future whose host copy starts at
+    dispatch (``PipelineContext.await_verdict``), harvested by the verdict
     stage off the tick critical path (DESIGN.md §12).
   * ``ppermute``: the batched programs — one fused program per tick phase
     (``begin_areas``, zero fill, ``force_areas``, one ``fused_copy_ppermute``
@@ -38,7 +39,6 @@ from repro.core.adaptive import Area, bucket_size, demote_area, pad_to_bucket
 from repro.core.pipeline.accounting import AccountingStage
 from repro.core.pipeline.budget import BudgetStage, TickBudget
 from repro.core.pipeline.context import PipelineContext
-from repro.core.queues import CommitBatch
 from repro.core.state import REGION, SLOT
 
 
@@ -678,9 +678,9 @@ class DispatchStage:
         for a in small + huge:
             ctx.active.remove(a)
         if small:
-            ctx.pending.append(CommitBatch(small, offsets, verdict_small))
+            ctx.await_verdict(small, offsets, verdict_small)
         if huge:
-            ctx.pending.append(CommitBatch(huge, np.arange(k + 1), verdict_groups))
+            ctx.await_verdict(huge, np.arange(k + 1), verdict_groups)
 
     # -- batched dispatch (ppermute backend) --------------------------------
 
@@ -782,7 +782,7 @@ class DispatchStage:
         ctx.count("dispatches", 1, program="commit_areas")
         for a in ready:
             ctx.active.remove(a)
-        ctx.pending.append(CommitBatch(ready, offsets, verdict))
+        ctx.await_verdict(ready, offsets, verdict)
 
     # -- tier transitions (two-tier pool) ----------------------------------
 

@@ -3,13 +3,16 @@
 This stage is the pipeline's ONLY device→host synchronization point.
 Commit dispatches — the batched ``commit_areas`` program (ppermute
 backend), or the commit phase of the megastep (DESIGN.md §12) — return
-packed dirty vectors that stay on device, wrapped in ``CommitBatch``
-futures on ``ctx.pending``.  Harvest materializes them opportunistically
-(``is_ready()`` first, so a tick never stalls on an unfinished verdict)
-or blocking at drain, always at least one tick after the commit was
-dispatched: the copy→remap race window of §2 closes asynchronously, off
-the tick's critical path.  Everything downstream of the fetch is host-side
-bookkeeping over exact mirrors — no further device round-trips.
+packed dirty vectors, wrapped in ``CommitBatch`` futures on
+``ctx.pending`` by ``PipelineContext.await_verdict``, which also starts
+each vector's device→host copy at dispatch.  Harvest materializes them
+opportunistically (``is_ready()`` first, so a tick never stalls on an
+unfinished verdict) or blocking at drain, always at least one tick after
+the commit was dispatched: the copy→remap race window of §2 closes
+asynchronously, off the tick's critical path.  By then the copy has
+usually landed, so the read (span ``verdict.sync``) times only what is
+left of it.  Everything downstream of the fetch is host-side bookkeeping
+over exact mirrors — no further device round-trips.
 
 Per area, the packed vector resolves as: clean blocks remap in the host
 mirror and credit their request (or continue to a relay's second hop),
@@ -44,8 +47,8 @@ class VerdictStage:
     # -- harvest -----------------------------------------------------------
 
     def harvest(self, block: bool) -> None:
-        """Process every pending commit verdict already on the host (or all
-        of them, synchronizing, when ``block``)."""
+        """Process every pending commit verdict the device has produced (or
+        all of them, synchronizing, when ``block``)."""
         ctx = self.ctx
         if not ctx.pending:
             return
@@ -62,8 +65,9 @@ class VerdictStage:
                     still.append(batch)
                     continue
                 # Sync point: materializing the verdict blocks until the
-                # device produced it (opportunistic harvests already saw
-                # is_ready(), so only block=True pays a real wait here).
+                # device produced it and its host copy, started at
+                # dispatch, landed (opportunistic harvests already saw
+                # is_ready(), so they wait at most for the copy).
                 with ctx.telemetry.stage("verdict.sync"):
                     packed = np.asarray(batch.verdict)
                 for area, start, end in zip(batch.areas, batch.offsets, batch.offsets[1:]):

@@ -30,6 +30,10 @@ class MigrationStats:
     # packed index vector per megastep, plus the heat weights on a tick
     # with a heat phase (zero under the batched generation).
     h2d_transfers: int = 0
+    # Commit verdicts whose device-to-host copy started at dispatch
+    # (PipelineContext.await_verdict), on both dispatch generations: one per
+    # batch the verdict stage harvests.
+    verdict_prefetches: int = 0
     ticks: int = 0
     jit_cache_misses: int = 0  # migration-program compiles since driver init
     # per-tier counters (two-tier pool; all zero on a small-only pool)

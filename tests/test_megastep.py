@@ -11,7 +11,9 @@ Covers the acceptance criteria of the device-resident tick loop:
     round up to the shared floored bucket, so megastep compiles a bounded
     number of variants after warmup,
   * the dispatch generation follows the copy backend (megastep on xla,
-    batched on ppermute).
+    batched on ppermute),
+  * verdict prefetch — every commit verdict's host copy starts at dispatch,
+    one per harvested batch, and changes no outcome.
 """
 
 import jax
@@ -20,6 +22,7 @@ import numpy as np
 import pytest
 
 from repro.core import (
+    CommitBatch,
     LeapConfig,
     MigrationDriver,
     PoolConfig,
@@ -27,6 +30,7 @@ from repro.core import (
     leap_write,
     migrator,
 )
+from repro.core.pipeline import PipelineContext
 from repro.kernels.heat_scan import padded_heat_len
 
 
@@ -319,3 +323,99 @@ def test_h2d_transfers_per_dispatch(huge, tiering):
         assert s.dispatches < s.h2d_transfers <= 2 * s.dispatches
     else:
         assert s.h2d_transfers == s.dispatches
+
+
+# ---------------------------------------------------------------------------
+# Verdict prefetch: the host copy starts at dispatch
+# ---------------------------------------------------------------------------
+
+
+def _leaps_with_writes(huge, seed=17, n=32):
+    """Two seeded leaps (out and back) under overlapping writes.  Each tick's
+    programs finish before the next one, so every run harvests each verdict
+    on the same tick and the whole schedule is reproducible."""
+    cfg = PoolConfig(2, 2 * n, (4,), huge_factor=huge)
+    state = init_state(cfg, n, np.zeros(n, np.int32))
+    leap = LeapConfig(
+        initial_area_blocks=8,
+        budget_blocks_per_tick=8,
+        max_attempts_before_force=3,
+        telemetry=True,
+    )
+    drv = MigrationDriver(state, cfg, leap)
+    if huge > 1:
+        assert drv.adopt_huge(np.arange(n // huge)) == n // huge
+    rng = np.random.default_rng(seed)
+    expected = rng.normal(size=(n, 4)).astype(np.float32)
+    drv.write(jnp.arange(n), jnp.asarray(expected))
+    session = drv.default_session()
+    for dst in (1, 0):
+        session.leap(np.arange(n), dst)
+        steps = 0
+        while not drv.done and steps < 1000:
+            drv.tick()
+            jax.block_until_ready(drv.state)
+            ids = rng.choice(n, size=8, replace=False)
+            vals = rng.normal(size=(8, 4)).astype(np.float32)
+            drv.write(jnp.asarray(ids), jnp.asarray(vals))
+            expected[ids] = vals
+            steps += 1
+        assert drv.done and session.drain()
+    return drv, expected
+
+
+def _assert_drained_and_mirrored(drv, expected):
+    assert not drv.ctx.pending
+    np.testing.assert_array_equal(drv.host_table(), np.asarray(drv.state.table))
+    assert drv.verify_mirror()
+    assert (drv.host_placement() == 0).all()
+    np.testing.assert_array_equal(np.asarray(drv.read(np.arange(len(expected)))), expected)
+
+
+@pytest.mark.parametrize("huge", [1, 2], ids=["small", "huge"])
+def test_verdict_prefetched_per_harvested_batch(huge):
+    """Every batch the verdict stage harvests had its host copy started at
+    dispatch: one ``verdict.sync`` read per prefetch."""
+    drv, expected = _leaps_with_writes(huge)
+    rec = drv.telemetry
+    assert rec.dropped == 0
+    harvested = sum(
+        1 for ev in rec.events() if ev["kind"] == "stage" and ev["name"] == "verdict.sync"
+    )
+    assert drv.stats.verdict_prefetches == harvested > 0
+    assert rec.counter_totals()["verdict_prefetches"] == harvested
+    _assert_drained_and_mirrored(drv, expected)
+
+
+@pytest.mark.parametrize("huge", [1, 2], ids=["small", "huge"])
+def test_verdict_prefetch_changes_no_outcome(huge, monkeypatch):
+    """The prefetch moves when the verdict's bytes cross, nothing else: with
+    the copy left to the harvest, the same schedule commits, rejects and
+    splits the same blocks and ends in the same pool."""
+    drv, expected = _leaps_with_writes(huge)
+
+    def without_prefetch(ctx, areas, offsets, verdict):
+        ctx.pending.append(CommitBatch(areas, offsets, verdict))
+
+    monkeypatch.setattr(PipelineContext, "await_verdict", without_prefetch)
+    plain, plain_expected = _leaps_with_writes(huge)
+    assert plain.stats.verdict_prefetches == 0 < drv.stats.verdict_prefetches
+    np.testing.assert_array_equal(plain_expected, expected)
+    for key in (
+        "blocks_migrated",
+        "blocks_forced",
+        "dirty_rejections",
+        "splits",
+        "huge_areas_committed",
+        "demotions",
+        "dispatches",
+        "ticks",
+    ):
+        assert getattr(plain.stats, key) == getattr(drv.stats, key), key
+    assert drv.stats.dirty_rejections > 0 and drv.stats.splits > 0
+    if huge > 1:
+        assert drv.stats.huge_areas_committed > 0
+    np.testing.assert_array_equal(np.asarray(plain.state.table), np.asarray(drv.state.table))
+    np.testing.assert_array_equal(np.asarray(plain.state.pool), np.asarray(drv.state.pool))
+    _assert_drained_and_mirrored(plain, expected)
+    _assert_drained_and_mirrored(drv, expected)

@@ -86,7 +86,8 @@ mesh = jax.make_mesh((8,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
 N = 32
 # the MigrationStats counters that telemetry mirrors (tests/test_obs_drift.py)
 MIRRORED = ("blocks_requested", "blocks_migrated", "blocks_forced", "blocks_cancelled",
-            "bytes_copied", "dispatches", "h2d_transfers", "dirty_rejections", "splits",
+            "bytes_copied", "dispatches", "h2d_transfers", "verdict_prefetches",
+            "dirty_rejections", "splits",
             "huge_areas_committed", "demotions", "promotions", "bytes_copied_huge",
             "deferred_congested", "multi_hop_areas")
 CASES = {
@@ -146,6 +147,10 @@ def run(case, backend):
     if leap.telemetry:
         totals = drv.telemetry.counter_totals()
         out["drift"] = {k: [totals.get(k, 0), getattr(s, k)] for k in MIRRORED}
+        out["dropped"] = drv.telemetry.dropped
+        # one verdict.sync read per harvested commit batch
+        out["harvested"] = sum(1 for ev in drv.telemetry.events()
+                               if ev["kind"] == "stage" and ev["name"] == "verdict.sync")
     return out
 
 for case in CASES:
@@ -196,6 +201,16 @@ def test_batched_ppermute_matches_megastep(batched_vs_megastep, case):
         for run in (mega, batched):
             for key, (ring, stat) in run["drift"].items():
                 assert ring == stat, (key, ring, stat)
+
+
+@pytest.mark.parametrize("generation", ["megastep", "batched"])
+def test_verdict_prefetched_per_harvested_batch_on_mesh(batched_vs_megastep, generation):
+    """Both generations start every commit verdict's host copy at dispatch:
+    on a region-sharded pool too, one prefetch per harvested batch."""
+    run = batched_vs_megastep["drift"][generation]
+    assert run["dropped"] == 0
+    assert run["stats"]["verdict_prefetches"] == run["harvested"] > 0
+    assert run["mirror"] and run["payload"]
 
 
 def test_sharded_train_step_matches_single_device():

@@ -25,6 +25,7 @@ MIRRORED = (
     "bytes_copied",
     "dispatches",
     "h2d_transfers",
+    "verdict_prefetches",
 )
 #: Mirrored too, but only nonzero on some scenario shapes (tiered pools,
 #: topologies with congestion/relays) — same equality, asserted when present.
